@@ -35,6 +35,7 @@ from repro.core import DiscoConfig, DiscoSolver, comm
 from repro.data.sparse import make_sparse_glm_data
 from repro.data.store import ShardStore
 from repro.data.stream import plan_streams
+from repro.kernels.ops import ref_kernels_off_tpu
 from repro.robust.faults import FaultInjector, FaultPlan
 from repro.robust.straggler import (ChunkTimingLedger, ElasticReplanner,
                                     barrier_seconds)
@@ -147,7 +148,7 @@ def _retry_accuracy(rows):
 
 
 def run(quiet=False):
-    os.environ.setdefault("REPRO_KERNEL_MODE", "ref")
+    ref_kernels_off_tpu()
     rows = []
     gate = dict(straggler=_straggler_recovery(rows),
                 retry=_retry_accuracy(rows))

@@ -205,14 +205,13 @@ def _solver_section(rows, gate):
 
 
 def run(quiet=False):
-    import jax
+    from repro.kernels.ops import ref_kernels_off_tpu
 
     # the gate audits byte *ratios* and solver parity; the fast jnp
     # reference path keeps CPU runs honest and quick. On a TPU backend
     # the mode is left alone so the native kernels run and the
     # wall-clock gate times the memory system it models.
-    if jax.default_backend() != "tpu":
-        os.environ.setdefault("REPRO_KERNEL_MODE", "ref")
+    ref_kernels_off_tpu()
     rows: list[dict] = []
     gate: dict = {}
 

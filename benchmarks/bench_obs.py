@@ -155,7 +155,8 @@ def _traced_solve(mesh=None) -> dict:
     json.dumps(obs.export.chrome_trace(tracer))
     obs.disable()
     import jax
-    return dict(devices=len(jax.devices()),
+    return dict(platform=jax.devices()[0].platform,
+                devices=len(jax.devices()),
                 outer_iters=len(res.history),
                 ledger_rounds=int(res.ledger.rounds),
                 counter_rounds=int(counters.get("comm.rounds", 0)),
@@ -168,10 +169,12 @@ def _traced_solve(mesh=None) -> dict:
 _SUBPROCESS_SCRIPT = textwrap.dedent("""
     import json, os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
-    os.environ.setdefault("REPRO_KERNEL_MODE", "ref")
     import jax
+    from repro.kernels.ops import ref_kernels_off_tpu
+    from repro.launch.mesh import make_mesh
+    ref_kernels_off_tpu()
     assert len(jax.devices()) == 4
-    mesh = jax.make_mesh((4,), ("data",))
+    mesh = make_mesh((4,), ("data",))
     from benchmarks import bench_obs
     print("OBS_RESULT " + json.dumps(bench_obs._traced_solve(mesh=mesh)))
 """)
@@ -187,6 +190,9 @@ def _rounds_case() -> dict:
             [repo, os.path.join(repo, "src")]
             + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else []))
         env.pop("XLA_FLAGS", None)
+        # the forced 4-device mesh exists only on the CPU backend, and a
+        # child must never compete with this process for an accelerator
+        env["JAX_PLATFORMS"] = "cpu"
         r = subprocess.run([sys.executable, "-c", _SUBPROCESS_SCRIPT],
                            env=env, capture_output=True, text=True,
                            timeout=540)
@@ -196,7 +202,7 @@ def _rounds_case() -> dict:
         line = [ln for ln in r.stdout.splitlines()
                 if ln.startswith("OBS_RESULT ")][-1]
         out = json.loads(line[len("OBS_RESULT "):])
-    out["case"] = f"trace-{out['devices']}dev"
+    out["case"] = f"trace-{out['devices']}dev-{out['platform']}"
     out["rounds_match"] = (
         out["counter_rounds"] == out["ledger_rounds"]
         == out["allreduce_spans"])
@@ -204,7 +210,9 @@ def _rounds_case() -> dict:
 
 
 def run(quiet=False):
-    os.environ.setdefault("REPRO_KERNEL_MODE", "ref")
+    from repro.kernels.ops import ref_kernels_off_tpu
+
+    ref_kernels_off_tpu()
     overhead = _overhead_case()
     rounds = _rounds_case()
     rows = [overhead, rounds]

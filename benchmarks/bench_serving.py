@@ -38,6 +38,7 @@ from repro.data.store import ShardStore
 from repro.glm_serve import (MicroBatchScheduler, ModelRegistry,
                              RefitLoop, ScoreRequest, ScoringEngine,
                              oracle_margins)
+from repro.kernels.ops import ref_kernels_off_tpu
 
 if smoke():
     D, N, CHUNK = 64, 512, 64
@@ -86,7 +87,7 @@ def _time_sequential(engine, requests):
 
 
 def run(quiet=False):
-    os.environ.setdefault("REPRO_KERNEL_MODE", "ref")
+    ref_kernels_off_tpu()
     X, y, _ = make_sparse_glm_data(d=D, n=N, density=DENSITY, alpha=ALPHA,
                                    beta=BETA, seed=0)
     Xd = X.todense()

@@ -32,6 +32,7 @@ from benchmarks.common import Timer, save_json, smoke, table
 from repro.core import DiscoConfig, DiscoSolver, comm
 from repro.data.sparse import make_sparse_glm_data
 from repro.data.store import ShardStore
+from repro.kernels.ops import ref_kernels_off_tpu
 
 if smoke():
     D, N, DENSITY = 128, 256, 0.05
@@ -67,7 +68,7 @@ def _fit_pair(X, y, partition, chunk_size, depth=DEPTH):
 
 
 def run(quiet=False):
-    os.environ.setdefault("REPRO_KERNEL_MODE", "ref")
+    ref_kernels_off_tpu()
     X, y, _ = make_sparse_glm_data(d=D, n=N, density=DENSITY, alpha=ALPHA,
                                    beta=BETA, seed=0)
     rows, gate = [], {}

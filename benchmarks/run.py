@@ -29,9 +29,13 @@ def main(argv=None):
                          "roofline")
     args = ap.parse_args(argv)
 
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     if args.smoke:
         os.environ["REPRO_BENCH_SMOKE"] = "1"
-        os.environ.setdefault("REPRO_KERNEL_MODE", "ref")
+        from repro.kernels.ops import ref_kernels_off_tpu
+        ref_kernels_off_tpu()
 
     selected = set(args.only.split(",")) if args.only else None
 

@@ -1,0 +1,410 @@
+#!/usr/bin/env python3
+"""Bring-up check: the DiSCO solver and the GLM scoring engine on a TPU.
+
+Drives the system's main path once, in one process, through the entry
+points a user calls, and checks every result against a reference:
+
+1. device gate: JAX sees a TPU and the Pallas kernels resolve to native;
+2. dense in-memory DiSCO-S at the shape of epsilon (PASCAL Large Scale
+   Learning Challenge, as listed on the LIBSVM binary page: d = 2,000,
+   n = 400,000, dense, 3.2 GB in f32) with the Pallas HVP kernels,
+   against the same solve on the jnp path and an f64 NumPy gradient;
+3. sparse in-memory DiSCO-F from a CSR matrix (128x128 blocked-ELL tiles,
+   LPT partitioning), against the dense solve of the same matrix;
+4. out-of-core DiSCO-S streamed from a ShardStore, against the in-memory
+   solve of the same data;
+5. scoring: phase 4's model published to a ModelRegistry and served in
+   micro-batches by a ScoringEngine, against ``oracle_margins``.
+
+Every phase prints one JSON line (shapes, bytes, device memory, compile
+seconds, steady seconds per outer iteration, error against its reference);
+the last line is ``{"ok": true, "device": {...}}``. A failed check exits
+non-zero. All data is generated from ``--seed``. Sizes of phases 3-5 are
+cut (padded blocked-ELL at published sparsity would not fit a chip); the
+dense phase runs at full size.
+
+    python chip_smoke.py              # one chip, all phases
+    python chip_smoke.py --chips 4    # 4-chip mesh vs 1 chip, same process
+"""
+from __future__ import annotations
+
+import argparse
+import dataclasses
+import gc
+import json
+import os
+import statistics
+import sys
+import tempfile
+import time
+
+import numpy as np
+
+sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                                "src"))
+
+import jax  # noqa: E402
+
+from repro import obs  # noqa: E402
+from repro.core import DiscoConfig, DiscoSolver  # noqa: E402
+from repro.data.sparse import make_sparse_glm_data  # noqa: E402
+from repro.data.store import ShardStore  # noqa: E402
+from repro.data.synthetic import make_glm_data  # noqa: E402
+from repro.glm_serve import (ModelRegistry, ScoreRequest,  # noqa: E402
+                             ScoringEngine, oracle_margins)
+from repro.kernels import ops  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
+from repro.utils.compile_cache import enable_compile_cache  # noqa: E402
+
+
+@dataclasses.dataclass(frozen=True)
+class Sizes:
+    dense_d: int = 2_000            # epsilon, full size
+    dense_n: int = 400_000
+    dense_outer: int = 6
+    sparse_d: int = 4_096           # cut: padded ELL tiles must fit HBM
+    sparse_n: int = 32_768
+    sparse_density: float = 0.005
+    sparse_outer: int = 8
+    stream_d: int = 1_024           # cut: every PCG round re-reads disk
+    stream_n: int = 16_384
+    stream_density: float = 0.02
+    stream_chunk: int = 2_048
+    stream_outer: int = 12          # a cap: the phase runs to STREAM_GRAD_TOL
+    requests: int = 256
+    batch: int = 64
+    tile: int = 128                 # blocked-ELL tile edge
+
+
+DENSE_LAM = 1e-3        # lam of the dense phase (epsilon is a dense n >> d
+SPARSE_LAM = 1e-2       # problem); the sparse phases use the tests' lam
+W_REL_TOL = 1e-4        # kernel vs jnp, 4 chips vs 1: f32 rounding only
+SPARSE_REL_TOL = 1e-5   # sparse == dense, streamed == in-memory
+SCORE_REL_TOL = 1e-5    # the serving parity bound (bench_serving)
+# Streamed and in-memory solves are compared where both have converged.
+# Before that, two inexact-Newton trajectories in f32 part by far more than
+# 1e-5: at the phase-4 shape after 3 outer iterations the in-memory CSR
+# solve and the dense solve of the same matrix differ by 1.8e-3 (CPU,
+# jnp reference kernels), although each step agrees to rounding.
+STREAM_GRAD_TOL = 1e-7
+
+
+def check(ok: bool, what: str) -> None:
+    if not ok:
+        raise SystemExit(f"chip_smoke: FAILED {what}")
+
+
+def emit(rec: dict) -> None:
+    print(json.dumps(rec, default=float), flush=True)
+
+
+def rel_err(a, b) -> float:
+    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+    return float(np.linalg.norm(a - b) / max(np.linalg.norm(b), 1e-30))
+
+
+class CompileClock:
+    """Seconds JAX spends tracing, lowering and compiling, and the number
+    of backend compiles, from JAX's own monitoring events."""
+
+    EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
+              "/jax/core/compile/jaxpr_to_mlir_module_duration",
+              "/jax/core/compile/backend_compile_duration")
+
+    def __init__(self):
+        self.seconds, self.compiles = 0.0, 0
+        jax.monitoring.register_event_duration_secs_listener(self._on)
+
+    def _on(self, event, duration, **_):
+        if event in self.EVENTS:
+            self.seconds += duration
+            self.compiles += event == self.EVENTS[-1]
+
+    def take(self) -> tuple[float, int]:
+        out = (self.seconds, self.compiles)
+        self.seconds, self.compiles = 0.0, 0
+        return out
+
+
+CLOCK = CompileClock()
+
+
+def memory() -> dict:
+    st = jax.devices()[0].memory_stats() or {}
+    return dict(bytes_in_use=st.get("bytes_in_use"),
+                peak_bytes_in_use=st.get("peak_bytes_in_use"))
+
+
+def shard_devices(arr) -> list[int]:
+    return sorted({s.device.id for s in arr.addressable_shards})
+
+
+def solve(X, y, cfg, mesh=None):
+    """A fresh solver's result, with its device arrays released: the
+    solver's jitted step refers back to it, so only the cycle collector
+    frees them."""
+    res = DiscoSolver(X, y, cfg, mesh).fit()
+    gc.collect()
+    return res
+
+
+def timed_fit(solver, w0=None):
+    """``fit`` twice: the first call compiles, the second (from the first
+    one's result) is the steady window. Returns both results and the
+    timing record; each outer iteration is timed by ``fit`` around
+    ``block_until_ready`` of the step's outputs."""
+    CLOCK.take()
+    first = solver.fit(w0)
+    compile_s, compiles = CLOCK.take()
+    steady = solver.fit(first.w)
+    _, steady_compiles = CLOCK.take()
+    iters = [h["iter_s"] for h in steady.history]
+    return first, steady, dict(
+        compile_s=compile_s, compiles=compiles,
+        steady_compiles=steady_compiles,
+        first_iter_s=first.history[0]["iter_s"],
+        steady_iter_s=statistics.median(iters), **memory())
+
+
+def logistic_grad_norm_f64(X, y, w, lam, chunk=50_000) -> tuple[float, float]:
+    """||grad|| of the logistic objective in f64, and the f32 rounding
+    scale ``|| |X| |d1| / n + lam |w| ||`` of that sum."""
+    n = X.shape[1]
+    w64 = np.asarray(w, np.float64)
+    g, scale = lam * w64, lam * np.abs(w64)
+    for lo in range(0, n, chunk):
+        Xc = np.asarray(X[:, lo:lo + chunk], np.float64)
+        yc = np.asarray(y[lo:lo + chunk], np.float64)
+        d1 = -yc / (1.0 + np.exp(yc * (Xc.T @ w64)))
+        g += Xc @ d1 / n
+        scale += np.abs(Xc) @ np.abs(d1) / n
+    return float(np.linalg.norm(g)), float(np.linalg.norm(scale))
+
+
+# ---------------------------------------------------------------------------
+# phases
+# ---------------------------------------------------------------------------
+
+def device_gate() -> dict:
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        raise SystemExit(f"chip_smoke: needs a TPU, but JAX found platform "
+                         f"{dev.platform!r}")
+    mode = ops._mode()
+    check(mode == "native", f"kernel mode resolves to {mode!r}, not native "
+          f"(REPRO_KERNEL_MODE={os.environ.get('REPRO_KERNEL_MODE')!r})")
+    return dict(platform=dev.platform, kind=dev.device_kind,
+                count=len(jax.devices()))
+
+
+def phase_dense(seed: int, sz: Sizes, partition: str = "samples",
+                mesh=None, data=None):
+    """Dense DiSCO with the Pallas kernels vs the jnp path, and the
+    solver's gradient norm vs an f64 recomputation at its result."""
+    X, y, _ = data or make_glm_data(sz.dense_d, sz.dense_n, seed=seed)
+    base = dict(partition=partition, lam=DENSE_LAM,
+                max_outer=sz.dense_outer, grad_tol=0.0, seed=seed)
+
+    tracer = obs.enable(reset=True)
+    solver = DiscoSolver(X, y, DiscoConfig(use_kernel=True, **base), mesh)
+    kern, steady, timing = timed_fit(solver)
+    obs.disable()
+    modes = sorted({e.args.get("mode") for e in tracer.snapshot()[0]
+                    if e.kind == "kernel.dispatch"})
+    devices = shard_devices(solver.X)
+    del solver
+    gc.collect()
+
+    ref = solve(X, y, DiscoConfig(use_kernel=False, **base), mesh)
+    g64, scale = logistic_grad_norm_f64(X, y, kern.w, DENSE_LAM)
+    g_solver = steady.history[0]["grad_norm"]      # at kern.w
+    rec = dict(phase="dense", partition=partition, shape=[*X.shape],
+               x_bytes=X.nbytes, shard_devices=devices, kernel_modes=modes,
+               outer_iters=len(kern.history),
+               grad_norm_first=kern.history[0]["grad_norm"],
+               w_rel_err_vs_jnp=rel_err(kern.w, ref.w),
+               grad_norm_solver=g_solver, grad_norm_f64=g64,
+               grad_abs_err=abs(g_solver - g64),
+               grad_err_bound=1e-3 * g64 + 64 * np.finfo(np.float32).eps
+               * scale, **timing)
+    emit(rec)
+    check(rec["w_rel_err_vs_jnp"] <= W_REL_TOL,
+          f"dense {partition}: kernel vs jnp w")
+    check(rec["grad_abs_err"] <= rec["grad_err_bound"],
+          f"dense {partition}: gradient norm vs f64")
+    check(g64 <= 1e-3 * rec["grad_norm_first"],
+          f"dense {partition}: gradient did not fall 1000x")
+    check(modes == [ops._mode()],
+          f"dense {partition}: kernels dispatched as {modes}")
+    check(timing["steady_compiles"] == 0, "dense: compiled in steady window")
+    return rec, kern.w
+
+
+def phase_sparse(seed: int, sz: Sizes) -> dict:
+    """CSR DiSCO-F (128x128 tiles, LPT) == the dense solve of the same
+    matrix densified."""
+    X, y, _ = make_sparse_glm_data(sz.sparse_d, sz.sparse_n,
+                                   density=sz.sparse_density, seed=seed)
+    base = dict(partition="features", lam=SPARSE_LAM,
+                max_outer=sz.sparse_outer, grad_tol=0.0, seed=seed)
+    solver = DiscoSolver(X, y, DiscoConfig(ell_block_d=sz.tile,
+                                           ell_block_n=sz.tile, **base))
+    tiles = solver.ell_data.nbytes + solver.ell_dataT.nbytes
+    res, _, timing = timed_fit(solver)
+    del solver
+    gc.collect()
+    dense = solve(X.todense(), y, DiscoConfig(**base))
+    check(timing["steady_compiles"] == 0, "sparse: compiled in steady window")
+    rec = dict(phase="sparse", partition="features", shape=[*X.shape],
+               nnz=X.nnz, tile=[sz.tile, sz.tile], padded_tile_bytes=tiles,
+               imbalance=res.partition_info["imbalance"],
+               outer_iters=len(res.history),
+               grad_norm_last=res.history[-1]["grad_norm"],
+               w_rel_err_vs_dense=rel_err(res.w, dense.w), **timing)
+    emit(rec)
+    check(rec["w_rel_err_vs_dense"] <= SPARSE_REL_TOL, "sparse == dense w")
+    return rec
+
+
+def stream_problem(seed: int, sz: Sizes):
+    return make_sparse_glm_data(sz.stream_d, sz.stream_n,
+                                density=sz.stream_density, seed=seed + 1)
+
+
+def stream_config(seed: int, sz: Sizes) -> DiscoConfig:
+    return DiscoConfig(partition="samples", lam=SPARSE_LAM,
+                       max_outer=sz.stream_outer, grad_tol=STREAM_GRAD_TOL,
+                       seed=seed,
+                       ell_block_d=sz.tile, ell_block_n=sz.tile,
+                       partition_block=sz.stream_chunk,
+                       stream_chunk_size=sz.stream_chunk)
+
+
+def phase_stream(seed: int, sz: Sizes, workdir: str, mesh=None,
+                 data=None):
+    """Out-of-core DiSCO-S from a ShardStore == the in-memory solve, both
+    run to ``STREAM_GRAD_TOL``. The steady window is one outer iteration
+    restarted at the solution."""
+    X, y, _ = data or stream_problem(seed, sz)
+    cfg = stream_config(seed, sz)
+    store = ShardStore.from_csr(X, y, tempfile.mkdtemp(dir=workdir),
+                                axis="samples", chunk_size=sz.stream_chunk)
+    solver = DiscoSolver.from_store(store, cfg, mesh)
+    res, _, timing = timed_fit(solver)
+    devices = shard_devices(solver.y)
+    del solver
+    gc.collect()
+    mem = solve(X, y, cfg, mesh)
+    st = res.stream_stats
+    rec = dict(phase="stream", partition="samples", shape=[*X.shape],
+               nnz=X.nnz, chunk=sz.stream_chunk, chunks=store.n_chunks,
+               store_bytes=store.data_bytes(), shard_devices=devices,
+               outer_iters=len(res.history),
+               grad_norm_last=res.history[-1]["grad_norm"],
+               inmemory_outer_iters=len(mem.history), passes=st["passes"],
+               bytes_loaded=st["bytes_loaded"],
+               peak_stream_bytes=st["peak_bytes"],
+               w_rel_err_vs_inmemory=rel_err(res.w, mem.w), **timing)
+    emit(rec)
+    check(len(res.history) >= 2, "stream: fewer than 2 outer iterations")
+    check(res.converged and mem.converged,
+          f"stream: no convergence to {STREAM_GRAD_TOL} in {sz.stream_outer}"
+          " outer iterations")
+    check(rec["w_rel_err_vs_inmemory"] <= SPARSE_REL_TOL,
+          "streamed == in-memory w")
+    check(timing["steady_compiles"] == 0, "stream: compiled in steady window")
+    return rec, res
+
+
+def phase_score(sz: Sizes, X, res, cfg: DiscoConfig, workdir: str) -> dict:
+    """Publish a fit, serve its samples in micro-batches, compare with
+    the NumPy oracle."""
+    reg = ModelRegistry(tempfile.mkdtemp(dir=workdir))
+    version = reg.publish(res, cfg)
+    pub = reg.load()
+    rows = X.transpose()                              # (n, d): one per sample
+    requests = []
+    for i in range(sz.requests):
+        lo, hi = rows.indptr[i], rows.indptr[i + 1]
+        requests.append(ScoreRequest(indices=rows.indices[lo:hi],
+                                     values=rows.data[lo:hi]))
+    engine = ScoringEngine(reg, batch=sz.batch, block_d=sz.tile)
+    CLOCK.take()
+    engine.score(requests[: sz.batch])                # compile
+    compile_s, _ = CLOCK.take()
+    t0 = time.perf_counter()
+    got = engine.score(requests)
+    batch_s = (time.perf_counter() - t0) / -(-sz.requests // sz.batch)
+    _, steady_compiles = CLOCK.take()
+    want = oracle_margins(requests, pub.w)
+    rec = dict(phase="score", version=version, d=len(pub.w),
+               requests=sz.requests, batch=sz.batch,
+               w_bit_identical=pub.w.tobytes() == np.asarray(res.w).tobytes(),
+               margin_rel_err=float(np.abs(got - want).max())
+               / max(float(np.abs(want).max()), 1e-30),
+               compile_s=compile_s, steady_compiles=steady_compiles,
+               seconds_per_batch=batch_s, **memory())
+    emit(rec)
+    check(rec["w_bit_identical"], "registry round trip")
+    check(rec["margin_rel_err"] <= SCORE_REL_TOL, "margins vs oracle")
+    check(steady_compiles == 0, "score: compiled in steady window")
+    return rec
+
+
+def run_one_chip(seed: int, sz: Sizes, workdir: str) -> None:
+    phase_dense(seed, sz)
+    phase_sparse(seed, sz)
+    data = stream_problem(seed, sz)
+    _, res = phase_stream(seed, sz, workdir, data=data)
+    phase_score(sz, data[0], res, stream_config(seed, sz), workdir)
+
+
+def run_four_chips(seed: int, sz: Sizes, workdir: str) -> None:
+    """Dense DiSCO-S and DiSCO-F at the phase-2 shape and the streamed
+    DiSCO-S solve, each on a 4-chip mesh and on ``jax.devices()[:1]``."""
+    check(jax.device_count() == 4,
+          f"--chips 4 needs 4 devices, found {jax.device_count()}")
+    dense = make_glm_data(sz.dense_d, sz.dense_n, seed=seed)
+    sparse = stream_problem(seed, sz)
+    for name, partition, axis in (("dense", "samples", "data"),
+                                  ("dense", "features", "model"),
+                                  ("stream", "samples", "data")):
+        w = {}
+        for chips in (4, 1):
+            devices = jax.devices()[:chips]
+            mesh = make_mesh((chips,), (axis,), devices=devices)
+            if name == "dense":
+                rec, w[chips] = phase_dense(seed, sz, partition, mesh, dense)
+            else:
+                rec, res = phase_stream(seed, sz, workdir, mesh, sparse)
+                w[chips] = res.w
+            check(rec["shard_devices"] == sorted(d.id for d in devices),
+                  f"{name} {partition}: shards on {rec['shard_devices']}")
+        rec = dict(phase="four_vs_one", run=f"{name}/{partition}",
+                   w_rel_err=rel_err(w[4], w[1]))
+        emit(rec)
+        check(rec["w_rel_err"] <= W_REL_TOL,
+              f"{name} {partition}: 4 chips vs 1")
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
+    args = ap.parse_args(argv)
+
+    device = device_gate()
+    enable_compile_cache()
+    sz = Sizes()
+    with tempfile.TemporaryDirectory() as workdir:
+        if args.chips == 4:
+            run_four_chips(args.seed, sz, workdir)
+        else:
+            run_one_chip(args.seed, sz, workdir)
+    device["count"] = len(jax.devices())
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -18,13 +18,14 @@ import tempfile
 
 import numpy as np
 
-os.environ.setdefault("REPRO_KERNEL_MODE", "ref")   # fast CPU path
-
 from repro.core import DiscoConfig, DiscoSolver
 from repro.data.sparse import CSRMatrix, make_sparse_glm_data
 from repro.data.store import ShardStore
 from repro.glm_serve import (MicroBatchScheduler, ModelRegistry,
                              RefitLoop, ScoreRequest, ScoringEngine)
+from repro.kernels.ops import ref_kernels_off_tpu
+
+ref_kernels_off_tpu()   # the fast jnp path off the chip
 
 D, N, CHUNK, BATCH = 64, 512, 64, 16
 

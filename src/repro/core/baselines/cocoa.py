@@ -23,8 +23,7 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import comm
-from repro.core.disco import _single_axis_mesh
-from repro.utils.compat import pcast, shard_map
+from repro.launch.mesh import make_mesh
 from repro.utils.padding import pad_to_multiple
 from repro.core.losses import get_loss
 
@@ -45,7 +44,8 @@ def cocoa_fit(X, y, cfg: CocoaConfig | None = None, mesh: Mesh | None = None):
     X = np.asarray(X)
     y = np.asarray(y)
     d, n = X.shape
-    mesh = mesh if mesh is not None else _single_axis_mesh("data")
+    mesh = mesh if mesh is not None else make_mesh((jax.device_count(),),
+                                                   ("data",))
     m = mesh.shape["data"]
     sigma_p = float(m)  # safe aggregation parameter for gamma = 1 (adding)
 
@@ -87,8 +87,8 @@ def cocoa_fit(X, y, cfg: CocoaConfig | None = None, mesh: Mesh | None = None):
             hi = jnp.where(root_right, hi, mid)
             return lo, hi
 
-        lo = pcast(jnp.asarray(eps, xv.dtype), "data", to="varying")
-        hi = pcast(jnp.asarray(1.0 - eps, xv.dtype), "data", to="varying")
+        lo = lax.pcast(jnp.asarray(eps, xv.dtype), "data", to="varying")
+        hi = lax.pcast(jnp.asarray(1.0 - eps, xv.dtype), "data", to="varying")
         lo, hi = lax.fori_loop(0, 40, body, (lo, hi))
         b = 0.5 * (lo + hi)
         return b * yi - alpha_i
@@ -110,7 +110,7 @@ def cocoa_fit(X, y, cfg: CocoaConfig | None = None, mesh: Mesh | None = None):
             dxa = dxa + delta * xi
             return alpha, dxa
 
-        dxa0 = pcast(jnp.zeros_like(w), "data", to="varying")
+        dxa0 = lax.pcast(jnp.zeros_like(w), "data", to="varying")
         alpha_loc, dxa = lax.fori_loop(0, H, body, (alpha_loc, dxa0))
         dw = lax.psum(dxa, "data") / lam_n        # the ONE d-vector reduceAll
         w_new = w + dw
@@ -123,7 +123,7 @@ def cocoa_fit(X, y, cfg: CocoaConfig | None = None, mesh: Mesh | None = None):
             + 0.5 * cfg.lam * jnp.vdot(w_new, w_new)
         return alpha_loc, w_new, dict(grad_norm=gnorm, f=fval)
 
-    fn = jax.jit(shard_map(
+    fn = jax.jit(jax.shard_map(
         step_local, mesh=mesh,
         in_specs=(P(None, "data"), P("data"), P("data"), P("data"),
                   P("data"), P(), P()),

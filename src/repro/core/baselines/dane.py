@@ -22,8 +22,7 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import comm
-from repro.core.disco import _single_axis_mesh
-from repro.utils.compat import pcast, shard_map
+from repro.launch.mesh import make_mesh
 from repro.utils.padding import pad_to_multiple
 from repro.core.losses import get_loss
 
@@ -69,7 +68,8 @@ def dane_fit(X, y, cfg: DaneConfig | None = None, mesh: Mesh | None = None,
     X = np.asarray(X)
     y = np.asarray(y)
     d, n = X.shape
-    mesh = mesh if mesh is not None else _single_axis_mesh("data")
+    mesh = mesh if mesh is not None else make_mesh((jax.device_count(),),
+                                                   ("data",))
     m = mesh.shape["data"]
 
     Xp, npad = pad_to_multiple(X, 1, m)
@@ -107,7 +107,7 @@ def dane_fit(X, y, cfg: DaneConfig | None = None, mesh: Mesh | None = None,
             step = _local_cg(local_hvp_at(v), grad_h, cfg.local_cg_iters)
             return v - step
 
-        w_var = pcast(w, "data", to="varying")  # carry becomes shard-local
+        w_var = lax.pcast(w, "data", to="varying")  # carry becomes shard-local
         wj = lax.fori_loop(0, cfg.local_newton_iters, newton_body, w_var)
         w_new = lax.pmean(wj, "data")                   # round 2 (reduceAll d)
 
@@ -116,7 +116,7 @@ def dane_fit(X, y, cfg: DaneConfig | None = None, mesh: Mesh | None = None,
             + 0.5 * cfg.lam * jnp.vdot(w, w)
         return w_new, dict(grad_norm=gnorm, f=fval)
 
-    fn = jax.jit(shard_map(
+    fn = jax.jit(jax.shard_map(
         step_local, mesh=mesh,
         in_specs=(P(None, "data"), P("data"), P("data"), P()),
         out_specs=(P(), P())))

@@ -15,8 +15,7 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import comm
-from repro.core.disco import _single_axis_mesh
-from repro.utils.compat import shard_map
+from repro.launch.mesh import make_mesh
 from repro.utils.padding import pad_to_multiple
 from repro.core.losses import get_loss
 
@@ -36,7 +35,8 @@ def gd_fit(X, y, cfg: GDConfig | None = None, mesh: Mesh | None = None):
     X = np.asarray(X)
     y = np.asarray(y)
     d, n = X.shape
-    mesh = mesh if mesh is not None else _single_axis_mesh("data")
+    mesh = mesh if mesh is not None else make_mesh((jax.device_count(),),
+                                                   ("data",))
     m = mesh.shape["data"]
 
     Xp, npad = pad_to_multiple(X, 1, m)
@@ -66,7 +66,7 @@ def gd_fit(X, y, cfg: GDConfig | None = None, mesh: Mesh | None = None):
             + 0.5 * cfg.lam * jnp.vdot(w, w)
         return w - step * g, dict(grad_norm=gnorm, f=fval)
 
-    fn = jax.jit(shard_map(
+    fn = jax.jit(jax.shard_map(
         step_local, mesh=mesh,
         in_specs=(P(None, "data"), P("data"), P("data"), P()),
         out_specs=(P(), P())))

@@ -23,7 +23,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import comm
@@ -32,6 +32,8 @@ from repro.core.losses import get_loss
 from repro.core.pcg import pcg_features, pcg_samples
 from repro.obs import tracer as obs
 from repro.data.partition import Partition, make_partition
+from repro.kernels import ops as kops
+from repro.launch.mesh import make_mesh
 from repro.data.sparse import (CSRMatrix, EllPair, build_shard_ell_pairs,
                                hvp_tile_dtype, shard_csrs_from_partition)
 from repro.robust.checkpoint import (CheckpointState, load_checkpoint,
@@ -39,7 +41,6 @@ from repro.robust.checkpoint import (CheckpointState, load_checkpoint,
 from repro.robust.faults import FaultInjector, FaultPlan
 from repro.robust.retry import RetryPolicy
 from repro.robust.straggler import ChunkTimingLedger, ElasticReplanner
-from repro.utils.compat import shard_map
 from repro.utils.padding import pad_to_multiple
 
 
@@ -209,8 +210,54 @@ class DiscoResult:
         return np.array([h["comm_rounds_cum"] for h in self.history])
 
 
-def _single_axis_mesh(axis_name: str) -> Mesh:
-    return jax.make_mesh((len(jax.devices()),), (axis_name,))
+def _chunk_mv(data, cols, v, c=None, *, mode):
+    """One chunk's ``A (c .* v)``: matvec or, for a 2-D ``v``, matmat."""
+    op = kops.ell_matmat if v.ndim == 2 else kops.ell_matvec
+    return op(data, cols, v, c, mode=mode)
+
+
+def _chunk_hvp(dataT, colsT, c, u, *, mode):
+    """One chunk's whole HVP from its transposed layout (fused kernel)."""
+    op = kops.ell_hvp_mm if u.ndim == 2 else kops.ell_hvp
+    return op(dataT, colsT, u, c, mode=mode)
+
+
+def _chunk_hvp_two_pass(dataT, colsT, data, cols, c, u, *, mode):
+    """One chunk's HVP as two passes: ``X_t (c .* (X_t^T u))``."""
+    return _chunk_mv(data, cols, _chunk_mv(dataT, colsT, u, mode=mode), c,
+                     mode=mode)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "body", "mesh", "axis", "n_stacked", "n_sliced", "chunk", "reduce",
+    "mode"))
+def _on_shards(t, *arrays, body, mesh, axis, n_stacked, n_sliced, chunk,
+               reduce, mode):
+    """Step ``t`` of a streamed pass: ``body`` on every shard's device.
+
+    A Pallas kernel cannot be partitioned by the compiler, so each shard's
+    chunk products run inside a ``shard_map``. ``arrays`` are, in order,
+    ``n_stacked`` arrays with a leading shard axis (the step's tiles, or
+    per-shard inputs), ``n_sliced`` vectors on the permuted chunked axis
+    (each shard reads the ``t``-th chunk of its own range) and whole,
+    replicated operands; ``body`` takes them in that order. Returns the
+    sum over shards (``reduce``) or their ``(m, ...)`` stack.
+    """
+    n_whole = len(arrays) - n_stacked - n_sliced
+
+    def local(t, *arrs):
+        stacked = [a[0] for a in arrs[:n_stacked]]
+        sliced = [lax.dynamic_slice_in_dim(a, t * chunk, chunk)
+                  for a in arrs[n_stacked:n_stacked + n_sliced]]
+        out = body(*stacked, *sliced, *arrs[n_stacked + n_sliced:],
+                   mode=mode)
+        return lax.psum(out, axis) if reduce else out[None]
+
+    in_specs = ((P(),) + (P(axis),) * (n_stacked + n_sliced)
+                + (P(),) * n_whole)
+    return shard_map(local, mesh=mesh, in_specs=in_specs,
+                     out_specs=P() if reduce else P(axis),
+                     check_vma=False)(t, *arrays)
 
 
 def _shard_subsample_mask(key, frac, shape, axis_name):
@@ -269,7 +316,8 @@ class DiscoSolver:
 
         axis = "model" if cfg.partition == "features" else "data"
         self.axis = axis
-        self.mesh = mesh if mesh is not None else _single_axis_mesh(axis)
+        self.mesh = mesh if mesh is not None else make_mesh(
+            (jax.device_count(),), (axis,))
         self.m = self.mesh.shape[axis]
         self._part: Partition | None = None
 
@@ -466,12 +514,12 @@ class DiscoSolver:
                              delta=res.delta, pcg_r_norm=res.r_norm)
                 return w_new, stats
 
-            fn = shard_map(
+            fn = jax.jit(shard_map(
                 step_local, mesh=self.mesh,
                 in_specs=(P(axis, None), P(axis, None), P(axis, None),
                           P(), P(), P(axis), P()),
                 out_specs=(P(axis), P()),
-                check_vma=False)  # pallas_call outputs carry no vma info
+                check_vma=False))  # pallas_call outputs carry no vma info
 
             def step(w, key):
                 return fn(self.X, self.X_hvp, self.X_tau, self.y,
@@ -508,18 +556,20 @@ class DiscoSolver:
                              delta=res.delta, pcg_r_norm=res.r_norm)
                 return w_new, stats
 
-            fn = shard_map(
+            fn = jax.jit(shard_map(
                 step_local, mesh=self.mesh,
                 in_specs=(P(None, axis), P(None, axis), P(axis), P(axis),
                           P(), P(), P(), P()),
                 out_specs=(P(), P()),
-                check_vma=False)  # pallas_call outputs carry no vma info
+                check_vma=False))  # pallas_call outputs carry no vma info
 
             def step(w, key):
                 return fn(self.X, self.X_hvp, self.y, self.weights,
                           self.X_tau, self.y_tau, w, key)
 
-        return jax.jit(step)
+        # the device data enter the jitted program as arguments: an array
+        # closed over by a jitted function is embedded as a constant
+        return step
 
     # ------------------------------------------------------------------
     def _build_step_sparse(self):
@@ -530,7 +580,6 @@ class DiscoSolver:
         cfg, loss, axis = self.cfg, self.loss, self.axis
         n, tau = self.n, self.tau
         frac = cfg.hessian_subsample
-        from repro.kernels import ops as kops
 
         if cfg.partition == "features":
             def step_local(ed, ec, edT, ecT, edh, edTh, X_tau_loc, y,
@@ -567,7 +616,7 @@ class DiscoSolver:
                              delta=res.delta, pcg_r_norm=res.r_norm)
                 return w_new, stats
 
-            fn = shard_map(
+            fn = jax.jit(shard_map(
                 step_local, mesh=self.mesh,
                 in_specs=(P(axis, None, None, None, None), P(axis, None),
                           P(axis, None, None, None, None), P(axis, None),
@@ -575,7 +624,7 @@ class DiscoSolver:
                           P(axis, None, None, None, None),
                           P(axis, None), P(), P(), P(), P(axis), P()),
                 out_specs=(P(axis), P()),
-                check_vma=False)  # pallas_call outputs carry no vma info
+                check_vma=False))  # pallas_call outputs carry no vma info
 
             def step(w, key):
                 return fn(self.ell_data, self.ell_cols, self.ell_dataT,
@@ -619,7 +668,7 @@ class DiscoSolver:
                              delta=res.delta, pcg_r_norm=res.r_norm)
                 return w_new, stats
 
-            fn = shard_map(
+            fn = jax.jit(shard_map(
                 step_local, mesh=self.mesh,
                 in_specs=(P(axis, None, None, None, None), P(axis, None),
                           P(axis, None, None, None, None), P(axis, None),
@@ -627,7 +676,7 @@ class DiscoSolver:
                           P(axis, None, None, None, None),
                           P(axis), P(axis), P(), P(), P(), P()),
                 out_specs=(P(), P()),
-                check_vma=False)  # pallas_call outputs carry no vma info
+                check_vma=False))  # pallas_call outputs carry no vma info
 
             def step(w, key):
                 return fn(self.ell_data, self.ell_cols, self.ell_dataT,
@@ -635,7 +684,9 @@ class DiscoSolver:
                           self.ell_dataT_h, self.y, self.weights,
                           self.X_tau, self.y_tau, w, key)
 
-        return jax.jit(step)
+        # the device data enter the jitted program as arguments: an array
+        # closed over by a jitted function is embedded as a constant
+        return step
 
     # ------------------------------------------------------------------
     # out-of-core streaming path (docs/streaming.md)
@@ -687,7 +738,8 @@ class DiscoSolver:
         self.tau = min(cfg.tau, self.n)
         axis = "model" if cfg.partition == "features" else "data"
         self.axis = axis
-        self.mesh = mesh if mesh is not None else _single_axis_mesh(axis)
+        self.mesh = mesh if mesh is not None else make_mesh(
+            (jax.device_count(),), (axis,))
         self.m = self.mesh.shape[axis]
         self._replan_events = []
         self._outer_iter = 0
@@ -796,12 +848,17 @@ class DiscoSolver:
             NamedSharding(self.mesh, P(self.axis, None, None)))
 
     # -- streamed X products (each is one prefetched pass over the store)
-    def _slab(self, vec, s, t):
-        chunk, width = self._plan.chunk_size, self._plan.width_local
-        start = s * width + t * chunk
-        return vec[start: start + chunk]
+    def _shard_step(self, body, t, stacked=(), sliced=(), whole=(),
+                    reduce=True):
+        """One schedule step of a streamed pass, ``body`` run by every
+        shard on its own device (see :func:`_on_shards`)."""
+        return _on_shards(np.int32(t), *stacked, *sliced, *whole,
+                          body=body, mesh=self.mesh, axis=self.axis,
+                          n_stacked=len(stacked), n_sliced=len(sliced),
+                          chunk=self._plan.chunk_size, reduce=reduce,
+                          mode=kops._mode())
 
-    def _stream_xt(self, u, local=False, multi=False, hvp=False):
+    def _stream_xt(self, u, local=False, hvp=False):
         """Pass A — ``z = X^T u`` over the permuted padded axis.
 
         features: streams the transposed chunk layouts and accumulates
@@ -810,47 +867,44 @@ class DiscoSolver:
         (the zero-communication s-step basis operator). ``hvp=True``
         stages the tiles in ``cfg.hvp_dtype`` (the PCG loop's passes).
         """
-        from repro.kernels import ops as kops
-
-        plan, m = self._plan, self.m
-        op = kops.ell_matmat if multi else kops.ell_matvec
-        shape = (self.n_padded, u.shape[1]) if multi else (self.n_padded,)
+        shape = (self.n_padded,) + u.shape[1:]
         if local:
-            shape = (m,) + shape
+            shape = (self.m,) + shape
         acc = jnp.zeros(shape, u.dtype)
-        with plan.stream("tr", hvp=hvp) as pf:
+        with self._plan.stream("tr", hvp=hvp) as pf:
             for t, payload in enumerate(pf):
-                for s in range(m):
-                    contrib = op(payload["dataT"][s], payload["colsT"][s],
-                                 self._slab(u, s, t))
-                    acc = (acc.at[s].add(contrib) if local
-                           else acc + contrib)
+                acc = acc + self._shard_step(
+                    _chunk_mv, t, stacked=(payload["dataT"],
+                                           payload["colsT"]),
+                    sliced=(u,), reduce=not local)
         return acc
 
-    def _stream_x(self, z, coeffs=None, local=False, multi=False,
-                  hvp=False):
+    def _stream_x(self, z, coeffs=None, local=False, hvp=False):
         """Pass B — ``y = X (c .* z)`` back onto the permuted padded axis.
 
         features: streams the forward chunk layouts; each chunk emits its
-        own slab of the output, concatenated in schedule order (exactly
-        the permuted layout). ``local=True`` reads per-shard inputs
+        own slab of the output, laid out in schedule order (exactly the
+        permuted layout). ``local=True`` reads per-shard inputs
         ``z: (m, n_padded)`` (s-step basis operator pass B).
         """
-        from repro.kernels import ops as kops
-
-        plan, m = self._plan, self.m
-        op = kops.ell_matmat if multi else kops.ell_matvec
-        parts = [[None] * plan.n_steps for _ in range(m)]
-        with plan.stream("fwd", hvp=hvp) as pf:
+        whole = () if coeffs is None else (coeffs,)
+        parts = []
+        with self._plan.stream("fwd", hvp=hvp) as pf:
             for t, payload in enumerate(pf):
-                for s in range(m):
-                    zin = z[s] if local else z
-                    parts[s][t] = op(payload["data"][s],
-                                     payload["cols"][s], zin, coeffs)
-        return jnp.concatenate([jnp.concatenate(parts[s])
-                                for s in range(m)])
+                tiles = (payload["data"], payload["cols"])
+                parts.append(self._shard_step(
+                    _chunk_mv, t,
+                    stacked=tiles + ((z,) if local else ()),
+                    whole=(() if local else (z,)) + whole, reduce=False))
+        return self._schedule_order(parts)
 
-    def _stream_hvp_samples(self, u, coeffs, multi=False):
+    def _schedule_order(self, parts):
+        """Per-step ``(m, chunk, ...)`` outputs as one vector on the
+        permuted padded axis: shard-major, then step."""
+        out = jnp.stack(parts, axis=1)                 # (m, T, chunk, ...)
+        return out.reshape((-1,) + out.shape[3:])
+
+    def _stream_hvp_samples(self, u, coeffs):
         """DiSCO-S chunk-local pass: each sample chunk completes both HVP
         directions (``X_t (c_t .* (X_t^T u))``), so one pass over the
         store serves the whole product. With ``cfg.hvp_fused`` only the
@@ -861,58 +915,42 @@ class DiscoSolver:
         global tile geometry, so an oversized chunk row degrades to the
         two-pass kernel stream — never to the ops-level last-resort jnp
         path — and the whole stream takes one consistent shape."""
-        from repro.kernels import ops as kops
-
-        plan, m = self._plan, self.m
+        plan = self._plan
         acc = jnp.zeros(u.shape, u.dtype)
         fused = self.cfg.hvp_fused and plan.fused_hvp_fits(
-            self.d_padded, s=(u.shape[1] if multi else 1))
-        if fused:
-            op = kops.ell_hvp_mm if multi else kops.ell_hvp
-            with plan.stream("tr", hvp=True) as pf:
-                for t, payload in enumerate(pf):
-                    for s in range(m):
-                        acc = acc + op(payload["dataT"][s],
-                                       payload["colsT"][s],
-                                       u, self._slab(coeffs, s, t))
-            return acc
-        op = kops.ell_matmat if multi else kops.ell_matvec
-        with plan.stream("both", hvp=True) as pf:
+            self.d_padded, s=(u.shape[1] if u.ndim == 2 else 1))
+        kind, keys, body = (("tr", ("dataT", "colsT"), _chunk_hvp) if fused
+                            else ("both", ("dataT", "colsT", "data", "cols"),
+                                  _chunk_hvp_two_pass))
+        with plan.stream(kind, hvp=True) as pf:
             for t, payload in enumerate(pf):
-                for s in range(m):
-                    z = op(payload["dataT"][s], payload["colsT"][s], u)
-                    acc = acc + op(payload["data"][s], payload["cols"][s],
-                                   z, self._slab(coeffs, s, t))
+                acc = acc + self._shard_step(
+                    body, t, stacked=tuple(payload[k] for k in keys),
+                    sliced=(coeffs,), whole=(u,))
         return acc
 
     def _stream_margins_samples(self, w):
         """DiSCO-S margins: one 'tr' pass, each chunk emitting its slab
         of the permuted ``(n_padded,)`` margin vector."""
-        from repro.kernels import ops as kops
-
-        plan, m = self._plan, self.m
-        parts = [[None] * plan.n_steps for _ in range(m)]
-        with plan.stream("tr") as pf:
+        parts = []
+        with self._plan.stream("tr") as pf:
             for t, payload in enumerate(pf):
-                for s in range(m):
-                    parts[s][t] = kops.ell_matvec(payload["dataT"][s],
-                                                  payload["colsT"][s], w)
-        return jnp.concatenate([jnp.concatenate(parts[s])
-                                for s in range(m)])
+                parts.append(self._shard_step(
+                    _chunk_mv, t, stacked=(payload["dataT"],
+                                           payload["colsT"]),
+                    whole=(w,), reduce=False))
+        return self._schedule_order(parts)
 
     def _stream_grad_samples(self, d1):
         """DiSCO-S gradient accumulation: one 'fwd' pass of
         ``sum_t X_t d1_t`` (the cross-shard reduce is the accumulation)."""
-        from repro.kernels import ops as kops
-
-        plan, m = self._plan, self.m
         acc = jnp.zeros((self.d_padded,), d1.dtype)
-        with plan.stream("fwd") as pf:
+        with self._plan.stream("fwd") as pf:
             for t, payload in enumerate(pf):
-                for s in range(m):
-                    acc = acc + kops.ell_matvec(payload["data"][s],
-                                                payload["cols"][s],
-                                                self._slab(d1, s, t))
+                acc = acc + self._shard_step(
+                    _chunk_mv, t, stacked=(payload["data"],
+                                           payload["cols"]),
+                    sliced=(d1,))
         return acc
 
     # -- elastic re-planning (docs/robustness.md) ----------------------
@@ -1035,15 +1073,14 @@ class DiscoSolver:
                         self._stream_xt(u, hvp=True), coeffs=c_eff,
                         hvp=True),
                     apply_multi=lambda U: self._stream_x(
-                        self._stream_xt(U, multi=True, hvp=True),
-                        coeffs=c_eff, multi=True, hvp=True),
+                        self._stream_xt(U, hvp=True), coeffs=c_eff,
+                        hvp=True),
                     pass_a=lambda u: self._stream_xt(u, hvp=True),
                     pass_b=lambda z: self._stream_x(
                         z, coeffs=c_eff, hvp=True),
-                    pass_a_multi=lambda U: self._stream_xt(
-                        U, multi=True, hvp=True),
+                    pass_a_multi=lambda U: self._stream_xt(U, hvp=True),
                     pass_b_multi=lambda Z: self._stream_x(
-                        Z, coeffs=c_eff, multi=True, hvp=True))
+                        Z, coeffs=c_eff, hvp=True))
 
                 def hvp(u):
                     return op.apply(u) / n + lam * u
@@ -1108,7 +1145,7 @@ class DiscoSolver:
                     apply=lambda u: self._stream_hvp_samples(
                         u, state["c_eff"]),
                     apply_multi=lambda U: self._stream_hvp_samples(
-                        U, state["c_eff"], multi=True),
+                        U, state["c_eff"]),
                     fused=cfg.hvp_fused)
 
                 def hvp(u):
@@ -1275,9 +1312,9 @@ class DiscoSolver:
             t_it = time.perf_counter()
             with obs.span("newton.outer", outer_iter=k,
                           streaming=bool(self._streaming)):
-                w, stats = self._step(w, sub)
-                # the float() syncs pull the step to completion, so the
-                # span (and iter_s) covers real work, not dispatch
+                # block on the step's outputs, so the span (and iter_s)
+                # covers the device work, not its dispatch
+                w, stats = jax.block_until_ready(self._step(w, sub))
                 stats = {name: float(v) for name, v in stats.items()}
             stats["iter_s"] = time.perf_counter() - t_it
             rounds, floats, spmd = self._comm_costs(int(stats["pcg_iters"]))

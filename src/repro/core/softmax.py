@@ -36,7 +36,7 @@ from repro.core.hvp import (SoftmaxHvpOperator, make_local_operator,
 from repro.core.pcg import (PCGResult, _krylov_columns, _mgs, _pcg_loop,
                             _sstep_loop)
 from repro.data.sparse import hvp_tile_dtype
-from repro.utils.compat import shard_map
+from repro.launch.mesh import make_mesh
 from repro.utils.padding import pad_to_multiple
 
 
@@ -162,8 +162,8 @@ class SoftmaxSolver:
 
         axis = "model" if cfg.partition == "features" else "data"
         self.axis = axis
-        self.mesh = mesh if mesh is not None else jax.make_mesh(
-            (len(jax.devices()),), (axis,))
+        self.mesh = mesh if mesh is not None else make_mesh(
+            (jax.device_count(),), (axis,))
         self.m = self.mesh.shape[axis]
         hdt = hvp_tile_dtype(cfg.hvp_dtype)
 
@@ -292,12 +292,12 @@ class SoftmaxSolver:
                              pcg_r_norm=res.r_norm)
                 return W_new, stats
 
-            fn = shard_map(
+            fn = jax.jit(jax.shard_map(
                 step_local, mesh=self.mesh,
                 in_specs=(P(None, axis), P(None, axis), P(axis, None),
                           P(axis), P(), P(), P()),
                 out_specs=(P(), P()),
-                check_vma=False)
+                check_vma=False))
 
             def step(W):
                 return fn(self.X, self.X_hvp, self.Y1, self.wts,
@@ -381,16 +381,18 @@ class SoftmaxSolver:
                              pcg_r_norm=res.r_norm)
                 return W_new, stats
 
-            fn = shard_map(
+            fn = jax.jit(jax.shard_map(
                 step_local, mesh=self.mesh,
                 in_specs=(P(axis, None), P(axis, None), P(), P(axis, None)),
                 out_specs=(P(axis, None), P()),
-                check_vma=False)
+                check_vma=False))
 
             def step(W):
                 return fn(self.X, self.X_hvp, self.Y1, W)
 
-        return jax.jit(step)
+        # the device data enter the jitted program as arguments: an array
+        # closed over by a jitted function is embedded as a constant
+        return step
 
     # ------------------------------------------------------------------
     def fit(self, W0: np.ndarray | None = None) -> SoftmaxResult:

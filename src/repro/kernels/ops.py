@@ -25,7 +25,22 @@ from repro.kernels import sparse_hvp as _sparse
 from repro.obs import tracer as obs
 from repro.utils.padding import pad_to_multiple as _pad_axis
 
-_seen_dispatch: set[str] = set()    # modes already traced (dedup)
+# mode -> the tracer that already has its dispatch instant (dedup): a fresh
+# tracer, such as one installed by ``obs.enable(reset=True)``, sees each
+# mode once again
+_seen_dispatch: dict[str, object] = {}
+
+
+def ref_kernels_off_tpu() -> None:
+    """Default ``REPRO_KERNEL_MODE`` to ``ref`` unless the backend is a TPU.
+
+    For entry points that demo or time the solver: off the chip the jnp
+    reference is the fast path (interpret mode emulates each kernel in
+    Python), on the chip the kernels stay native. An explicit setting
+    always wins.
+    """
+    if jax.default_backend() != "tpu":
+        os.environ.setdefault("REPRO_KERNEL_MODE", "ref")
 
 
 def _mode() -> str:
@@ -34,10 +49,11 @@ def _mode() -> str:
     if m == "auto":
         resolved = ("native" if jax.default_backend() == "tpu"
                     else "interpret")
-    if obs.enabled() and resolved not in _seen_dispatch:
+    tracer = obs.get_tracer()
+    if obs.enabled() and _seen_dispatch.get(resolved) is not tracer:
         # once per distinct mode, not per call — the eager chunk ops
         # would otherwise flood the trace with identical instants
-        _seen_dispatch.add(resolved)
+        _seen_dispatch[resolved] = tracer
         obs.instant("kernel.dispatch", mode=resolved, env=m)
     return resolved
 
@@ -68,8 +84,8 @@ def ell_fused_fits(wt: int, bc: int, br: int, itemsize: int, u_len: int,
     *padded* (nrb, br, s) blocks resident). Callers that choose a
     *streaming plan* (disco's fused DiSCO-S chunk HVP) should check
     this up front with the plan's global tile geometry and fall back to
-    the two-pass layout stream when it fails, rather than hitting the
-    per-call last-resort fallback below.
+    the two-pass layout stream when it fails: past the budget the
+    wrappers below need the forward layout, or they raise.
     """
     s_pad = 1 if s <= 1 else -(-s // LANE) * LANE
     tile_row = wt * bc * br * itemsize
@@ -331,6 +347,21 @@ def softmax_hvp(X, probs, U, *, lam=0.0, n_global=None, weights=None,
 # Blocked-ELL sparse HVP passes (see data/sparse.py for the layout)
 # ---------------------------------------------------------------------------
 
+# Each public op resolves the kernel mode and the fused-fits decision per
+# call (both read mutable state: the environment and the VMEM budget) and
+# runs a jitted body with them static. Jitting matters where the streamed
+# solver calls these eagerly, chunk by chunk: an un-jitted pallas_call
+# re-traces its kernel, and so compiles again, on every call.
+
+@functools.partial(jax.jit, static_argnames=("mode", "out_dtype"))
+def _ell_matvec_impl(data, cols, v, c, *, mode, out_dtype):
+    if mode == "ref":
+        return _ref.ref_ell_mv(data, cols, v, c, out_dtype=out_dtype)
+    return _sparse.ell_mv(data, cols, v, c,
+                          interpret=(mode == "interpret"),
+                          out_dtype=out_dtype)
+
+
 def ell_matvec(data, cols, v, c=None, *, mode=None, out_dtype=jnp.float32):
     """y = A @ (c .* v) for a blocked-ELL operand (sparse HVP pass).
 
@@ -343,12 +374,20 @@ def ell_matvec(data, cols, v, c=None, *, mode=None, out_dtype=jnp.float32):
     (pass A) — one kernel covers both HVP directions
     (docs/architecture.md#kernels).
     """
-    mode = mode or _mode()
+    return _ell_matvec_impl(data, cols, v, c, mode=mode or _mode(),
+                            out_dtype=out_dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("mode", "out_dtype"))
+def _ell_matmat_impl(data, cols, V, c, *, mode, out_dtype):
     if mode == "ref":
-        return _ref.ref_ell_mv(data, cols, v, c, out_dtype=out_dtype)
-    return _sparse.ell_mv(data, cols, v, c,
-                          interpret=(mode == "interpret"),
-                          out_dtype=out_dtype)
+        return _ref.ref_ell_mm(data, cols, V, c, out_dtype=out_dtype)
+    s = V.shape[1]
+    Vp, _ = _pad_axis(V, 1, LANE)
+    Y = _sparse.ell_mm(data, cols, Vp, c,
+                       interpret=(mode == "interpret"),
+                       out_dtype=out_dtype)
+    return Y[:, :s]
 
 
 def ell_matmat(data, cols, V, c=None, *, mode=None, out_dtype=jnp.float32):
@@ -358,15 +397,41 @@ def ell_matmat(data, cols, V, c=None, *, mode=None, out_dtype=jnp.float32):
     padded to the TPU lane width for the native kernel and cropped back,
     mirroring ``xt_multi``/``x_cz_multi``.
     """
-    mode = mode or _mode()
+    return _ell_matmat_impl(data, cols, V, c, mode=mode or _mode(),
+                            out_dtype=out_dtype)
+
+
+def _fused_or_fwd(dataT, fwd, u_len: int, s: int, mode: str) -> bool:
+    """Whether a fused ELL HVP runs one-pass. Past the VMEM budget it
+    falls back to the two-pass kernels over the forward layout ``fwd``;
+    without ``fwd`` there is no kernel path, so raise (never the jnp
+    reference: that would hide the device)."""
+    if mode == "ref" or _fused_ell_fits(dataT, u_len, s):
+        return True
+    if fwd is None:
+        _, wt, bc, br = dataT.shape
+        raise ValueError(
+            f"fused ELL HVP: a tile row of {wt} ({bc}, {br}) tiles plus "
+            f"the resident vectors exceeds the {_FUSED_VMEM_BYTES}-byte "
+            "VMEM budget; pass fwd=(data, cols) for the two-pass kernels")
+    return False
+
+
+@functools.partial(jax.jit, static_argnames=("mode", "fused", "out_dtype"))
+def _ell_hvp_impl(dataT, colsT, u, c, fwd, *, mode, fused, out_dtype):
     if mode == "ref":
-        return _ref.ref_ell_mm(data, cols, V, c, out_dtype=out_dtype)
-    s = V.shape[1]
-    Vp, _ = _pad_axis(V, 1, LANE)
-    Y = _sparse.ell_mm(data, cols, Vp, c,
-                       interpret=(mode == "interpret"),
-                       out_dtype=out_dtype)
-    return Y[:, :s]
+        if fwd is not None:
+            z = _ref.ref_ell_mv(dataT, colsT, u)
+            return _ref.ref_ell_mv(fwd[0], fwd[1], z, c,
+                                   out_dtype=out_dtype)
+        return _ref.ref_ell_hvp_t(dataT, colsT, u, c, out_dtype=out_dtype)
+    interp = mode == "interpret"
+    if fused:
+        return _sparse.ell_hvp(dataT, colsT, u, c, interpret=interp,
+                               out_dtype=out_dtype)
+    z = _sparse.ell_mv(dataT, colsT, u, interpret=interp)
+    return _sparse.ell_mv(fwd[0], fwd[1], z, c, interpret=interp,
+                          out_dtype=out_dtype)
 
 
 def ell_hvp(dataT, colsT, u, c=None, *, fwd=None, mode=None,
@@ -378,27 +443,37 @@ def ell_hvp(dataT, colsT, u, c=None, *, fwd=None, mode=None,
     halves versus the two-pass ``ell_matvec`` pair (docs/kernels.md).
     ``u`` lives on A's padded row axis (nrb * br), ``c`` on its padded
     column axis. ``fwd=(data, cols)`` optionally supplies the forward
-    layout: it enables the two-pass fallback when the fused working set
-    exceeds the VMEM budget, and makes the 'ref'-mode dispatch take the
-    exact two-oracle-pass path (bit-identical to the two-pass HVP in
-    f32). Returns f32-accumulated ``out_dtype``.
+    layout: it enables the two-pass kernel fallback when the fused
+    working set exceeds the VMEM budget (without it that case raises
+    rather than leaving the kernels), and makes the 'ref'-mode dispatch
+    take the exact two-oracle-pass path (bit-identical to the two-pass
+    HVP in f32). Returns f32-accumulated ``out_dtype``.
     """
     mode = mode or _mode()
+    fused = _fused_or_fwd(dataT, fwd, u.shape[0], 1, mode)
+    return _ell_hvp_impl(dataT, colsT, u, c, fwd, mode=mode, fused=fused,
+                         out_dtype=out_dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("mode", "fused", "out_dtype"))
+def _ell_hvp_mm_impl(dataT, colsT, U, c, fwd, *, mode, fused, out_dtype):
     if mode == "ref":
         if fwd is not None:
-            z = _ref.ref_ell_mv(dataT, colsT, u)
-            return _ref.ref_ell_mv(fwd[0], fwd[1], z, c,
+            Z = _ref.ref_ell_mm(dataT, colsT, U)
+            return _ref.ref_ell_mm(fwd[0], fwd[1], Z, c,
                                    out_dtype=out_dtype)
-        return _ref.ref_ell_hvp_t(dataT, colsT, u, c, out_dtype=out_dtype)
+        return _ref.ref_ell_hvp_mm_t(dataT, colsT, U, c,
+                                     out_dtype=out_dtype)
     interp = mode == "interpret"
-    if not _fused_ell_fits(dataT, u.shape[0]):
-        if fwd is not None:
-            z = _sparse.ell_mv(dataT, colsT, u, interpret=interp)
-            return _sparse.ell_mv(fwd[0], fwd[1], z, c, interpret=interp,
-                                  out_dtype=out_dtype)
-        return _ref.ref_ell_hvp_t(dataT, colsT, u, c, out_dtype=out_dtype)
-    return _sparse.ell_hvp(dataT, colsT, u, c, interpret=interp,
-                           out_dtype=out_dtype)
+    s = U.shape[1]
+    Up, _ = _pad_axis(U, 1, LANE)
+    if fused:
+        Y = _sparse.ell_hvp_mm(dataT, colsT, Up, c, interpret=interp,
+                               out_dtype=out_dtype)
+        return Y[:, :s]
+    Z = _sparse.ell_mm(dataT, colsT, Up, c=None, interpret=interp)[:, :s]
+    return _ell_matmat_impl(fwd[0], fwd[1], Z, c, mode=mode,
+                            out_dtype=out_dtype)
 
 
 def ell_hvp_mm(dataT, colsT, U, c=None, *, fwd=None, mode=None,
@@ -411,28 +486,9 @@ def ell_hvp_mm(dataT, colsT, U, c=None, *, fwd=None, mode=None,
     read serves both directions of all s probe vectors.
     """
     mode = mode or _mode()
-    if mode == "ref":
-        if fwd is not None:
-            Z = _ref.ref_ell_mm(dataT, colsT, U)
-            return _ref.ref_ell_mm(fwd[0], fwd[1], Z, c,
-                                   out_dtype=out_dtype)
-        return _ref.ref_ell_hvp_mm_t(dataT, colsT, U, c,
-                                     out_dtype=out_dtype)
-    interp = mode == "interpret"
-    s = U.shape[1]
-    if not _fused_ell_fits(dataT, U.shape[0], s):
-        if fwd is not None:
-            Z = _sparse.ell_mm(dataT, colsT,
-                               _pad_axis(U, 1, LANE)[0], c=None,
-                               interpret=interp)[:, :s]
-            return ell_matmat(fwd[0], fwd[1], Z, c, mode=mode,
-                              out_dtype=out_dtype)
-        return _ref.ref_ell_hvp_mm_t(dataT, colsT, U, c,
-                                     out_dtype=out_dtype)
-    Up, _ = _pad_axis(U, 1, LANE)
-    Y = _sparse.ell_hvp_mm(dataT, colsT, Up, c, interpret=interp,
-                           out_dtype=out_dtype)
-    return Y[:, :s]
+    fused = _fused_or_fwd(dataT, fwd, U.shape[0], U.shape[1], mode)
+    return _ell_hvp_mm_impl(dataT, colsT, U, c, fwd, mode=mode,
+                            fused=fused, out_dtype=out_dtype)
 
 
 # ---------------------------------------------------------------------------

@@ -60,6 +60,20 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
+def _vec_block(size, block_of):
+    """Block spec of one ``(1, size)`` chunk of a vector stored as
+    ``(n_chunks, 1, size)``; ``block_of`` maps the grid indices (and the
+    prefetched ``cols``) to the chunk index.
+
+    The leading axis is squeezed out of the block, so the kernel sees a
+    ``(1, size)`` ref, and the last two block dims equal the array's —
+    the TPU tiling rule that a ``(1, size)`` block of an
+    ``(n_chunks, size)`` array would break.
+    """
+    return pl.BlockSpec((None, 1, size),
+                        lambda *idx: (block_of(*idx), 0, 0))
+
+
 # ---------------------------------------------------------------------------
 # generalized blocked-ELL matvec:  y = A (c .* v)
 # ---------------------------------------------------------------------------
@@ -100,17 +114,17 @@ def ell_mv(data, cols, v, c=None, *, interpret=False,
         grid=(nb, w),
         in_specs=[
             pl.BlockSpec((1, 1, br, bc), lambda i, k, cols: (i, k, 0, 0)),
-            pl.BlockSpec((1, bc), lambda i, k, cols: (cols[i, k], 0)),
-            pl.BlockSpec((1, bc), lambda i, k, cols: (cols[i, k], 0)),
+            _vec_block(bc, lambda i, k, cols: cols[i, k]),
+            _vec_block(bc, lambda i, k, cols: cols[i, k]),
         ],
-        out_specs=pl.BlockSpec((1, br), lambda i, k, cols: (i, 0)),
+        out_specs=_vec_block(br, lambda i, k, cols: i),
     )
     out = pl.pallas_call(
         _ell_mv_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((nb, br), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((nb, 1, br), jnp.float32),
         interpret=interpret,
-    )(cols, data, c.reshape(ncb, bc), v.reshape(ncb, bc))
+    )(cols, data, c.reshape(ncb, 1, bc), v.reshape(ncb, 1, bc))
     return out.reshape(nb * br).astype(out_dtype)
 
 
@@ -153,7 +167,7 @@ def ell_mm(data, cols, V, c=None, *, interpret=False,
         grid=(nb, w),
         in_specs=[
             pl.BlockSpec((1, 1, br, bc), lambda i, k, cols: (i, k, 0, 0)),
-            pl.BlockSpec((1, bc), lambda i, k, cols: (cols[i, k], 0)),
+            _vec_block(bc, lambda i, k, cols: cols[i, k]),
             pl.BlockSpec((bc, s), lambda i, k, cols: (cols[i, k], 0)),
         ],
         out_specs=pl.BlockSpec((1, br, s), lambda i, k, cols: (i, 0, 0)),
@@ -163,7 +177,7 @@ def ell_mm(data, cols, V, c=None, *, interpret=False,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((nb, br, s), jnp.float32),
         interpret=interpret,
-    )(cols, data, c.reshape(ncb, bc), V)
+    )(cols, data, c.reshape(ncb, 1, bc), V)
     return out.reshape(nb * br, s).astype(out_dtype)
 
 
@@ -226,7 +240,7 @@ def ell_hvp(dataT, colsT, u, c=None, *, interpret=False,
         grid=(ncb,),
         in_specs=[
             pl.BlockSpec((1, wt, bc, br), lambda j, cols: (j, 0, 0, 0)),
-            pl.BlockSpec((1, bc), lambda j, cols: (j, 0)),
+            _vec_block(bc, lambda j, cols: j),
             pl.BlockSpec((nrb, br), lambda j, cols: (0, 0)),
         ],
         out_specs=pl.BlockSpec((nrb, br), lambda j, cols: (0, 0)),
@@ -236,7 +250,7 @@ def ell_hvp(dataT, colsT, u, c=None, *, interpret=False,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((nrb, br), jnp.float32),
         interpret=interpret,
-    )(colsT, dataT, c.reshape(ncb, bc),
+    )(colsT, dataT, c.reshape(ncb, 1, bc),
       u.astype(dataT.dtype).reshape(nrb, br))
     return out.reshape(nrb * br).astype(out_dtype)
 
@@ -285,7 +299,7 @@ def ell_hvp_mm(dataT, colsT, U, c=None, *, interpret=False,
         grid=(ncb,),
         in_specs=[
             pl.BlockSpec((1, wt, bc, br), lambda j, cols: (j, 0, 0, 0)),
-            pl.BlockSpec((1, bc), lambda j, cols: (j, 0)),
+            _vec_block(bc, lambda j, cols: j),
             pl.BlockSpec((nrb, br, s), lambda j, cols: (0, 0, 0)),
         ],
         out_specs=pl.BlockSpec((nrb, br, s), lambda j, cols: (0, 0, 0)),
@@ -295,6 +309,6 @@ def ell_hvp_mm(dataT, colsT, U, c=None, *, interpret=False,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((nrb, br, s), jnp.float32),
         interpret=interpret,
-    )(colsT, dataT, c.reshape(ncb, bc),
+    )(colsT, dataT, c.reshape(ncb, 1, bc),
       U.astype(dataT.dtype).reshape(nrb, br, s))
     return out.reshape(nrb * br, s).astype(out_dtype)

@@ -47,7 +47,6 @@ from repro.launch.mesh import make_production_mesh
 from repro.models import decode_step, forward
 from repro.models import policy as actpolicy
 from repro.train.losses import lm_loss
-from repro.utils.compat import cost_analysis_dict
 from repro.train.sharding import (batch_pspec_for, cache_pspecs,
                                   param_pspecs)
 
@@ -158,7 +157,7 @@ def _costs(cfg, shape_name, mesh) -> dict:
         fn, args = build_probe(cfg, shape_name, mesh)
         lowered = fn.lower(*args)
     compiled = lowered.compile()
-    cost = cost_analysis_dict(compiled)
+    cost = compiled.cost_analysis()
     colls = collective_stats(compiled.as_text())
     return {"flops": cost.get("flops", 0.0),
             "bytes": cost.get("bytes accessed", 0.0),

@@ -38,7 +38,6 @@ from repro.models import policy as actpolicy
 from repro.train.losses import lm_loss
 from repro.train.sharding import (batch_pspec_for, cache_pspecs,
                                   param_pspecs)
-from repro.utils.compat import cost_analysis_dict
 
 # ---------------------------------------------------------------------------
 # HLO collective parsing
@@ -230,7 +229,7 @@ def run_combo(arch: str, shape_name: str, multi_pod: bool,
     t_compile = time.perf_counter() - t0
 
     mem = compiled.memory_analysis()
-    cost = cost_analysis_dict(compiled)
+    cost = compiled.cost_analysis()
     colls = collective_stats(compiled.as_text())
 
     n_dev = mesh.size

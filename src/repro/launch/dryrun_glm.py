@@ -37,7 +37,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.core.losses import get_loss
 from repro.core.pcg import PCGResult, pcg_features, pcg_samples
 from repro.launch.dryrun import collective_stats
-from repro.utils.compat import shard_map
+from repro.launch.mesh import make_mesh
 
 D_GLOBAL = 1 << 20          # 1,048,576 features
 N_GLOBAL = 1 << 18          # 262,144 samples
@@ -46,9 +46,8 @@ PCG_ITERS = 16              # fixed trip count so the HLO while-loop is bounded
 
 
 def _flat_mesh(n_dev: int, axis: str) -> Mesh:
-    devices = jax.devices()
-    assert len(devices) >= n_dev
-    return Mesh(np.asarray(devices[:n_dev]), (axis,))
+    assert jax.device_count() >= n_dev
+    return make_mesh((n_dev,), (axis,))
 
 
 def build_step(partition: str, mesh: Mesh, loss_name="logistic",
@@ -73,7 +72,7 @@ def build_step(partition: str, mesh: Mesh, loss_name="logistic",
                                axis_name=axis, precond="woodbury")
             return w_loc - res.v / (1.0 + res.delta)
 
-        fn = shard_map(
+        fn = jax.shard_map(
             step, mesh=mesh,
             in_specs=(P(axis, None), P(axis), P(), P()),
             out_specs=P(axis), check_vma=False)
@@ -97,7 +96,7 @@ def build_step(partition: str, mesh: Mesh, loss_name="logistic",
                               axis_name=axis, precond="woodbury")
             return w - res.v / (1.0 + res.delta)
 
-        fn = shard_map(
+        fn = jax.shard_map(
             step, mesh=mesh,
             in_specs=(P(None, axis), P(axis), P(), P(), P()),
             out_specs=P(), check_vma=False)

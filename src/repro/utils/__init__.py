@@ -1,5 +1,4 @@
-"""Small shared utilities (padding, version compatibility)."""
-from repro.utils.compat import pcast, shard_map
+"""Small shared utilities (padding)."""
 from repro.utils.padding import pad_to_multiple
 
-__all__ = ["pad_to_multiple", "pcast", "shard_map"]
+__all__ = ["pad_to_multiple"]

@@ -137,10 +137,11 @@ _MASK_SCRIPT = textwrap.dedent("""
     import numpy as np
     from jax.sharding import PartitionSpec as P
     assert len(jax.devices()) == 4
+    from repro.launch.mesh import make_mesh
     from repro.core.disco import _shard_subsample_mask
-    from repro.utils.compat import shard_map
+    from jax import shard_map
 
-    mesh = jax.make_mesh((4,), ("data",))
+    mesh = make_mesh((4,), ("data",))
 
     def body(key):
         m = _shard_subsample_mask(key, 0.5, (64,), "data")
@@ -179,13 +180,14 @@ _SSTEP_4DEV_SCRIPT = textwrap.dedent("""
     import numpy as np
     import jax
     assert len(jax.devices()) == 4
+    from repro.launch.mesh import make_mesh
     from repro.core import DiscoConfig, DiscoSolver
     from repro.data.synthetic import make_glm_data
 
     X, y, _ = make_glm_data(d=64, n=320, seed=0)
     kw = dict(loss="logistic", lam=1e-3, tau=64, max_outer=6, grad_tol=0.0)
     for partition, axis in (("features", "model"), ("samples", "data")):
-        mesh4 = jax.make_mesh((4,), (axis,))
+        mesh4 = make_mesh((4,), (axis,))
         r1 = DiscoSolver(X, y, DiscoConfig(partition=partition, **kw),
                          mesh=mesh4).fit()
         rs = DiscoSolver(X, y, DiscoConfig(partition=partition,

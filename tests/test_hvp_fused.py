@@ -68,8 +68,13 @@ def test_dense_fused_multi_matches_oracle(rng, s):
     np.testing.assert_allclose(np.asarray(got), want, atol=1e-4, rtol=1e-4)
     # column k of the batched fused HVP == the single-vector fused HVP
     one = kops.x_c_xt_u(X, c, U[:, 0], block_n=128)
-    np.testing.assert_allclose(np.asarray(got[:, 0]), np.asarray(one),
-                               atol=1e-5, rtol=1e-5)
+    # Both kernels accumulate the same d*n products in f32, in different
+    # orders, so each may be off by about eps32 * sum|terms|: bound the
+    # gap by that per element, not by a fixed 1e-5 (values reach ~1e2).
+    Xa = np.abs(Xf)
+    scale = Xa @ (np.asarray(c) * (Xa.T @ np.abs(np.asarray(U[:, 0]))))
+    gap = np.abs(np.asarray(got[:, 0]) - np.asarray(one))
+    assert np.all(gap <= np.finfo(np.float32).eps * scale), gap.max()
 
 
 def test_dense_fused_vmem_fallback(rng, monkeypatch):
@@ -155,11 +160,14 @@ def test_ell_fused_vmem_fallback(rng, monkeypatch):
     want = np.asarray(kops.ell_hvp(dataT, colsT, u, c, fwd=(data, cols)))
     monkeypatch.setattr(kops, "_FUSED_VMEM_BYTES", 64)    # force fallback
     with_fwd = kops.ell_hvp(dataT, colsT, u, c, fwd=(data, cols))
-    without = kops.ell_hvp(dataT, colsT, u, c)            # jnp scatter path
     np.testing.assert_allclose(np.asarray(with_fwd), want, atol=1e-5,
                                rtol=1e-5)
-    np.testing.assert_allclose(np.asarray(without), want, atol=1e-5,
-                               rtol=1e-5)
+    # without the forward layout there is no kernel fallback: it raises
+    # instead of leaving the kernels for the jnp reference
+    with pytest.raises(ValueError, match="VMEM budget"):
+        kops.ell_hvp(dataT, colsT, u, c)
+    with pytest.raises(ValueError, match="VMEM budget"):
+        kops.ell_hvp_mm(dataT, colsT, u[:, None], c)
 
 
 # ---------------------------------------------------------------------------
@@ -377,6 +385,7 @@ SCRIPT = textwrap.dedent("""
     import numpy as np
     import jax
     assert len(jax.devices()) == 4
+    from repro.launch.mesh import make_mesh
     from repro.core import DiscoConfig, DiscoSolver
     from repro.data.sparse import make_sparse_glm_data
 
@@ -387,7 +396,7 @@ SCRIPT = textwrap.dedent("""
               partition_block=16)
 
     for partition, axis in (("features", "model"), ("samples", "data")):
-        mesh = jax.make_mesh((4,), (axis,))
+        mesh = make_mesh((4,), (axis,))
         for s in (1, 2):
             cfg0 = DiscoConfig(partition=partition, pcg_block_s=s, **kw)
             cfg1 = DiscoConfig(partition=partition, pcg_block_s=s,

@@ -642,6 +642,7 @@ SCRIPT = textwrap.dedent("""
     import numpy as np
     import jax
     assert len(jax.devices()) == 4
+    from repro.launch.mesh import make_mesh
 
     from repro.core import DiscoConfig
     from repro.core.lambda_path import lambda_path_fit
@@ -653,8 +654,8 @@ SCRIPT = textwrap.dedent("""
     y = r.integers(0, K, size=n)
 
     for partition, axis in (("samples", "data"), ("features", "model")):
-        mesh1 = jax.make_mesh((1,), (axis,))
-        mesh4 = jax.make_mesh((4,), (axis,))
+        mesh1 = make_mesh((1,), (axis,))
+        mesh4 = make_mesh((4,), (axis,))
         for s in (1, 2):
             cfg = SoftmaxConfig(lam=1e-2, partition=partition,
                                 max_outer=12, max_pcg=80, grad_tol=1e-7,
@@ -668,8 +669,8 @@ SCRIPT = textwrap.dedent("""
     yb = np.sign(r.standard_normal(n)).astype(np.float32)
     lams = [0.3, 0.03, 0.003]
     for partition, axis in (("samples", "data"), ("features", "model")):
-        mesh1 = jax.make_mesh((1,), (axis,))
-        mesh4 = jax.make_mesh((4,), (axis,))
+        mesh1 = make_mesh((1,), (axis,))
+        mesh4 = make_mesh((4,), (axis,))
         cfg = DiscoConfig(partition=partition, max_outer=15, max_pcg=80,
                           tau=24, grad_tol=1e-7, pcg_block_s=2)
         p1 = lambda_path_fit(X, yb, lams, cfg, mesh=mesh1)

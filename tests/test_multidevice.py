@@ -20,6 +20,7 @@ SCRIPT = textwrap.dedent("""
     import numpy as np
     import jax
     assert len(jax.devices()) == 4
+    from repro.launch.mesh import make_mesh
     from repro.core import DiscoConfig, DiscoSolver
     from repro.data.synthetic import make_glm_data
 
@@ -27,8 +28,8 @@ SCRIPT = textwrap.dedent("""
     kw = dict(loss="logistic", lam=1e-3, tau=16, max_outer=6, grad_tol=0.0)
 
     for partition, axis in (("features", "model"), ("samples", "data")):
-        mesh4 = jax.make_mesh((4,), (axis,))
-        mesh1 = jax.sharding.Mesh(np.asarray(jax.devices()[:1]), (axis,))
+        mesh4 = make_mesh((4,), (axis,))
+        mesh1 = make_mesh((1,), (axis,))
         w4 = DiscoSolver(X, y, DiscoConfig(partition=partition, **kw),
                          mesh=mesh4).fit()
         w1 = DiscoSolver(X, y, DiscoConfig(partition=partition, **kw),
@@ -65,6 +66,7 @@ SPARSE_SCRIPT = textwrap.dedent("""
     import numpy as np
     import jax
     assert len(jax.devices()) == 4
+    from repro.launch.mesh import make_mesh
     from repro.core import DiscoConfig, DiscoSolver
     from repro.data.sparse import make_sparse_glm_data
 
@@ -75,7 +77,7 @@ SPARSE_SCRIPT = textwrap.dedent("""
               ell_block_d=8, ell_block_n=8)
 
     for partition, axis in (("features", "model"), ("samples", "data")):
-        mesh = jax.make_mesh((4,), (axis,))
+        mesh = make_mesh((4,), (axis,))
         rd = DiscoSolver(Xd, y, DiscoConfig(partition=partition,
                          loss="logistic", lam=1e-3, tau=16, max_outer=8,
                          grad_tol=0.0), mesh=mesh).fit()

@@ -73,6 +73,24 @@ def test_span_nesting_and_thread_attribution():
     assert events[3].tid != outer.tid
 
 
+def test_kernel_dispatch_instant_once_per_tracer(ref_mode):
+    """Each fresh tracer records the resolved kernel mode once, however
+    many kernels dispatch and whatever tracers came before it."""
+    import gc
+
+    from repro import obs
+    from repro.kernels import ops
+
+    for _ in range(3):
+        tracer = obs.enable(reset=True)
+        ops._mode()
+        ops._mode()
+        assert tracer.span_count("kernel.dispatch") == 1
+        obs.disable()
+        del tracer
+        gc.collect()        # a new tracer may reuse the old one's address
+
+
 def test_noop_fast_path_identity():
     from repro import obs
     from repro.obs.tracer import _NOOP_SPAN

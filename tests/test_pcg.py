@@ -10,11 +10,12 @@ from oracles import (make_glm_problem as _problem,
 from repro.core.glm import GLMProblem
 from repro.core.losses import get_loss
 from repro.core.pcg import PCGResult, pcg_features, pcg_samples
-from repro.utils.compat import shard_map
+from repro.launch.mesh import make_mesh
+from jax import shard_map
 
 
 def _run_single_device(fn, in_specs, out_specs, axis, *args):
-    mesh = jax.make_mesh((1,), (axis,))
+    mesh = make_mesh((1,), (axis,))
     return jax.jit(shard_map(fn, mesh=mesh, in_specs=in_specs,
                              out_specs=out_specs, check_vma=False))(*args)
 

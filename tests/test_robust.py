@@ -534,6 +534,7 @@ KILL_RESUME_SCRIPT = textwrap.dedent("""
     import numpy as np
     import jax
     assert len(jax.devices()) == 4
+    from repro.launch.mesh import make_mesh
     from repro.core import DiscoConfig, DiscoSolver
     from repro.data.sparse import make_sparse_glm_data
     from repro.data.store import ShardStore
@@ -545,7 +546,7 @@ KILL_RESUME_SCRIPT = textwrap.dedent("""
     cfg = DiscoConfig(partition="samples", loss="logistic", lam=1e-2,
                       tau=16, max_outer=5, grad_tol=1e-10, ell_block_d=8,
                       ell_block_n=16, partition_block=32)
-    mesh = jax.make_mesh((4,), ("data",))
+    mesh = make_mesh((4,), ("data",))
     spath = os.path.join(work, "store")
     if not os.path.isdir(spath):
         ShardStore.from_csr(X, y, spath, axis="samples", chunk_size=32)
@@ -612,6 +613,7 @@ REPLAN_SCRIPT = textwrap.dedent("""
     import numpy as np
     import jax
     assert len(jax.devices()) == 4
+    from repro.launch.mesh import make_mesh
     from repro.core import DiscoConfig, DiscoSolver
     from repro.data.sparse import make_sparse_glm_data
     from repro.data.store import ShardStore
@@ -623,7 +625,7 @@ REPLAN_SCRIPT = textwrap.dedent("""
     kw = dict(partition="samples", loss="logistic", lam=1e-2, tau=32,
               max_outer=3, grad_tol=1e-10, ell_block_d=16,
               ell_block_n=128, partition_block=128)
-    mesh = jax.make_mesh((4,), ("data",))
+    mesh = make_mesh((4,), ("data",))
     with tempfile.TemporaryDirectory() as td:
         store = ShardStore.from_csr(X, y, td + "/s", axis="samples",
                                     chunk_size=128)

@@ -9,12 +9,13 @@ import repro.configs as cfgs
 from repro.configs.shapes import input_specs, is_applicable
 from repro.models import init_params
 from repro.train.sharding import batch_pspec_for, cache_pspecs, param_pspecs
+from repro.launch.mesh import make_mesh
 
 
 @pytest.fixture(scope="module")
 def mesh():
     # 1x1 mesh: exercises the full rule engine (axis sizes 1 divide all)
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 @pytest.mark.parametrize("arch", cfgs.ARCHS)
@@ -85,7 +86,7 @@ def test_long_500k_skips_match_design():
 
 
 def test_batch_pspec_fallback():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     batch = {"tokens": jax.ShapeDtypeStruct((4, 16), jnp.int32)}
     specs = batch_pspec_for(batch, mesh)
     assert specs["tokens"] == P("data", None)
@@ -107,7 +108,7 @@ def test_policy_constrain_with_mesh():
     import jax
     import jax.numpy as jnp
     from repro.models import policy
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     with policy.use_mesh(mesh):
         x = jnp.ones((4, 8))
         y = policy.constrain(x, "batch", "model")

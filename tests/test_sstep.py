@@ -10,7 +10,8 @@ from repro.core import DiscoConfig, disco_fit
 from repro.core import comm
 from repro.core.glm import GLMProblem
 from repro.core.pcg import PCGResult, pcg_features, pcg_samples
-from repro.utils.compat import shard_map
+from repro.launch.mesh import make_mesh
+from jax import shard_map
 
 
 def _problem(rng, d=40, n=200, loss="logistic", lam=1e-2):
@@ -23,7 +24,7 @@ def _problem(rng, d=40, n=200, loss="logistic", lam=1e-2):
 
 
 def _run_single_device(fn, in_specs, out_specs, axis, *args):
-    mesh = jax.make_mesh((1,), (axis,))
+    mesh = make_mesh((1,), (axis,))
     return jax.jit(shard_map(fn, mesh=mesh, in_specs=in_specs,
                              out_specs=out_specs, check_vma=False))(*args)
 

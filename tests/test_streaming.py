@@ -113,6 +113,7 @@ SCRIPT = textwrap.dedent("""
     import numpy as np
     import jax
     assert len(jax.devices()) == 4
+    from repro.launch.mesh import make_mesh
     from repro.core import DiscoConfig, DiscoSolver
     from repro.data.sparse import make_sparse_glm_data
     from repro.data.store import ShardStore
@@ -124,7 +125,7 @@ SCRIPT = textwrap.dedent("""
               partition_block=16)
 
     for partition, axis in (("features", "model"), ("samples", "data")):
-        mesh = jax.make_mesh((4,), (axis,))
+        mesh = make_mesh((4,), (axis,))
         for s in (1, 2):
             cfg = DiscoConfig(partition=partition, pcg_block_s=s, **kw)
             with tempfile.TemporaryDirectory() as td:

@@ -1,0 +1,167 @@
+"""Compile-only checks of every Pallas kernel for a described TPU v5e.
+
+Nothing here runs on a chip: the TPU compiler is installed alongside JAX,
+and ``get_topology_desc`` describes a v5e 2x2 host without one attached.
+Each test lowers one kernel at a real width for one chip of it and asserts
+that Mosaic accepted the kernel (``tpu_custom_call`` in the compiled HLO).
+This catches what interpret mode cannot: block shapes that break the
+(8, 128) tiling rule, unaligned slices, and VMEM overruns.
+
+The topology is described inside a module-scoped fixture (never at import)
+so that pytest-xdist workers all collect the same tests and only the worker
+running this file loads the TPU library.
+"""
+import importlib
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from repro.core import disco
+from repro.kernels import ops
+from repro.launch.mesh import make_mesh
+
+# the package re-exports functions under these module names
+dense = importlib.import_module("repro.kernels.glm_hvp")
+sparse = importlib.import_module("repro.kernels.sparse_hvp")
+
+D = 2048           # epsilon's d = 2,000 padded to the 512 dense tile
+N = 400_384        # epsilon's n = 400,000 padded to the 512 dense tile
+S = 128            # probe block of the s-step kernels (one lane width)
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """The four chips of a described v5e 2x2 host."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means "cannot"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep the cache out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield topo.devices
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e):
+    return SingleDeviceSharding(v5e[0])
+
+
+def _compile(fn, sharding, *shapes):
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=sharding)
+            for s, dt in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    return text
+
+
+DENSE = {
+    "xt_u": (lambda X, u: dense.xt_u(X, u),
+             lambda dt: [((D, N), dt), ((D,), jnp.float32)]),
+    "x_cz": (lambda X, c, z: dense.x_cz(X, c, z),
+             lambda dt: [((D, N), dt), ((N,), jnp.float32),
+                         ((N,), jnp.float32)]),
+    "xt_multi": (lambda X, U: dense.xt_multi(X, U),
+                 lambda dt: [((D, N), dt), ((D, S), jnp.float32)]),
+    "x_cz_multi": (lambda X, c, Z: dense.x_cz_multi(X, c, Z),
+                   lambda dt: [((D, N), dt), ((N,), jnp.float32),
+                               ((N, S), jnp.float32)]),
+    "x_c_xt_u": (lambda X, c, u: dense.x_c_xt_u(X, c, u),
+                 lambda dt: [((D, N), dt), ((N,), jnp.float32),
+                             ((D,), jnp.float32)]),
+    "x_c_xt_multi": (lambda X, c, U: dense.x_c_xt_multi(X, c, U),
+                     lambda dt: [((D, N), dt), ((N,), jnp.float32),
+                                 ((D, S), jnp.float32)]),
+    # the solver's entry: epsilon's unpadded shape through the wrapper
+    "ops.glm_hvp": (lambda X, c, u: ops.glm_hvp(X, c, u, 1e-4,
+                                                 mode="native"),
+                    lambda dt: [((2000, 400_000), dt),
+                                ((400_000,), jnp.float32),
+                                ((2000,), jnp.float32)]),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("name", list(DENSE))
+def test_dense_kernel_compiles_for_v5e(one_chip, name, dtype):
+    fn, shapes = DENSE[name]
+    _compile(fn, one_chip, *shapes(dtype))
+
+
+NB, W, NCB, BR, BC = 64, 8, 48, 128, 128      # forward layout, 128x128 tiles
+WT = 10                                       # transposed tile-row width
+
+SPARSE = {
+    "ell_mv": (lambda x, c, v, col: sparse.ell_mv(x, col, v, c),
+               [((NB, W, BR, BC), jnp.float32), ((NCB * BC,), jnp.float32),
+                ((NCB * BC,), jnp.float32), ((NB, W), jnp.int32)]),
+    "ell_mm": (lambda x, c, V, col: sparse.ell_mm(x, col, V, c),
+               [((NB, W, BR, BC), jnp.float32), ((NCB * BC,), jnp.float32),
+                ((NCB * BC, S), jnp.float32), ((NB, W), jnp.int32)]),
+    "ell_hvp": (lambda xT, c, u, col: sparse.ell_hvp(xT, col, u, c),
+                [((NCB, WT, BC, BR), jnp.float32),
+                 ((NCB * BC,), jnp.float32), ((NB * BR,), jnp.float32),
+                 ((NCB, WT), jnp.int32)]),
+    "ell_hvp_mm": (lambda xT, c, U, col: sparse.ell_hvp_mm(xT, col, U, c),
+                   [((NCB, WT, BC, BR), jnp.float32),
+                    ((NCB * BC,), jnp.float32),
+                    ((NB * BR, S), jnp.float32), ((NCB, WT), jnp.int32)]),
+    # the scoring engine's request tiles: 8 requests x 128 features
+    "ell_matvec_scoring": (
+        lambda x, v, col: ops.ell_matvec(x, col, v, mode="native"),
+        [((8, 40, 8, 128), jnp.float32), ((7813 * 128,), jnp.float32),
+         ((8, 40), jnp.int32)]),
+}
+
+
+@pytest.mark.parametrize("name", list(SPARSE))
+def test_ell_kernel_compiles_for_v5e(one_chip, name):
+    fn, shapes = SPARSE[name]
+    _compile(fn, one_chip, *shapes)
+
+
+@pytest.mark.parametrize("body", ["mv", "hvp", "hvp_two_pass"])
+def test_streamed_step_compiles_on_four_chips(v5e, body):
+    """One step of a streamed DiSCO-S pass on a 4-chip mesh: the chunk
+    kernels run per shard inside ``shard_map`` (the compiler refuses to
+    partition a Pallas call)."""
+    mesh = make_mesh((4,), ("data",), devices=v5e)
+    m, chunk, d = 4, 256, 1024
+    nb, nbt = d // BR, chunk // BC
+    tiles = {"dataT": ((m, nbt, W, BC, BR), jnp.float32),
+             "colsT": ((m, nbt, W), jnp.int32),
+             "data": ((m, nb, W, BR, BC), jnp.float32),
+             "cols": ((m, nb, W), jnp.int32)}
+    keys, fn, reduce, whole = {
+        "mv": (("dataT", "colsT"), disco._chunk_mv, False, [((d,), P())]),
+        "hvp": (("dataT", "colsT"), disco._chunk_hvp, True,
+                [((m * 2 * chunk,), P("data")), ((d,), P())]),
+        "hvp_two_pass": (("dataT", "colsT", "data", "cols"),
+                         disco._chunk_hvp_two_pass, True,
+                         [((m * 2 * chunk,), P("data")), ((d,), P())]),
+    }[body]
+    args = [jax.ShapeDtypeStruct(*tiles[k], sharding=NamedSharding(
+        mesh, P("data"))) for k in keys]
+    args += [jax.ShapeDtypeStruct(s, jnp.float32,
+                                  sharding=NamedSharding(mesh, spec))
+             for s, spec in whole]
+    n_sliced = 1 if reduce else 0
+    text = disco._on_shards.lower(
+        np.int32(1), *args, body=fn, mesh=mesh, axis="data",
+        n_stacked=len(keys), n_sliced=n_sliced, chunk=chunk, reduce=reduce,
+        mode="native").compile().as_text()
+    assert "tpu_custom_call" in text

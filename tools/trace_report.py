@@ -32,7 +32,9 @@ import tempfile
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(REPO, "src"))
-os.environ.setdefault("REPRO_KERNEL_MODE", "ref")
+from repro.kernels.ops import ref_kernels_off_tpu  # noqa: E402
+
+ref_kernels_off_tpu()   # the fast jnp path off the chip
 
 # workload: small enough for CI, large enough that every span kind fires
 D, N, DENSITY = 96, 320, 0.15

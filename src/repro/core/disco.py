@@ -1305,45 +1305,47 @@ class DiscoSolver:
 
         converged = False
         for k in range(start_iter, cfg.max_outer):
-            self._outer_iter = k
-            if self._faults is not None:
-                self._faults.on_outer_step(k)
-            key, sub = jax.random.split(key)
-            t_it = time.perf_counter()
             with obs.span("newton.outer", outer_iter=k,
                           streaming=bool(self._streaming)):
-                # block on the step's outputs, so the span (and iter_s)
-                # covers the device work, not its dispatch
-                w, stats = jax.block_until_ready(self._step(w, sub))
-                stats = {name: float(v) for name, v in stats.items()}
-            stats["iter_s"] = time.perf_counter() - t_it
-            rounds, floats, spmd = self._comm_costs(int(stats["pcg_iters"]))
-            ledger.add(rounds, floats, spmd)
-            obs.count("comm.floats", floats)
-            obs.count("comm.spmd_collectives", spmd)
-            if not self._streaming:
-                # in-memory PCG runs inside a jitted while_loop where
-                # per-round events are invisible; tally the analytic
-                # rounds instead. Streamed solves count at the actual
-                # call sites (step closures + pcg_streamed) — the
-                # independent tally bench_obs cross-checks.
-                obs.count("comm.rounds", rounds)
-            stats.update(outer_iter=k, comm_rounds_cum=ledger.rounds,
-                         comm_floats_cum=ledger.floats)
-            history.append(stats)
-            if checkpoint_dir is not None \
-                    and (k + 1) % max(checkpoint_every, 1) == 0:
-                save_checkpoint(checkpoint_dir, CheckpointState(
-                    next_iter=k + 1, w=self._w_to_original(w),
-                    key=np.asarray(key), history=history,
-                    ledger=dict(rounds=ledger.rounds,
-                                floats=ledger.floats,
-                                spmd_collectives=ledger.spmd_collectives),
-                    replan_events=list(self._replan_events),
-                    cfg=self._cfg_fingerprint()))
-            if stats["grad_norm"] <= cfg.grad_tol:
-                converged = True
-                break
+                self._outer_iter = k
+                if self._faults is not None:
+                    self._faults.on_outer_step(k)
+                key, sub = jax.random.split(key)
+                t_it = time.perf_counter()
+                with obs.span("newton.step"):
+                    # block on the step's outputs, so the span (and
+                    # iter_s) covers the device work, not its dispatch
+                    w, stats = jax.block_until_ready(self._step(w, sub))
+                    stats = {name: float(v) for name, v in stats.items()}
+                stats["iter_s"] = time.perf_counter() - t_it
+                rounds, floats, spmd = self._comm_costs(
+                    int(stats["pcg_iters"]))
+                ledger.add(rounds, floats, spmd)
+                obs.count("comm.floats", floats)
+                obs.count("comm.spmd_collectives", spmd)
+                if not self._streaming:
+                    # in-memory PCG runs inside a jitted while_loop where
+                    # per-round events are invisible; tally the analytic
+                    # rounds instead. Streamed solves count at the actual
+                    # call sites (step closures + pcg_streamed) — the
+                    # independent tally bench_obs cross-checks.
+                    obs.count("comm.rounds", rounds)
+                stats.update(outer_iter=k, comm_rounds_cum=ledger.rounds,
+                             comm_floats_cum=ledger.floats)
+                history.append(stats)
+                if checkpoint_dir is not None \
+                        and (k + 1) % max(checkpoint_every, 1) == 0:
+                    save_checkpoint(checkpoint_dir, CheckpointState(
+                        next_iter=k + 1, w=self._w_to_original(w),
+                        key=np.asarray(key), history=history,
+                        ledger=dict(
+                            rounds=ledger.rounds, floats=ledger.floats,
+                            spmd_collectives=ledger.spmd_collectives),
+                        replan_events=list(self._replan_events),
+                        cfg=self._cfg_fingerprint()))
+                if stats["grad_norm"] <= cfg.grad_tol:
+                    converged = True
+                    break
 
         w_full = self._w_to_original(w)
         stream_stats = None

@@ -139,8 +139,8 @@ class MicroBatchScheduler:
         is the only safe swap point — mid-batch all slots must score
         against one ``w``), then admits, scores, completes. With
         tracing on, each tick is a ``serve.tick`` span and the queue
-        depth / tick count ride as obs gauges — serving and solver
-        share one metrics vocabulary (docs/observability.md).
+        depth rides as an obs gauge — serving and solver share one
+        metrics vocabulary (docs/observability.md).
         """
         obs.gauge("serve.queue_depth", len(self.waiting))
         with obs.span("serve.tick", tick=self.stats.ticks) as sp:
@@ -148,13 +148,13 @@ class MicroBatchScheduler:
             sp.set(scored=scored)
         if scored:
             obs.count("serve.scored", scored)
-        obs.gauge("serve.ticks", self.stats.ticks)
         return scored
 
     def _tick(self) -> int:
         self.engine.maybe_reload()
         now = self.clock()
         batch: list[_Waiting] = []
+        queue_wait_s = 0.0
         while self.waiting and len(batch) < self.engine.batch:
             item = self.waiting.popleft()
             if item.deadline is not None and now > item.deadline:
@@ -164,8 +164,10 @@ class MicroBatchScheduler:
                 self.stats.rejected += 1
                 continue
             batch.append(item)
+            queue_wait_s += now - item.t_submit
         if not batch:
             return 0
+        obs.count("serve.queue_wait_s", queue_wait_s)
         t0 = self.clock()
         margins = self.engine.score([b.req for b in batch])
         t1 = self.clock()

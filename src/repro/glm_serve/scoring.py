@@ -280,15 +280,24 @@ class ScoringEngine:
 
         Requests are packed ``batch`` at a time; each pack is one jit'd
         ELL matvec (the shapes never change, so after the first call
-        every tick reuses the same executable).
+        every tick reuses the same executable). Each piece of a pack
+        ends in a wait: the kernel needs the tiles and the copy back
+        waits for the kernel anyway, so the waits move the sync points
+        without adding work, and each piece's span times its own work.
         """
         out = np.zeros(len(requests), self.packer.dtype)
         for lo in range(0, len(requests), self.packer.batch):
             part = requests[lo: lo + self.packer.batch]
-            data, cols = self.packer.pack(part)
-            y = self._step(jnp.asarray(data), jnp.asarray(cols),
-                           self._w_dev)
-            out[lo: lo + len(part)] = np.asarray(y)[: len(part)]
+            with obs.span("serve.pack"):
+                data, cols = self.packer.pack(part)
+            with obs.span("serve.copy_in"):
+                data, cols = jax.block_until_ready(
+                    (jnp.asarray(data), jnp.asarray(cols)))
+            with obs.span("serve.kernel"):
+                y = jax.block_until_ready(
+                    self._step(data, cols, self._w_dev))
+            with obs.span("serve.copy_out"):
+                out[lo: lo + len(part)] = np.asarray(y)[: len(part)]
         return out
 
     def predict(self, requests: Sequence[ScoreRequest]) -> np.ndarray:

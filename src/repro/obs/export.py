@@ -1,17 +1,12 @@
-"""Exporters for the in-process tracer (docs/observability.md).
+"""Exporter for the in-process tracer (docs/observability.md).
 
-Two output formats:
-
-* :func:`chrome_trace` — Chrome trace-event JSON ("trace event format",
-  the JSON-array flavour). Load the written file straight into
-  https://ui.perfetto.dev (or chrome://tracing) to see the span
-  timeline, one track per thread — the chunk-prefetch producer thread
-  shows up as its own lane next to the solver's main thread.
-* :func:`summary_rows` — flat, JSON-scalar rows (one per span kind +
-  one per counter/gauge) shaped for the schema-checked
-  ``benchmarks.common.validate_bench_record`` / ``write_bench_record``
-  path, so a traced run can ship its summary through the same validated
-  pipe every benchmark uses.
+:func:`chrome_trace` — Chrome trace-event JSON ("trace event format",
+the JSON-array flavour). Load the written file straight into
+https://ui.perfetto.dev (or chrome://tracing) to see the span timeline,
+one track per thread — the chunk-prefetch producer thread shows up as
+its own lane next to the solver's main thread. The same spans also sit
+in any ``jax.profiler`` capture taken while tracing is on, beside the
+device operations.
 """
 from __future__ import annotations
 
@@ -59,32 +54,3 @@ def write_chrome_trace(tracer: Tracer, path: str) -> str:
         json.dump(chrome_trace(tracer), f)
     return path
 
-
-def summary_rows(tracer: Tracer) -> list[dict]:
-    """Aggregate the trace into flat rows (one per span kind, then one
-    per counter and gauge) with JSON-scalar values only — the row shape
-    ``benchmarks.common.validate_bench_record`` accepts."""
-    events, counters, gauges = tracer.snapshot()
-    agg: dict[str, dict] = {}
-    for ev in events:
-        a = agg.setdefault(ev.kind, {"kind": ev.kind, "events": 0,
-                                     "total_s": 0.0, "max_ms": 0.0})
-        a["events"] += 1
-        dur_s = ev.dur_ns / 1e9
-        a["total_s"] += dur_s
-        a["max_ms"] = max(a["max_ms"], dur_s * 1e3)
-    rows = []
-    for kind in sorted(agg):
-        a = agg[kind]
-        rows.append({"kind": kind, "events": int(a["events"]),
-                     "total_s": float(a["total_s"]),
-                     "max_ms": float(a["max_ms"])})
-    for name in sorted(counters):
-        rows.append({"kind": f"counter:{name}", "events": 1,
-                     "total_s": 0.0, "value": float(counters[name]),
-                     "max_ms": 0.0})
-    for name in sorted(gauges):
-        rows.append({"kind": f"gauge:{name}", "events": 1,
-                     "total_s": 0.0, "value": float(gauges[name]),
-                     "max_ms": 0.0})
-    return rows

@@ -28,6 +28,14 @@ Contract:
   ``tools/docs_check.py`` — the same drift gate as the HVP support
   matrix.
 
+* **On the profiler's clock.** An enabled span also enters a
+  ``jax.profiler.TraceAnnotation`` named by its kind, with its opening
+  args as event stats, so a ``jax.profiler`` capture shows the program's
+  spans on the host plane beside the device operations. Only the
+  enabled tracer imports ``jax.profiler``. Instants and
+  :meth:`Tracer.complete` spans (recorded after the fact) stay in memory
+  only.
+
 Enable with ``REPRO_TRACE=1`` in the environment (read at import), with
 ``DiscoConfig(trace=True)`` (the solver calls :func:`enable` at
 construction), or programmatically via :func:`enable`.
@@ -49,7 +57,14 @@ from typing import NamedTuple
 SPAN_KINDS: dict[str, tuple[str, str, str]] = {
     "newton.outer": (
         "core", "span",
-        "one damped-Newton outer iteration (step dispatch + host sync)"),
+        "one damped-Newton outer iteration: the whole loop body of "
+        "`fit` (fault hook, key split, step, stats, ledger, checkpoint, "
+        "convergence test)"),
+    "newton.step": (
+        "core", "span",
+        "the jitted outer step inside `newton.outer`: dispatch, "
+        "`block_until_ready` and the stats' host conversion (the extent "
+        "of `iter_s`)"),
     "pcg.round": (
         "core", "span",
         "one host-driven streamed PCG round (classic iteration or "
@@ -108,6 +123,20 @@ SPAN_KINDS: dict[str, tuple[str, str, str]] = {
         "serve", "span",
         "one scheduler tick: admit -> score -> complete (args: tick "
         "index, scored count)"),
+    "serve.pack": (
+        "serve", "span",
+        "one batch packed into blocked-ELL tiles on the host "
+        "(`RequestPacker.pack`; nested inside serve.tick)"),
+    "serve.copy_in": (
+        "serve", "span",
+        "the packed tiles copied to the device, until both arrays are "
+        "ready"),
+    "serve.kernel": (
+        "serve", "span",
+        "the scoring `ell_matvec` dispatched and waited for"),
+    "serve.copy_out": (
+        "serve", "span",
+        "the margins copied back to the host"),
 }
 
 #: counter registry: name -> description. Counters are monotone sums.
@@ -122,6 +151,10 @@ COUNTER_KINDS: dict[str, str] = {
         "SPMD collective launches (analytic tally, both paths)"),
     "io.retries": "transient I/O failures retried by the retry policy",
     "serve.scored": "requests scored by the micro-batch scheduler",
+    "serve.queue_wait_s": (
+        "seconds the scored requests waited in the scheduler's queue, "
+        "submit to admission, on the scheduler's clock (over "
+        "`serve.scored`: the mean wait)"),
 }
 
 #: gauge registry: name -> description. Gauges record last-value samples.
@@ -129,7 +162,6 @@ GAUGE_KINDS: dict[str, str] = {
     "serve.queue_depth": (
         "scheduler waiting-queue depth, sampled at the top of each "
         "tick"),
-    "serve.ticks": "scheduler ticks completed so far",
 }
 
 
@@ -209,22 +241,30 @@ class Span:
 
     Spans nest naturally (enter/exit order is the nesting); use
     :meth:`set` to attach args that are only known inside the block.
+    The span is also a ``jax.profiler.TraceAnnotation`` of the same name
+    for the duration of the block, carrying the args given at opening
+    (those of :meth:`set` reach the in-memory event only).
     """
 
-    __slots__ = ("_tracer", "_kind", "_args", "_t0")
+    __slots__ = ("_tracer", "_kind", "_args", "_t0", "_annotation")
 
     def __init__(self, tracer: "Tracer", kind: str, args: dict):
         self._tracer = tracer
         self._kind = kind
         self._args = args
         self._t0 = 0
+        self._annotation = None
 
     def __enter__(self) -> "Span":
+        self._annotation = self._tracer._annotation(self._kind,
+                                                    **self._args)
+        self._annotation.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc) -> bool:
         t1 = time.perf_counter_ns()
+        self._annotation.__exit__(*exc)
         self._tracer._record(self._kind, "X", self._t0, t1 - self._t0,
                              self._args)
         return False
@@ -248,6 +288,8 @@ class Tracer:
     enabled = True
 
     def __init__(self):
+        from jax.profiler import TraceAnnotation
+        self._annotation = TraceAnnotation
         self._lock = threading.Lock()
         self.events: list[TraceEvent] = []
         self.counters: dict[str, float] = {}
@@ -277,7 +319,8 @@ class Tracer:
         """Record a span whose start ``t0_ns`` (``perf_counter_ns``) was
         captured by the caller — for spans that cannot be a ``with``
         block, e.g. a prefetch pass closed from its context-manager
-        exit."""
+        exit. It cannot be back-dated into a profiler capture, so it
+        stays in memory only."""
         _check(kind, SPAN_KINDS, "span kind")
         t1 = time.perf_counter_ns()
         self._record(kind, "X", t0_ns, t1 - t0_ns, args)
@@ -346,7 +389,14 @@ def get_tracer() -> Tracer | NoopTracer:
 
 
 def span(kind: str, **args):
-    """Open a span on the global tracer (no-op context when disabled)."""
+    """Open a span on the global tracer (no-op context when disabled).
+
+    The disabled case returns the cached no-op span here, without a
+    second call that would pack the args again: ``fit`` opens two spans
+    per outer iteration and each scheduler tick five.
+    """
+    if _TRACER is _NOOP:
+        return _NOOP_SPAN
     return _TRACER.span(kind, **args)
 
 

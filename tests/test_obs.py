@@ -106,7 +106,7 @@ def test_noop_fast_path_identity():
     # disabled emission drops silently, even for unregistered names
     obs.instant("comm.allreduce")
     obs.count("comm.rounds", 5)
-    obs.gauge("serve.ticks", 1)
+    obs.gauge("serve.queue_depth", 1)
     tracer = obs.enable(reset=True)
     assert tracer.snapshot() == ([], {}, {})
 
@@ -144,6 +144,59 @@ def test_counters_gauges_and_span_count():
     assert tracer.span_count("comm.allreduce") == 2
 
 
+def test_disabled_obs_imports_no_jax():
+    """The disabled path is stdlib only: importing and calling obs with
+    tracing off loads no jax (the enabled tracer imports jax.profiler)."""
+    import subprocess
+    import sys
+
+    code = ("import sys\n"
+            "from repro import obs\n"
+            "with obs.span('newton.outer', outer_iter=0):\n"
+            "    obs.count('serve.queue_wait_s', 1.0)\n"
+            "assert not obs.enabled()\n"
+            "assert 'jax' not in sys.modules, 'jax imported'\n")
+    env = {k: v for k, v in os.environ.items() if k != "REPRO_TRACE"}
+    env["PYTHONPATH"] = SRC
+    p = subprocess.run([sys.executable, "-c", code], env=env,
+                       capture_output=True, text=True, timeout=120)
+    assert p.returncode == 0, p.stderr
+
+
+def test_enabled_span_sits_on_the_profilers_host_plane(tmp_path):
+    """An enabled span is also a TraceAnnotation: a CPU capture shows it
+    by name on the host plane inside an outer annotation, with its
+    opening args as event stats and the in-memory event's duration."""
+    import glob
+    import time
+
+    import jax
+    from repro import obs
+
+    tracer = obs.enable(reset=True)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with jax.profiler.TraceAnnotation("outer"):
+            with obs.span("serve.tick", tick=3) as sp:
+                time.sleep(0.02)
+                sp.set(scored=1)
+    finally:
+        jax.profiler.stop_trace()
+    (ev,), _, _ = tracer.snapshot()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    pd = jax.profiler.ProfileData.from_file(path)
+    host = {e.name: e for plane in pd.planes if plane.name == "/host:CPU"
+            for line in plane.lines for e in line.events
+            if e.name in ("outer", "serve.tick")}
+    tick, outer = host["serve.tick"], host["outer"]
+    assert outer.start_ns <= tick.start_ns
+    assert tick.start_ns + tick.duration_ns \
+        <= outer.start_ns + outer.duration_ns
+    assert dict(tick.stats) == {"tick": 3}
+    assert ev.args == {"tick": 3, "scored": 1}
+    assert abs(tick.duration_ns - ev.dur_ns) < 1e6
+
+
 # ---------------------------------------------------------------------------
 # exporters
 # ---------------------------------------------------------------------------
@@ -155,7 +208,7 @@ def test_chrome_trace_structure(tmp_path):
     with obs.span("newton.outer", outer_iter=0):
         obs.instant("comm.allreduce", phase="outer")
     obs.count("comm.rounds", 2)
-    obs.gauge("serve.ticks", 1)
+    obs.gauge("serve.queue_depth", 1)
 
     events = obs.export.chrome_trace(tracer)
     json.dumps(events)                       # Perfetto-loadable
@@ -173,25 +226,6 @@ def test_chrome_trace_structure(tmp_path):
     path = tmp_path / "trace.json"
     obs.export.write_chrome_trace(tracer, str(path))
     assert json.loads(path.read_text()) == json.loads(json.dumps(events))
-
-
-def test_summary_rows_are_flat_bench_rows():
-    import sys
-    sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
-    from benchmarks.common import validate_bench_record
-
-    from repro import obs
-
-    tracer = obs.enable(reset=True)
-    with obs.span("ckpt.write", next_iter=1):
-        pass
-    obs.count("io.retries", 2)
-    obs.gauge("serve.queue_depth", 5)
-    rows = obs.export.summary_rows(tracer)
-    assert {r["kind"] for r in rows} == {"ckpt.write", "counter:io.retries",
-                                         "gauge:serve.queue_depth"}
-    # flat JSON scalars: accepted verbatim by the bench record schema
-    validate_bench_record({"bench": "obs-test", "rows": rows})
 
 
 # ---------------------------------------------------------------------------
@@ -302,6 +336,27 @@ def test_inmemory_counter_matches_ledger_and_iter_s(ref_mode, glm_data):
         assert h["iter_s"] > 0.0             # per-iteration wall-clock
 
 
+def test_newton_step_nests_in_each_outer_and_times_iter_s(ref_mode,
+                                                         glm_data):
+    from repro import obs
+    from repro.core import DiscoConfig, DiscoSolver
+
+    X, y, _ = glm_data
+    cfg = DiscoConfig(partition="samples", loss="logistic", lam=1e-2,
+                      tau=16, max_outer=3, grad_tol=1e-10)
+    tracer = obs.enable(reset=True)
+    res = DiscoSolver(X, y, cfg).fit()
+    events, _, _ = tracer.snapshot()
+    outers = [e for e in events if e.kind == "newton.outer"]
+    steps = [e for e in events if e.kind == "newton.step"]
+    assert len(outers) == len(steps) == len(res.history) == 3
+    for outer, step, h in zip(outers, steps, res.history):
+        assert outer.args["outer_iter"] == h["outer_iter"]
+        assert outer.t0_ns <= step.t0_ns
+        assert step.t0_ns + step.dur_ns <= outer.t0_ns + outer.dur_ns
+        assert abs(h["iter_s"] - step.dur_ns * 1e-9) < 1e-3
+
+
 def test_measured_vs_predicted_rows(ref_mode, glm_data):
     from repro import obs
     from repro.core import DiscoConfig, DiscoSolver
@@ -401,5 +456,50 @@ def test_scheduler_ticks_emit_spans_and_gauges(ref_mode):
     # scored counts ride on the span args (set() after scoring)
     assert [t.args["scored"] for t in ticks] == [4, 4, 1]
     assert counters["serve.scored"] == sched.stats.completed == 9
-    assert gauges["serve.ticks"] == sched.stats.ticks
+    assert set(gauges) == {"serve.queue_depth"}
     assert gauges["serve.queue_depth"] == 1          # depth before last tick
+
+
+def test_tick_pieces_nest_in_each_scored_tick_and_queue_wait_counts(
+        ref_mode):
+    """Each tick that scores shows pack, copy in, kernel and copy out
+    once, in order, inside its serve.tick; an empty tick shows none. On
+    a fake clock the queue-wait counter is the hand-computed sum."""
+    from repro import obs
+    from repro.glm_serve import (MicroBatchScheduler, ScoreRequest,
+                                 ScoringEngine)
+
+    pieces = ("serve.pack", "serve.copy_in", "serve.kernel",
+              "serve.copy_out")
+    rng = np.random.default_rng(0)
+    w = rng.standard_normal(24).astype(np.float32)
+    eng = ScoringEngine(w, loss="logistic", batch=4, block_b=2, block_d=8)
+    now = [0.0]
+    sched = MicroBatchScheduler(eng, clock=lambda: now[0])
+    tracer = obs.enable(reset=True)
+    submits = [0.0, 0.5, 1.0, 1.5, 2.0, 2.5]
+    for t in submits:
+        now[0] = t
+        sched.submit(ScoreRequest(np.array([0, 5]),
+                                  np.array([1.0, -1.0], np.float32)))
+    for t in (3.0, 4.0, 4.5):            # 4 scored, 2 scored, none
+        now[0] = t
+        sched.tick()
+    events, counters, _ = tracer.snapshot()
+    ticks = [e for e in events if e.kind == "serve.tick"]
+    assert [t.args["scored"] for t in ticks] == [4, 2, 0]
+    for tick in ticks:
+        inside = [e for e in events if e.kind in pieces
+                  and tick.t0_ns <= e.t0_ns
+                  and e.t0_ns + e.dur_ns <= tick.t0_ns + tick.dur_ns]
+        if not tick.args["scored"]:
+            assert inside == []
+            continue
+        assert tuple(e.kind for e in inside) == pieces
+        for a, b in zip(inside, inside[1:]):
+            assert a.t0_ns + a.dur_ns <= b.t0_ns
+    assert sum(e.kind in pieces for e in events) == 2 * len(pieces)
+    want = sum(3.0 - t for t in submits[:4]) + sum(4.0 - t
+                                                  for t in submits[4:])
+    assert counters["serve.queue_wait_s"] == pytest.approx(want)
+    assert counters["serve.scored"] == 6

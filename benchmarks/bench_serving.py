@@ -7,11 +7,11 @@ power-law sparse synthetic:
     :class:`repro.glm_serve.registry.ModelRegistry`, reload — the
     weight vector must round-trip **bit-identically**;
   * **scoring parity**: score held-out requests through the
-    request-packer + blocked-ELL kernel path and compare against the
-    dense NumPy oracle;
+    request packer's (id, value) slots and the engine's gather step, and
+    compare against the dense NumPy oracle;
   * **micro-batched throughput**: the same request stream through the
     slot-based scheduler at batch 64 vs sequential single-request
-    scoring (one kernel dispatch per request), p50/p99 latency and the
+    scoring (one step dispatch per request), p50/p99 latency and the
     modeled speedup (:func:`repro.core.comm.glm_serving_throughput`)
     alongside the measured one;
   * **warm-start refit**: append a fresh sample slice to the store
@@ -36,8 +36,8 @@ from repro.core import DiscoConfig, DiscoSolver, comm
 from repro.data.sparse import CSRMatrix, make_sparse_glm_data
 from repro.data.store import ShardStore
 from repro.glm_serve import (MicroBatchScheduler, ModelRegistry,
-                             RefitLoop, ScoreRequest, ScoringEngine,
-                             oracle_margins)
+                             RefitLoop, RequestPacker, ScoreRequest,
+                             ScoringEngine, oracle_margins)
 from repro.kernels.ops import ref_kernels_off_tpu
 
 if smoke():
@@ -48,7 +48,6 @@ else:
     N_REQS = 256
 DENSITY, ALPHA, BETA = 0.08, 1.2, 0.8
 BATCH = 64                      # the micro-batch width the gate names
-BLOCK_B, BLOCK_D = 8, 16        # packer tile geometry
 APPEND_FRAC = 16                # refit appends n/APPEND_FRAC new samples
 # refit solver: tight forcing term so every Newton iteration is worth
 # ~2 orders of magnitude — the regime where a warm start's head start
@@ -78,7 +77,7 @@ def _time_batched(engine, requests):
 
 
 def _time_sequential(engine, requests):
-    """Seconds to score ``requests`` one kernel dispatch at a time."""
+    """Seconds to score ``requests`` one step dispatch at a time."""
     engine.score(requests[:1])                       # warmup / compile
     with Timer() as t:
         for r in requests:
@@ -113,35 +112,31 @@ def run(quiet=False):
         rng = np.random.default_rng(1)
         cols = rng.choice(N, size=N_REQS, replace=False)
         requests = [ScoreRequest.from_dense(Xd[:, j]) for j in cols]
-        engine = ScoringEngine(reg, batch=BATCH, block_b=BLOCK_B,
-                               block_d=BLOCK_D)
+        engine = ScoringEngine(reg, batch=BATCH)
         got = engine.score(requests)
         want = oracle_margins(requests, pub.w)
         denom = max(float(np.abs(want).max()), 1e-30)
         parity = float(np.abs(got - want).max()) / denom
         gate["parity"] = dict(rel_err=parity, ok=parity <= 1e-5)
 
-        # -- bf16 tile scoring parity (mixed-precision serving path) ------
-        engine_bf = ScoringEngine(reg, batch=BATCH, block_b=BLOCK_B,
-                                  block_d=BLOCK_D, hvp_dtype="bfloat16")
+        # -- bf16 value scoring parity (mixed-precision serving path) -----
+        engine_bf = ScoringEngine(reg, batch=BATCH, hvp_dtype="bfloat16")
         got_bf = engine_bf.score(requests)
         parity_bf = float(np.abs(got_bf - want).max()) / denom
         # bf16 mantissa is 8 bits: per-request dots should stay within
-        # ~2^-8 of the oracle (both MXU operands round to bf16, the
-        # accumulator and output stay f32 — docs/kernels.md)
+        # ~2^-8 of the oracle (the values round to bf16; the weights,
+        # products and sum stay f32 — docs/kernels.md)
         gate["parity_bf16"] = dict(rel_err=parity_bf,
                                    ok=parity_bf <= 2e-2)
 
         # -- micro-batched vs sequential throughput -----------------------
         t_b, stats = _time_batched(engine, requests)
-        seq_engine = ScoringEngine(reg, batch=1, block_b=1,
-                                   block_d=BLOCK_D)
+        seq_engine = ScoringEngine(reg, batch=1)
         t_s = _time_sequential(seq_engine, requests)
         speedup = t_s / max(t_b, 1e-12)
         nnz_per_req = float(np.mean([r.nnz for r in requests]))
         model = comm.glm_serving_throughput(
-            BATCH, nnz_per_req, ell_width=engine.packer.width,
-            block_b=BLOCK_B, block_d=BLOCK_D)
+            BATCH, nnz_per_req, slots=RequestPacker.slots(requests))
         gate["throughput"] = dict(speedup=speedup, ok=speedup >= 4.0)
 
         # -- warm-start refit on appended data ----------------------------
@@ -189,7 +184,7 @@ def run(quiet=False):
         print(f"[gate] registry round-trip bit-identical: "
               f"{gate['registry']['bit_identical']}")
         print(f"[gate] scoring parity rel_err={parity:.2e} (need <=1e-5)")
-        print(f"[gate] bf16-tile scoring parity rel_err={parity_bf:.2e} "
+        print(f"[gate] bf16-value scoring parity rel_err={parity_bf:.2e} "
               f"(need <=2e-2)")
         print(f"[gate] micro-batched speedup {speedup:.1f}x "
               f"(need >=4x; model predicts "

@@ -328,9 +328,9 @@ def phase_score(sz: Sizes, X, res, cfg: DiscoConfig, workdir: str) -> dict:
         lo, hi = rows.indptr[i], rows.indptr[i + 1]
         requests.append(ScoreRequest(indices=rows.indices[lo:hi],
                                      values=rows.data[lo:hi]))
-    engine = ScoringEngine(reg, batch=sz.batch, block_d=sz.tile)
+    engine = ScoringEngine(reg, batch=sz.batch)
     CLOCK.take()
-    engine.score(requests[: sz.batch])                # compile
+    engine.score(requests)             # compiles each k the packs need
     compile_s, _ = CLOCK.take()
     t0 = time.perf_counter()
     got = engine.score(requests)

@@ -51,7 +51,7 @@ with tempfile.TemporaryDirectory() as td:
           f"converged={result.converged} -> published v{v1}")
 
     # 2. serve a request stream through the micro-batching scheduler
-    engine = ScoringEngine(registry, batch=BATCH, block_b=8, block_d=16)
+    engine = ScoringEngine(registry, batch=BATCH)
     sched = MicroBatchScheduler(engine)
     rng = np.random.default_rng(1)
     cols = rng.choice(N, size=48, replace=False)

@@ -337,14 +337,18 @@ def disco_streaming_iter_time(shard_nnz, pcg_iters: int, partition: str,
 # online serving extension (docs/serving.md)
 #
 # The inference plane (repro.glm_serve) scores feature-vector requests
-# through the blocked-ELL kernels. Its latency structure is the inverse
-# of training's: per *tick* there is ONE kernel dispatch (jit call,
-# host->device staging, launch) whose fixed cost dwarfs the per-request
-# sparse dot product, so sequential single-request scoring is
-# dispatch-bound and micro-batching B requests amortizes the dispatch
-# over B — the ">= 4x at batch 64" gate of benchmarks/bench_serving.py
-# is exactly this amortization.
+# as (id, value) slots gathered against device-resident weights. Its
+# latency structure is the inverse of training's: per *tick* there is ONE
+# step dispatch (jit call, host->device staging, launch) whose fixed cost
+# dwarfs the per-request sparse dot product, so sequential
+# single-request scoring is dispatch-bound and micro-batching B requests
+# amortizes the dispatch over B — the ">= 4x at batch 64" gate of
+# benchmarks/bench_serving.py is exactly this amortization.
 # ---------------------------------------------------------------------------
+
+#: bytes of one packed scoring slot: an int32 feature id and an f32 value
+SLOT_BYTES = 4 + BYTES_PER_FLOAT
+
 
 def scoring_flops(nnz: int) -> int:
     """Flops of scoring stored request nonzeros: one multiply-add per
@@ -353,29 +357,24 @@ def scoring_flops(nnz: int) -> int:
     return 2 * nnz
 
 
-def glm_serving_tick_time(batch: int, nnz_per_req: float, *,
-                          ell_width: int, block_b: int, block_d: int,
+def glm_serving_tick_time(batch: int, nnz_per_req: float, *, slots: int,
                           dispatch_s: float = 2e-4,
                           flops_per_sec: float = 5e11,
                           bytes_per_sec: float = 1e10) -> dict:
     """Modeled seconds for ONE micro-batched scoring tick of ``batch``
-    requests.
+    requests packed ``slots`` (id, value) slots wide (``k`` of
+    :class:`repro.glm_serve.scoring.RequestPacker`).
 
     Three terms: the fixed per-tick ``dispatch_s`` (jit call + launch —
     paid once per tick regardless of batch); wire time for staging the
-    packed tile payload (the *padded* tile stream
-    ``ceil(batch / block_b) * ell_width`` tiles of ``block_b * block_d``
-    f32 values — padding slots cost bytes too, the serving face of the
-    load-imbalance story); and MXU time for the useful flops
+    pack, ``batch * slots`` slots of :data:`SLOT_BYTES` (padding slots
+    cost bytes too); and compute time for the useful flops
     (:func:`scoring_flops` over ``batch * nnz_per_req`` nonzeros).
 
     Returns a dict with ``dispatch_s``, ``stage_s``, ``compute_s``,
     ``total_s`` and ``per_request_s``.
     """
-    n_row_blocks = -(-max(batch, 1) // block_b)
-    tile_bytes = n_row_blocks * ell_width * block_b * block_d \
-        * BYTES_PER_FLOAT
-    stage_s = tile_bytes / bytes_per_sec
+    stage_s = max(batch, 1) * slots * SLOT_BYTES / bytes_per_sec
     compute_s = scoring_flops(int(batch * nnz_per_req)) / flops_per_sec
     total = dispatch_s + stage_s + compute_s
     return dict(dispatch_s=dispatch_s, stage_s=stage_s,
@@ -383,8 +382,7 @@ def glm_serving_tick_time(batch: int, nnz_per_req: float, *,
                 per_request_s=total / max(batch, 1))
 
 
-def glm_serving_throughput(batch: int, nnz_per_req: float, *,
-                           ell_width: int, block_b: int, block_d: int,
+def glm_serving_throughput(batch: int, nnz_per_req: float, *, slots: int,
                            dispatch_s: float = 2e-4,
                            flops_per_sec: float = 5e11,
                            bytes_per_sec: float = 1e10) -> dict:
@@ -392,18 +390,15 @@ def glm_serving_throughput(batch: int, nnz_per_req: float, *,
 
     ``batched_rps`` runs ticks of ``batch`` requests; ``sequential_rps``
     runs batch-1 ticks (one dispatch *per request* — the degenerate
-    schedule the ``bench_serving`` gate compares against). Their ratio
-    ``speedup`` approaches ``dispatch_s / per_request_work`` as requests
-    shrink: the smaller the request, the more batching pays.
+    schedule the ``bench_serving`` gate compares against), both
+    ``slots`` wide. Their ratio ``speedup`` approaches ``dispatch_s /
+    per_request_work`` as requests shrink: the smaller the request, the
+    more batching pays.
     """
-    tick = glm_serving_tick_time(
-        batch, nnz_per_req, ell_width=ell_width, block_b=block_b,
-        block_d=block_d, dispatch_s=dispatch_s,
-        flops_per_sec=flops_per_sec, bytes_per_sec=bytes_per_sec)
-    single = glm_serving_tick_time(
-        1, nnz_per_req, ell_width=ell_width, block_b=block_b,
-        block_d=block_d, dispatch_s=dispatch_s,
-        flops_per_sec=flops_per_sec, bytes_per_sec=bytes_per_sec)
+    kw = dict(slots=slots, dispatch_s=dispatch_s,
+              flops_per_sec=flops_per_sec, bytes_per_sec=bytes_per_sec)
+    tick = glm_serving_tick_time(batch, nnz_per_req, **kw)
+    single = glm_serving_tick_time(1, nnz_per_req, **kw)
     batched_rps = batch / tick["total_s"]
     sequential_rps = 1.0 / single["total_s"]
     return dict(batched_rps=batched_rps, sequential_rps=sequential_rps,

@@ -2,8 +2,8 @@
 
 The serving counterpart of the training stack (docs/serving.md): fitted
 :class:`repro.core.disco.DiscoResult` models are published to a
-versioned :class:`ModelRegistry`, scored in micro-batches through the
-blocked-ELL Pallas path (:class:`ScoringEngine` +
+versioned :class:`ModelRegistry`, scored in micro-batches of (id, value)
+slots gathered against device-resident weights (:class:`ScoringEngine` +
 :class:`MicroBatchScheduler`), and refreshed online by warm-started
 streaming refits (:class:`RefitLoop`) without pausing traffic.
 

@@ -1,31 +1,35 @@
-"""Batched sparse scoring: feature-vector requests through the ELL kernels.
+"""Batched sparse scoring: (id, value) slots gathered against resident weights.
 
 Inference for a fitted GLM is one sparse dot per request, ``margin =
-<x, w>``. Serving millions of them efficiently is a *layout* problem:
-the blocked-ELL Pallas path (:mod:`repro.kernels.sparse_hvp`) already
-streams tile lists with a static grid, so a **batch** of requests packed
-as the rows of a ``(B, d)`` sparse matrix scores with a single
-``ell_matvec`` against the weight vector — one kernel dispatch for the
-whole batch, the amortization the serving cost model
-(:func:`repro.core.comm.glm_serving_throughput`) and the
-``bench_serving`` throughput gate quantify.
+<x, w>``. A micro-batch of requests travels to the device as two small
+``(batch, k)`` arrays, each request's feature ids and values, and scores
+with one jitted gather-multiply-reduce against the weight vector, which
+stays on the device between ticks. A pack costs ``batch * k * 8`` bytes
+at float32 values, so one tick dispatch is amortized over the whole
+batch (:func:`repro.core.comm.glm_serving_throughput`, the
+``bench_serving`` throughput gate). Scoring does not use the solver's
+blocked-ELL tiles: those are full by construction, while a batch of
+independent requests would put about one nonzero in each tile.
 
 Pieces:
 
 * :class:`ScoreRequest` — one request: the (sparse) feature vector.
-* :class:`RequestPacker` — requests -> fixed-shape blocked-ELL tiles.
-  Every pack of the same packer has identical array shapes (short
-  batches are padded with empty rows, tile lists to a fixed ELL width),
-  so the jit'd scoring step compiles **once** — the shape-stable-tick
-  property the micro-batching scheduler
+* :class:`RequestPacker` — requests -> fixed-shape (id, value) slots.
+  ``k`` is the smallest power of two at least the batch's longest
+  request, and short batches pad with rows of padding slots, so the
+  shapes depend only on the request lengths: the jit'd step compiles
+  once per ``k``, and traffic of one request length compiles once —
+  the shape-stable tick the micro-batching scheduler
   (:mod:`repro.glm_serve.scheduler`) is built on.
+* :func:`slot_margins` — the step: per row, the sum of each slot's value
+  times the weight its id names.
 * :func:`oracle_margins` — the NumPy oracle the property tests and the
   ``bench_serving`` parity gate compare against.
 * :class:`ScoringEngine` — weights (from a
-  :class:`repro.glm_serve.registry.ModelRegistry` or given directly) +
-  packer + jit'd step + loss link (predict / predict_proba via the
-  :class:`repro.core.glm.GLMProblem` conventions), with between-tick
-  hot swap of a newly published model version.
+  :class:`repro.glm_serve.registry.ModelRegistry` or given directly) held
+  on the device + packer + jit'd step + loss link (predict /
+  predict_proba via the :class:`repro.core.glm.GLMProblem` conventions),
+  with between-tick hot swap of a newly published model version.
 """
 from __future__ import annotations
 
@@ -37,8 +41,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.losses import get_loss
-from repro.data.sparse import CSRMatrix, ell_from_csr
-from repro.kernels import ops as kops
 from repro.obs import tracer as obs
 
 
@@ -73,8 +75,9 @@ def oracle_margins(requests: Sequence[ScoreRequest], w: np.ndarray
     """NumPy reference margins ``<x_i, w>`` — the parity oracle.
 
     Computed per request as a float64 dot over its stored features, cast
-    to ``w.dtype``; what the packer + ELL kernel path must reproduce to
-    <= 1e-5 (``bench_serving`` gate, hypothesis property test).
+    to ``w.dtype``; what the packer + :func:`slot_margins` path must
+    reproduce to <= 1e-5 (``bench_serving`` gate, hypothesis property
+    test).
     """
     w = np.asarray(w)
     w64 = w.astype(np.float64)
@@ -86,65 +89,61 @@ def oracle_margins(requests: Sequence[ScoreRequest], w: np.ndarray
     return out.astype(w.dtype)
 
 
+def slot_margins(ids, vals, w):
+    """Margins of one pack: ``sum_j vals[i, j] * w[ids[i, j]]`` per row.
+
+    ``ids`` / ``vals`` are a pack of :class:`RequestPacker` and ``w`` the
+    ``(d + 1,)`` weights of :meth:`RequestPacker.pad_weights`, whose last
+    entry, the zero, is what every padding slot reads. Values stored
+    narrower than ``w`` (bfloat16) are widened before the product, so the
+    products and the sum are in ``w``'s precision.
+    """
+    return jnp.sum(vals.astype(w.dtype) * w[ids], axis=1)
+
+
 class RequestPacker:
-    """Packs up to ``batch`` requests into fixed-shape ELL tiles.
+    """Packs up to ``batch`` requests into fixed-shape (id, value) slots.
 
-    The batch matrix is ``R: (batch, d)`` with one request per row;
-    margins are ``R @ w``, so the forward blocked-ELL layout of ``R``
-    (row blocks of ``block_b`` requests, column blocks of ``block_d``
-    features) drives :func:`repro.kernels.ops.ell_matvec` directly.
+    Row ``i`` of a pack holds request ``i``'s feature ids and values; the
+    rest of the row, and every row of a short batch, are padding slots
+    with id ``d`` and value 0. Rows are ``k`` slots wide, ``k`` the
+    smallest power of two at least the batch's longest request (at least
+    1, see :meth:`slots`), so a pack's shape depends only on the request
+    lengths. An empty request (or batch) scores to zeros.
 
-    Shapes are **static** across packs: rows pad to
-    ``ceil(batch / block_b) * block_b`` (missing requests are empty
-    rows), the tile fan-out pads to ``width`` (default: the number of
-    feature blocks — always sufficient). A denser-than-``width`` pack
-    raises, mirroring ``ell_from_csr``; all-padding tiles (an entirely
-    empty batch) produce the zero-tile floor and score to zeros.
+    ``value_dtype`` is the storage dtype of the packed values (e.g.
+    bfloat16 for half-width packs); request values and weights stay
+    ``dtype``.
     """
 
-    def __init__(self, d: int, batch: int, block_b: int = 8,
-                 block_d: int = 128, width: int | None = None,
-                 dtype=np.float32, tile_dtype=None):
+    def __init__(self, d: int, batch: int, dtype=np.float32,
+                 value_dtype=None):
         if d <= 0 or batch <= 0:
             raise ValueError(f"need d > 0 and batch > 0, got d={d}, "
                              f"batch={batch}")
         self.d = d
         self.batch = batch
-        self.block_b = block_b
-        self.block_d = block_d
         self.dtype = np.dtype(dtype)
-        # tile_dtype: storage dtype of the packed ELL tiles (the bytes
-        # each scoring dispatch stages) — e.g. bfloat16 for half-width
-        # ticks; request values and weights stay ``dtype``, the kernel
-        # accumulates f32 (docs/kernels.md mixed-precision contract)
-        self.tile_dtype = self.dtype if tile_dtype is None \
-            else np.dtype(tile_dtype)
-        self.n_row_blocks = -(-batch // block_b)
-        self.n_col_blocks = max(-(-d // block_d), 1)
-        self.batch_padded = self.n_row_blocks * block_b
-        self.d_padded = self.n_col_blocks * block_d
-        self.width = width if width is not None else self.n_col_blocks
-        if not 1 <= self.width <= self.n_col_blocks:
-            raise ValueError(
-                f"width must be in [1, {self.n_col_blocks}], got "
-                f"{self.width}")
+        self.value_dtype = self.dtype if value_dtype is None \
+            else np.dtype(value_dtype)
 
     def validate(self, r: ScoreRequest, label: str = "request"
                  ) -> np.ndarray:
         """Check one request's feature ids (in range, no duplicates).
 
-        Returns the indices as int64. Duplicates must be rejected here:
-        the ELL tile scatter is last-write-wins, so a duplicate id would
-        silently mis-score instead of summing. Admission points (the
-        scheduler's ``submit``) call this too, so a malformed request
-        fails back to *its* submitter instead of poisoning a whole
-        packed batch.
+        Returns the indices as int64. An id out of range has no weight
+        (the gather would read the padding zero, or clamp), and a
+        duplicate id names one feature twice: both are malformed
+        requests, not margins. Admission points (the scheduler's ``submit``) call this
+        too, so a malformed request fails back to *its* submitter instead
+        of poisoning a whole packed batch.
         """
         idx = np.asarray(r.indices, np.int64)
-        if len(idx) and (idx.min() < 0 or idx.max() >= self.d):
+        srt = np.sort(idx)      # one sort: the extremes and the repeats
+        if len(srt) and (srt[0] < 0 or srt[-1] >= self.d):
             raise ValueError(
                 f"{label} has feature ids outside [0, {self.d})")
-        if len(idx) != len(np.unique(idx)):
+        if (srt[1:] == srt[:-1]).any():
             raise ValueError(f"{label} has duplicate feature ids")
         if len(idx) != len(np.asarray(r.values)):
             raise ValueError(
@@ -152,41 +151,40 @@ class RequestPacker:
                 f"{len(np.asarray(r.values))} values")
         return idx
 
+    @staticmethod
+    def slots(requests: Sequence[ScoreRequest]) -> int:
+        """``k`` of a batch: the smallest power of two at least its
+        longest request, and at least 1."""
+        longest = max((len(r.indices) for r in requests), default=0)
+        return 1 << max(longest - 1, 0).bit_length()
+
     def pack(self, requests: Sequence[ScoreRequest]
              ) -> tuple[np.ndarray, np.ndarray]:
-        """ELL ``(data, cols)`` of a batch (shapes fixed per packer).
+        """``(ids, vals)`` of a batch, each of shape ``(batch, k)``.
 
-        data : (n_row_blocks, width, block_b, block_d)
-        cols : (n_row_blocks, width) int32
+        ids  : int32, padding slots ``d``
+        vals : ``value_dtype``, padding slots 0
         """
         if len(requests) > self.batch:
             raise ValueError(f"{len(requests)} requests > batch size "
                              f"{self.batch}")
-        rows_l, cols_l, vals_l = [], [], []
-        for i, r in enumerate(requests):
-            idx = self.validate(r, label=f"request {i}")
-            rows_l.append(np.full(len(idx), i, np.int64))
-            cols_l.append(idx)
-            vals_l.append(np.asarray(r.values, self.dtype))
-        rows = np.concatenate(rows_l) if rows_l else np.zeros(0, np.int64)
-        cols = np.concatenate(cols_l) if cols_l else np.zeros(0, np.int64)
-        vals = (np.concatenate(vals_l) if vals_l
-                else np.zeros(0, self.dtype))
-        csr = CSRMatrix.from_coo(rows, cols, vals,
-                                 (self.batch_padded, self.d),
-                                 dtype=self.dtype)
-        ell = ell_from_csr(csr, self.block_b, self.block_d,
-                           width=self.width)
-        data = ell.data if ell.data.dtype == self.tile_dtype \
-            else ell.data.astype(self.tile_dtype)
-        return data, ell.cols
+        idx = [self.validate(r, label=f"request {i}")
+               for i, r in enumerate(requests)]
+        k = self.slots(requests)
+        ids = np.full((self.batch, k), self.d, np.int32)
+        vals = np.zeros((self.batch, k), self.value_dtype)
+        for i, (r, ix) in enumerate(zip(requests, idx)):
+            ids[i, :len(ix)] = ix
+            vals[i, :len(ix)] = np.asarray(r.values, self.dtype)
+        return ids, vals
 
     def pad_weights(self, w: np.ndarray) -> np.ndarray:
-        """Zero-pad ``(d,)`` weights to the packed ``(d_padded,)``."""
+        """``(d,)`` weights with the zero appended that padding slots
+        (id ``d``) read: ``(d + 1,)``."""
         w = np.asarray(w, self.dtype)
         if w.shape != (self.d,):
             raise ValueError(f"weights shape {w.shape} != ({self.d},)")
-        return np.pad(w, (0, self.d_padded - self.d))
+        return np.append(w, np.zeros(1, self.dtype))
 
 
 class ScoringEngine:
@@ -200,18 +198,15 @@ class ScoringEngine:
         loss: loss name for the prediction link; defaults to the
             registry model's ``cfg.loss`` (required for raw weights).
         batch: requests per scoring tick (the micro-batch width).
-        block_b / block_d / width: packer tile geometry
-            (:class:`RequestPacker`).
-        hvp_dtype: tile storage dtype of the packed request batches,
+        hvp_dtype: storage dtype of the packed request values,
             'float32' (default) or 'bfloat16' — the serving face of the
-            solver's ``DiscoConfig.hvp_dtype``: the scoring dispatch
-            stages half the tile bytes at bf16 while margins come back
-            f32-accumulated (the kernels' out_dtype contract).
+            solver's ``DiscoConfig.hvp_dtype``: a bf16 pack stages 6
+            bytes a slot instead of 8, while the weights, the products
+            and the margins stay f32.
     """
 
     def __init__(self, model, loss: str | None = None, *,
-                 batch: int = 64, block_b: int = 8, block_d: int = 128,
-                 width: int | None = None, hvp_dtype: str = "float32"):
+                 batch: int = 64, hvp_dtype: str = "float32"):
         from repro.data.sparse import hvp_tile_dtype
         from repro.glm_serve.registry import ModelRegistry
 
@@ -232,13 +227,11 @@ class ScoringEngine:
         dtype = w.dtype if np.issubdtype(w.dtype, np.floating) \
             else np.float32
         self.hvp_dtype = hvp_dtype
-        tile_dtype = hvp_tile_dtype(hvp_dtype)
-        self.packer = RequestPacker(len(w), batch, block_b=block_b,
-                                    block_d=block_d, width=width,
-                                    dtype=dtype, tile_dtype=tile_dtype)
+        self.packer = RequestPacker(len(w), batch, dtype=dtype,
+                                    value_dtype=hvp_tile_dtype(hvp_dtype))
         self.w = w
         self._w_dev = jnp.asarray(self.packer.pad_weights(self.w))
-        self._step = jax.jit(kops.ell_matvec)
+        self._step = jax.jit(slot_margins)
         self.reloads = 0
 
     @property
@@ -264,10 +257,8 @@ class ScoringEngine:
             if len(pub.w) != self.packer.d:
                 self.packer = RequestPacker(
                     len(pub.w), self.packer.batch,
-                    block_b=self.packer.block_b,
-                    block_d=self.packer.block_d,
                     dtype=self.packer.dtype,
-                    tile_dtype=self.packer.tile_dtype)
+                    value_dtype=self.packer.value_dtype)
             self.w = np.asarray(pub.w)
             self._w_dev = jnp.asarray(self.packer.pad_weights(self.w))
             self.version = v
@@ -278,24 +269,27 @@ class ScoringEngine:
     def score(self, requests: Sequence[ScoreRequest]) -> np.ndarray:
         """Margins ``<x_i, w>`` for any number of requests.
 
-        Requests are packed ``batch`` at a time; each pack is one jit'd
-        ELL matvec (the shapes never change, so after the first call
-        every tick reuses the same executable). Each piece of a pack
-        ends in a wait: the kernel needs the tiles and the copy back
-        waits for the kernel anyway, so the waits move the sync points
-        without adding work, and each piece's span times its own work.
+        Requests are packed ``batch`` at a time; each pack is one call of
+        the jit'd :func:`slot_margins` (one executable per ``k``, so
+        after the first pack of each ``k`` every tick reuses one). Each
+        piece of a pack ends in a wait: the step needs the slots and the
+        copy back waits for the step anyway, so the waits move the sync
+        points without adding work, and each piece's span times its own
+        work. The ``serve.pack`` span carries ``k``, so a pack that
+        widens ``k`` (and compiles) shows in a trace.
         """
         out = np.zeros(len(requests), self.packer.dtype)
         for lo in range(0, len(requests), self.packer.batch):
             part = requests[lo: lo + self.packer.batch]
-            with obs.span("serve.pack"):
-                data, cols = self.packer.pack(part)
+            with obs.span("serve.pack", k=self.packer.slots(part)):
+                ids, vals = self.packer.pack(part)
+                obs.count("serve.pack_bytes", ids.nbytes + vals.nbytes)
             with obs.span("serve.copy_in"):
-                data, cols = jax.block_until_ready(
-                    (jnp.asarray(data), jnp.asarray(cols)))
+                ids, vals = jax.block_until_ready(
+                    (jnp.asarray(ids), jnp.asarray(vals)))
             with obs.span("serve.kernel"):
                 y = jax.block_until_ready(
-                    self._step(data, cols, self._w_dev))
+                    self._step(ids, vals, self._w_dev))
             with obs.span("serve.copy_out"):
                 out[lo: lo + len(part)] = np.asarray(y)[: len(part)]
         return out

@@ -125,15 +125,17 @@ SPAN_KINDS: dict[str, tuple[str, str, str]] = {
         "index, scored count)"),
     "serve.pack": (
         "serve", "span",
-        "one batch packed into blocked-ELL tiles on the host "
-        "(`RequestPacker.pack`; nested inside serve.tick)"),
+        "one batch packed into (id, value) slots on the host "
+        "(`RequestPacker.pack`; arg `k`: slots per request; nested "
+        "inside serve.tick)"),
     "serve.copy_in": (
         "serve", "span",
-        "the packed tiles copied to the device, until both arrays are "
+        "the packed slots copied to the device, until both arrays are "
         "ready"),
     "serve.kernel": (
         "serve", "span",
-        "the scoring `ell_matvec` dispatched and waited for"),
+        "the scoring step (`slot_margins`: gather, multiply, row sum) "
+        "dispatched and waited for"),
     "serve.copy_out": (
         "serve", "span",
         "the margins copied back to the host"),
@@ -151,6 +153,9 @@ COUNTER_KINDS: dict[str, str] = {
         "SPMD collective launches (analytic tally, both paths)"),
     "io.retries": "transient I/O failures retried by the retry policy",
     "serve.scored": "requests scored by the micro-batch scheduler",
+    "serve.pack_bytes": (
+        "bytes of the packed (id, value) slots staged to the device, "
+        "summed over packs: `batch x k x 8` per float32 pack"),
     "serve.queue_wait_s": (
         "seconds the scored requests waited in the scheduler's queue, "
         "submit to admission, on the scheduler's clock (over "

@@ -4,6 +4,7 @@ refits; plus the GLMProblem inference API parity tests."""
 import dataclasses
 import os
 
+import jax
 import numpy as np
 import pytest
 
@@ -15,12 +16,13 @@ from repro.data.store import ShardStore
 from repro.glm_serve import (MicroBatchScheduler, ModelRegistry,
                              RequestPacker, ScoreRequest, ScoringEngine,
                              oracle_margins, RefitLoop)
+from repro.glm_serve.scoring import slot_margins
 
 
 @pytest.fixture()
 def ref_mode(monkeypatch):
-    # scoring applies kernels eagerly per tick; interpret-mode python
-    # emulation is needlessly slow for these shapes
+    # the refit's solver kernels in interpret-mode python emulation are
+    # needlessly slow for these shapes
     monkeypatch.setenv("REPRO_KERNEL_MODE", "ref")
 
 
@@ -169,44 +171,52 @@ class TestRegistry:
 # request packer vs the NumPy oracle
 # ---------------------------------------------------------------------------
 
-def _packed_margins(packer, requests, w, mode="ref"):
-    from repro.kernels import ops as kops
-    data, cols = packer.pack(requests)
-    y = kops.ell_matvec(data, cols, packer.pad_weights(w), mode=mode)
+def _packed_margins(packer, requests, w):
+    """Margins of one pack through the engine's jitted step."""
+    ids, vals = packer.pack(requests)
+    y = jax.jit(slot_margins)(ids, vals, packer.pad_weights(w))
     return np.asarray(y)[: len(requests)]
 
 
 class TestPacker:
     def test_shapes_static_across_packs(self):
-        p = RequestPacker(d=40, batch=6, block_b=4, block_d=16)
-        w = np.ones(40, np.float32)
-        shapes = set()
+        """One shape per k: empty, one-request, empty-feature and full
+        batches of the same k pack alike, and the shape follows only the
+        longest request."""
+        p = RequestPacker(d=40, batch=6)
+        one = ScoreRequest(np.array([0]), np.array([1.0]))
+        empty = ScoreRequest(np.array([], np.int64),
+                             np.array([], np.float32))
+        shapes = {}
         batches = [
             [],                                           # empty batch
-            [ScoreRequest(np.array([0]), np.array([1.0]))],
-            [ScoreRequest(np.array([], np.int64),
-                          np.array([], np.float32))] * 6,  # empty features
-            [ScoreRequest(np.arange(40), np.ones(40, np.float32))] * 3,
+            [one],
+            [empty] * 6,                                  # empty features
+            [one] * 6,                                    # full batch
+            [ScoreRequest(np.arange(3), np.ones(3, np.float32))] * 3,
+            [ScoreRequest(np.arange(40), np.ones(40, np.float32)), one],
         ]
         for reqs in batches:
-            data, cols = p.pack(reqs)
-            shapes.add((data.shape, cols.shape))
-        assert len(shapes) == 1
-        ((ds, cs),) = shapes
-        assert ds == (2, 3, 4, 16) and cs == (2, 3)
+            ids, vals = p.pack(reqs)
+            assert ids.dtype == np.int32 and vals.dtype == np.float32
+            assert ids.shape == vals.shape
+            shapes.setdefault(p.slots(reqs), set()).add(ids.shape)
+        assert shapes == {1: {(6, 1)}, 4: {(6, 4)}, 64: {(6, 64)}}
 
     def test_all_padding_tiles_score_zero(self):
-        p = RequestPacker(d=32, batch=4, block_b=4, block_d=8)
+        p = RequestPacker(d=32, batch=4)
         w = np.linspace(1, 2, 32).astype(np.float32)
         out = _packed_margins(p, [], w)
         assert out.shape == (0,)
         empty = [ScoreRequest(np.array([], np.int64),
                               np.array([], np.float32))] * 3
+        ids, vals = p.pack(empty)
+        assert np.all(ids == 32) and np.all(vals == 0)
         np.testing.assert_array_equal(_packed_margins(p, empty, w),
                                       np.zeros(3, np.float32))
 
     def test_single_request_batch(self):
-        p = RequestPacker(d=20, batch=8, block_b=8, block_d=8)
+        p = RequestPacker(d=20, batch=8)
         w = np.arange(20, dtype=np.float32)
         r = ScoreRequest(np.array([3, 17]), np.array([2.0, -1.0],
                                                      np.float32))
@@ -214,14 +224,16 @@ class TestPacker:
                                    oracle_margins([r], w), rtol=1e-6)
 
     def test_rejects_bad_requests(self):
-        p = RequestPacker(d=16, batch=2, block_b=2, block_d=8)
+        p = RequestPacker(d=16, batch=2)
         with pytest.raises(ValueError, match="outside"):
             p.pack([ScoreRequest(np.array([16]), np.array([1.0]))])
+        with pytest.raises(ValueError, match="outside"):
+            p.pack([ScoreRequest(np.array([-1]), np.array([1.0]))])
         with pytest.raises(ValueError, match="batch size"):
             p.pack([ScoreRequest(np.array([0]), np.array([1.0]))] * 3)
-        with pytest.raises(ValueError, match="width"):
-            RequestPacker(d=16, batch=2, width=9)
-        # duplicates would be last-write-wins in the tile scatter -> raise
+        with pytest.raises(ValueError, match="d > 0"):
+            RequestPacker(d=0, batch=2)
+        # a sparse vector names each feature once
         with pytest.raises(ValueError, match="duplicate"):
             p.pack([ScoreRequest(np.array([3, 3]),
                                  np.array([1.0, 2.0], np.float32))])
@@ -230,16 +242,26 @@ class TestPacker:
                                  np.array([1.0], np.float32))])
 
     def test_narrow_width_overflow_raises(self):
-        # 2 feature blocks hit but width=1 -> the ell layout must refuse
-        p = RequestPacker(d=16, batch=2, block_b=2, block_d=8, width=1)
-        dense = ScoreRequest(np.array([0, 15]), np.ones(2, np.float32))
-        with pytest.raises(ValueError, match="width"):
-            p.pack([dense])
+        """A request longer than the last pack's k does not overflow: its
+        pack widens to the next power of two, and every pack still
+        matches the oracle."""
+        rng = np.random.default_rng(5)
+        w = rng.standard_normal(16).astype(np.float32)
+        eng = ScoringEngine(w, loss="logistic", batch=2)
+        narrow = ScoreRequest(np.array([0, 15]), np.ones(2, np.float32))
+        wide = ScoreRequest(np.arange(1, 12),
+                            rng.standard_normal(11).astype(np.float32))
+        assert eng.packer.pack([narrow])[0].shape == (2, 2)
+        assert eng.packer.pack([narrow, wide])[0].shape == (2, 16)
+        for reqs in ([narrow], [narrow, wide], [narrow]):
+            np.testing.assert_allclose(eng.score(reqs),
+                                       oracle_margins(reqs, w),
+                                       rtol=1e-5, atol=1e-6)
 
     def test_property_packer_matches_oracle(self):
-        """Property test: packed-ELL scoring == NumPy oracle across
+        """Property test: packed-slot scoring == NumPy oracle across
         request sparsity (incl. empty-feature requests), batch fill
-        levels (single request, exactly-full), tile geometry, and
+        levels (single request, exactly-full), slot widths, and
         duplicate-free random feature subsets."""
         pytest.importorskip("hypothesis")
         from hypothesis import given, settings, strategies as st
@@ -248,13 +270,11 @@ class TestPacker:
         @given(
             d=st.integers(1, 40),
             batch=st.integers(1, 9),
-            block_b=st.integers(1, 4),
-            block_d=st.integers(1, 12),
             n_reqs=st.integers(0, 9),
             density=st.floats(0.0, 1.0),   # 0.0 -> empty-feature requests
             seed=st.integers(0, 2 ** 16),
         )
-        def check(d, batch, block_b, block_d, n_reqs, density, seed):
+        def check(d, batch, n_reqs, density, seed):
             n_reqs = min(n_reqs, batch)
             rng = np.random.default_rng(seed)
             reqs = []
@@ -265,13 +285,35 @@ class TestPacker:
                     indices=idx.astype(np.int64),
                     values=rng.standard_normal(k).astype(np.float32)))
             w = rng.standard_normal(d).astype(np.float32)
-            p = RequestPacker(d=d, batch=batch, block_b=block_b,
-                              block_d=block_d)
+            p = RequestPacker(d=d, batch=batch)
             got = _packed_margins(p, reqs, w)
             np.testing.assert_allclose(got, oracle_margins(reqs, w),
                                        rtol=1e-4, atol=1e-5)
 
         check()
+
+    def test_padding_slots_add_exactly_zero(self):
+        """Padding slots read the zero kept after the weights, never
+        ``w[0]``: an infinite or huge ``w[0]`` leaves every margin
+        exact."""
+        d = 24
+        rng = np.random.default_rng(2)
+        for w0 in (np.inf, np.finfo(np.float32).max):
+            w = rng.standard_normal(d).astype(np.float32)
+            w[0] = w0
+            p = RequestPacker(d=d, batch=4)
+            reqs = [ScoreRequest(np.array([3, 9, 20]),
+                                 np.array([1.0, -2.0, 0.5], np.float32)),
+                    ScoreRequest(np.array([5]), np.array([4.0],
+                                                         np.float32))]
+            ids, vals = p.pack(reqs)
+            assert ids.shape == (4, 4) and np.all(ids[2:] == d)
+            y = np.asarray(jax.jit(slot_margins)(ids, vals,
+                                                 p.pad_weights(w)))
+            assert np.all(np.isfinite(y))
+            np.testing.assert_array_equal(y[2:], np.zeros(2, np.float32))
+            np.testing.assert_allclose(y[:2], oracle_margins(reqs, w),
+                                       rtol=1e-6)
 
 
 # ---------------------------------------------------------------------------
@@ -279,13 +321,12 @@ class TestPacker:
 # ---------------------------------------------------------------------------
 
 class TestScoringEngine:
-    def test_parity_and_chunking(self, ref_mode):
+    def test_parity_and_chunking(self):
         X, y, _ = _sparse_problem()
         Xd = X.todense()
         rng = np.random.default_rng(1)
         w = rng.standard_normal(X.shape[0]).astype(np.float32)
-        eng = ScoringEngine(w, loss="logistic", batch=8, block_b=4,
-                            block_d=16)
+        eng = ScoringEngine(w, loss="logistic", batch=8)
         reqs = _requests_from_cols(Xd, range(19))   # 2 full packs + tail
         np.testing.assert_allclose(eng.score(reqs),
                                    oracle_margins(reqs, w), rtol=1e-4,
@@ -299,12 +340,12 @@ class TestScoringEngine:
         with pytest.raises(ValueError, match="loss"):
             ScoringEngine(np.ones(4, np.float32))
 
-    def test_registry_hot_swap(self, tmp_path, ref_mode):
+    def test_registry_hot_swap(self, tmp_path):
         reg = ModelRegistry(str(tmp_path / "reg"))
         cfg = DiscoConfig(loss="logistic")
         res1 = _fake_result(d=24, seed=1)
         reg.publish(res1, cfg)
-        eng = ScoringEngine(reg, batch=4, block_b=2, block_d=8)
+        eng = ScoringEngine(reg, batch=4)
         assert eng.version == 1
         r = ScoreRequest(np.array([0, 5]), np.array([1.0, 2.0],
                                                     np.float32))
@@ -319,6 +360,49 @@ class TestScoringEngine:
         np.testing.assert_allclose(m2, oracle_margins([r], res2.w)[0],
                                    rtol=1e-5)
 
+    def test_bf16_values_keep_f32_weights_and_margins(self):
+        """``hvp_dtype='bfloat16'`` stores the packed values at bf16 only:
+        the device weights and the margins stay f32, and the margins are
+        the f32 dots of the bf16-rounded values."""
+        import ml_dtypes
+        rng = np.random.default_rng(4)
+        w = rng.standard_normal(32).astype(np.float32)
+        eng = ScoringEngine(w, loss="logistic", batch=4,
+                            hvp_dtype="bfloat16")
+        reqs = [ScoreRequest(np.array([1, 7, 30]),
+                             rng.standard_normal(3).astype(np.float32)),
+                ScoreRequest(np.array([4]), np.array([1 / 3], np.float32))]
+        ids, vals = eng.packer.pack(reqs)
+        assert vals.dtype == ml_dtypes.bfloat16 and ids.dtype == np.int32
+        assert eng._w_dev.dtype == np.float32
+        got = eng.score(reqs)
+        assert got.dtype == np.float32
+        rounded = [ScoreRequest(r.indices, np.asarray(
+            np.asarray(r.values, ml_dtypes.bfloat16), np.float32))
+            for r in reqs]
+        np.testing.assert_allclose(got, oracle_margins(rounded, w),
+                                   rtol=1e-6)
+        assert np.max(np.abs(got - oracle_margins(reqs, w))) > 0
+
+    def test_pack_bytes_counts_the_staged_slots(self):
+        """``serve.pack_bytes`` adds ``batch * k * 8`` per f32 pack, and
+        each ``serve.pack`` span carries its ``k``."""
+        from repro import obs
+        w = np.arange(100, dtype=np.float32)
+        eng = ScoringEngine(w, loss="logistic", batch=8)
+        reqs = [ScoreRequest(np.arange(i, i + 39),
+                             np.ones(39, np.float32)) for i in range(8)]
+        reqs += [ScoreRequest(np.array([2, 3]), np.ones(2, np.float32))]
+        tracer = obs.enable(reset=True)
+        try:
+            eng.score(reqs)                 # one pack of k=64, one of k=2
+            events, counters, _ = tracer.snapshot()
+        finally:
+            obs.disable()
+        assert counters["serve.pack_bytes"] == 8 * 64 * 8 + 8 * 2 * 8
+        assert [e.args["k"] for e in events
+                if e.kind == "serve.pack"] == [64, 2]
+
 
 # ---------------------------------------------------------------------------
 # micro-batching scheduler
@@ -328,10 +412,9 @@ class TestScheduler:
     def _engine(self, d=24, seed=0, batch=4):
         rng = np.random.default_rng(seed)
         w = rng.standard_normal(d).astype(np.float32)
-        return w, ScoringEngine(w, loss="logistic", batch=batch,
-                                block_b=2, block_d=8)
+        return w, ScoringEngine(w, loss="logistic", batch=batch)
 
-    def test_drains_queue_and_matches_oracle(self, ref_mode):
+    def test_drains_queue_and_matches_oracle(self):
         w, eng = self._engine()
         rng = np.random.default_rng(3)
         reqs = [ScoreRequest.from_dense(
@@ -349,7 +432,7 @@ class TestScheduler:
         assert sched.stats.p50_s <= sched.stats.p99_s
         assert sched.stats.throughput_rps(1.0) == 11
 
-    def test_deadline_rejection(self, ref_mode):
+    def test_deadline_rejection(self):
         _, eng = self._engine()
         t = [0.0]
         sched = MicroBatchScheduler(eng, clock=lambda: t[0])
@@ -365,7 +448,7 @@ class TestScheduler:
         assert not sched.finished[rid_none].rejected
         assert sched.stats.rejected == 1 and sched.stats.completed == 2
 
-    def test_malformed_submit_fails_fast_not_the_batch(self, ref_mode):
+    def test_malformed_submit_fails_fast_not_the_batch(self):
         """A bad request raises at submit() — it never enters the queue,
         so a later tick cannot lose the innocent requests batched with
         it."""
@@ -385,11 +468,11 @@ class TestScheduler:
         got = sched.take_finished()
         assert list(got) == [rid] and sched.finished == {}
 
-    def test_hot_swap_between_ticks(self, tmp_path, ref_mode):
+    def test_hot_swap_between_ticks(self, tmp_path):
         reg = ModelRegistry(str(tmp_path / "reg"))
         cfg = DiscoConfig(loss="logistic")
         reg.publish(_fake_result(d=24, seed=1), cfg)
-        eng = ScoringEngine(reg, batch=2, block_b=2, block_d=8)
+        eng = ScoringEngine(reg, batch=2)
         sched = MicroBatchScheduler(eng)
         r = ScoreRequest(np.array([1]), np.array([1.0], np.float32))
         a = sched.submit(r)

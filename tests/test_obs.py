@@ -436,14 +436,14 @@ def test_checkpoint_roundtrips_history_and_resume_matches(tmp_path,
 # serving plane: tick spans + queue gauges
 # ---------------------------------------------------------------------------
 
-def test_scheduler_ticks_emit_spans_and_gauges(ref_mode):
+def test_scheduler_ticks_emit_spans_and_gauges():
     from repro import obs
     from repro.glm_serve import (MicroBatchScheduler, ScoreRequest,
                                  ScoringEngine)
 
     rng = np.random.default_rng(0)
     w = rng.standard_normal(24).astype(np.float32)
-    eng = ScoringEngine(w, loss="logistic", batch=4, block_b=2, block_d=8)
+    eng = ScoringEngine(w, loss="logistic", batch=4)
     sched = MicroBatchScheduler(eng)
     tracer = obs.enable(reset=True)
     for _ in range(9):
@@ -460,8 +460,7 @@ def test_scheduler_ticks_emit_spans_and_gauges(ref_mode):
     assert gauges["serve.queue_depth"] == 1          # depth before last tick
 
 
-def test_tick_pieces_nest_in_each_scored_tick_and_queue_wait_counts(
-        ref_mode):
+def test_tick_pieces_nest_in_each_scored_tick_and_queue_wait_counts():
     """Each tick that scores shows pack, copy in, kernel and copy out
     once, in order, inside its serve.tick; an empty tick shows none. On
     a fake clock the queue-wait counter is the hand-computed sum."""
@@ -473,7 +472,7 @@ def test_tick_pieces_nest_in_each_scored_tick_and_queue_wait_counts(
               "serve.copy_out")
     rng = np.random.default_rng(0)
     w = rng.standard_normal(24).astype(np.float32)
-    eng = ScoringEngine(w, loss="logistic", batch=4, block_b=2, block_d=8)
+    eng = ScoringEngine(w, loss="logistic", batch=4)
     now = [0.0]
     sched = MicroBatchScheduler(eng, clock=lambda: now[0])
     tracer = obs.enable(reset=True)

@@ -22,6 +22,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from repro.core import disco
+from repro.glm_serve.scoring import slot_margins
 from repro.kernels import ops
 from repro.launch.mesh import make_mesh
 
@@ -120,8 +121,8 @@ SPARSE = {
                    [((NCB, WT, BC, BR), jnp.float32),
                     ((NCB * BC,), jnp.float32),
                     ((NB * BR, S), jnp.float32), ((NCB, WT), jnp.int32)]),
-    # the scoring engine's request tiles: 8 requests x 128 features
-    "ell_matvec_scoring": (
+    # the matvec at short 8 x 128 tiles, 40 of 7,813 column tiles a row
+    "ell_matvec_8x128_tile": (
         lambda x, v, col: ops.ell_matvec(x, col, v, mode="native"),
         [((8, 40, 8, 128), jnp.float32), ((7813 * 128,), jnp.float32),
          ((8, 40), jnp.int32)]),
@@ -132,6 +133,21 @@ SPARSE = {
 def test_ell_kernel_compiles_for_v5e(one_chip, name):
     fn, shapes = SPARSE[name]
     _compile(fn, one_chip, *shapes)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_scoring_step_compiles_for_v5e(one_chip, dtype):
+    """The scoring engine's step at ``ctr``'s shape: 64 requests of 64
+    (id, value) slots gathered against d = 1,000,000 weights and the
+    padding zero. No Pallas kernel: XLA's gather and row sum."""
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in (((64, 64), jnp.int32), ((64, 64), dtype),
+                          ((1_000_001,), jnp.float32))]
+    compiled = jax.jit(slot_margins).lower(*args).compile()
+    assert "gather" in compiled.as_text()
+    out = jax.eval_shape(slot_margins, *args)
+    assert out.shape == (64,) and out.dtype == jnp.float32
 
 
 @pytest.mark.parametrize("body", ["mv", "hvp", "hvp_two_pass"])

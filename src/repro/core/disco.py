@@ -15,6 +15,7 @@ affine-invariant and globally convergent (Zhang & Xiao 2015).
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import functools
 import time
@@ -340,13 +341,8 @@ class DiscoSolver:
             Xp, self._dpad = pad_to_multiple(X, 0, self.m)
             self.d_padded = Xp.shape[0]
             X_tau_p, _ = pad_to_multiple(X_tau, 0, self.m)
-            xs = NamedSharding(self.mesh, P(axis, None))
-            rep = NamedSharding(self.mesh, P())
-            self.X = jax.device_put(jnp.asarray(Xp), xs)
-            self.X_tau = jax.device_put(jnp.asarray(X_tau_p),
-                                        NamedSharding(self.mesh, P(axis, None)))
-            self.y = jax.device_put(jnp.asarray(y), rep)
-            self.y_tau = jax.device_put(jnp.asarray(y_tau), rep)
+            self._place(X=(Xp, P(axis, None)), X_tau=(X_tau_p, P(axis, None)),
+                        y=(y, P()), y_tau=(y_tau, P()))
             self.weights = None
             self._w_sharding = NamedSharding(self.mesh, P(axis))
             self._w_shape = (self.d_padded,)
@@ -356,15 +352,10 @@ class DiscoSolver:
             wts = np.ones(self.n, X.dtype)
             wts = np.pad(wts, (0, npad))
             self.n_padded = Xp.shape[1]
-            xs = NamedSharding(self.mesh, P(None, axis))
-            ss = NamedSharding(self.mesh, P(axis))
-            rep = NamedSharding(self.mesh, P())
-            self.X = jax.device_put(jnp.asarray(Xp), xs)
-            self.y = jax.device_put(jnp.asarray(yp), ss)
-            self.weights = jax.device_put(jnp.asarray(wts), ss)
-            self.X_tau = jax.device_put(jnp.asarray(X_tau), rep)
-            self.y_tau = jax.device_put(jnp.asarray(y_tau), rep)
-            self._w_sharding = rep
+            self._place(X=(Xp, P(None, axis)), y=(yp, P(axis)),
+                        weights=(wts, P(axis)), X_tau=(X_tau, P()),
+                        y_tau=(y_tau, P()))
+            self._w_sharding = NamedSharding(self.mesh, P())
             self._w_shape = (self.d,)
         else:
             raise ValueError(f"unknown partition {cfg.partition!r}")
@@ -392,7 +383,6 @@ class DiscoSolver:
         # preconditioner samples: the first tau *original* columns
         X_tau = X.take_cols_dense(np.arange(self.tau))          # (d, tau)
         y_tau = y[: self.tau].copy()
-        rep = NamedSharding(self.mesh, P())
 
         hdt = hvp_tile_dtype(cfg.hvp_dtype)
 
@@ -413,18 +403,8 @@ class DiscoSolver:
             valid = part.perm < d
             X_tau_p[valid] = X_tau[part.perm[valid]]
 
-            es = NamedSharding(self.mesh, P(axis, None, None, None, None))
-            cs = NamedSharding(self.mesh, P(axis, None))
-            self.ell_data = jax.device_put(jnp.asarray(data), es)
-            self.ell_cols = jax.device_put(jnp.asarray(cols), cs)
-            self.ell_dataT = jax.device_put(jnp.asarray(dataT), es)
-            self.ell_colsT = jax.device_put(jnp.asarray(colsT), cs)
-            self.X_tau = jax.device_put(jnp.asarray(X_tau_p),
-                                        NamedSharding(self.mesh,
-                                                      P(axis, None)))
-            self.y = jax.device_put(jnp.asarray(y_p), rep)
-            self.y_tau = jax.device_put(jnp.asarray(y_tau), rep)
-            self.smask = jax.device_put(jnp.asarray(smask), rep)
+            placed = dict(X_tau=(X_tau_p, P(axis, None)), y=(y_p, P()),
+                          smask=(smask, P()))
             self._w_sharding = NamedSharding(self.mesh, P(axis))
             self._w_shape = (self.d_padded,)
         elif cfg.partition == "samples":
@@ -443,36 +423,50 @@ class DiscoSolver:
             X_tau_p = np.zeros((self.d_padded, self.tau), dtype)
             X_tau_p[:d] = X_tau
 
-            es = NamedSharding(self.mesh, P(axis, None, None, None, None))
-            cs = NamedSharding(self.mesh, P(axis, None))
-            ss = NamedSharding(self.mesh, P(axis))
-            self.ell_data = jax.device_put(jnp.asarray(data), es)
-            self.ell_cols = jax.device_put(jnp.asarray(cols), cs)
-            self.ell_dataT = jax.device_put(jnp.asarray(dataT), es)
-            self.ell_colsT = jax.device_put(jnp.asarray(colsT), cs)
-            self.y = jax.device_put(jnp.asarray(y_p), ss)
-            self.weights = jax.device_put(jnp.asarray(wts), ss)
-            self.X_tau = jax.device_put(jnp.asarray(X_tau_p), rep)
-            self.y_tau = jax.device_put(jnp.asarray(y_tau), rep)
-            self._w_sharding = rep
+            placed = dict(y=(y_p, P(axis)), weights=(wts, P(axis)),
+                          X_tau=(X_tau_p, P()))
+            self._w_sharding = NamedSharding(self.mesh, P())
             self._w_shape = (self.d_padded,)
         else:
             raise ValueError(f"unknown partition {cfg.partition!r}")
         self._part = part
+        es, cs = P(axis, None, None, None, None), P(axis, None)
+        self._place(ell_data=(data, es), ell_cols=(cols, cs),
+                    ell_dataT=(dataT, es), ell_colsT=(colsT, cs),
+                    y_tau=(y_tau, P()), **placed)
 
         # mixed-precision HVP tile copies (docs/kernels.md): the PCG loop
         # streams these; margins/gradient keep the f32 layouts and the
         # cols arrays are shared (int32 either way). Same objects at the
-        # default hvp_dtype, so f32 costs nothing.
-        if data.dtype == hdt:
+        # default hvp_dtype, so f32 costs nothing; otherwise each device
+        # casts its own shard.
+        if self.ell_data.dtype == hdt:
             self.ell_data_h = self.ell_data
             self.ell_dataT_h = self.ell_dataT
         else:
-            es = NamedSharding(self.mesh, P(axis, None, None, None, None))
-            self.ell_data_h = jax.device_put(
-                jnp.asarray(data.astype(hdt)), es)
-            self.ell_dataT_h = jax.device_put(
-                jnp.asarray(dataT.astype(hdt)), es)
+            self.ell_data_h = self.ell_data.astype(hdt)
+            self.ell_dataT_h = self.ell_dataT.astype(hdt)
+
+    def _place(self, **arrays):
+        """Put host arrays, ``name=(array, spec)``, on the mesh as
+        ``self.<name>``: each device receives only the shard that
+        ``spec`` gives it, sliced on the host, so no device ever holds
+        a whole sharded array on its way to the others. Waits for the
+        copies, inside the ``disco.place`` span."""
+        total = sum(a.size * jax.dtypes.canonicalize_dtype(a.dtype).itemsize
+                    for a, _ in arrays.values())
+        with obs.span("disco.place", bytes=total, shards=self.m):
+            placed = {name: jax.device_put(a, NamedSharding(self.mesh, spec))
+                      for name, (a, spec) in arrays.items()}
+            jax.block_until_ready(list(placed.values()))
+        for name, a in placed.items():
+            setattr(self, name, a)
+        if obs.enabled():
+            per_device = collections.Counter()
+            for a in placed.values():
+                for s in a.addressable_shards:
+                    per_device[s.device] += s.data.nbytes
+            obs.count("disco.place_bytes_max", max(per_device.values()))
 
     # ------------------------------------------------------------------
     def _build_step(self):

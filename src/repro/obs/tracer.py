@@ -65,6 +65,11 @@ SPAN_KINDS: dict[str, tuple[str, str, str]] = {
         "the jitted outer step inside `newton.outer`: dispatch, "
         "`block_until_ready` and the stats' host conversion (the extent "
         "of `iter_s`)"),
+    "disco.place": (
+        "core", "span",
+        "the solver's data put on the mesh at construction, each device "
+        "sent only its own shards, until the copies are done (args: "
+        "`bytes` placed in all, `shards` on the mesh axis)"),
     "pcg.round": (
         "core", "span",
         "one host-driven streamed PCG round (classic iteration or "
@@ -151,6 +156,10 @@ COUNTER_KINDS: dict[str, str] = {
     "comm.floats": "floats communicated (analytic tally, both paths)",
     "comm.spmd_collectives": (
         "SPMD collective launches (analytic tally, both paths)"),
+    "disco.place_bytes_max": (
+        "bytes of the solver's placed data held by its most-loaded "
+        "device, summed over the shards of every array `disco.place` "
+        "put (a replicated array counts on each device)"),
     "io.retries": "transient I/O failures retried by the retry policy",
     "serve.scored": "requests scored by the micro-batch scheduler",
     "serve.pack_bytes": (

@@ -41,6 +41,16 @@ def glm_hvp_oracle(X, c, u, lam, n_global=None):
     return local_hvp_oracle(X, c, u) / n + lam * np.asarray(u, np.float64)
 
 
+def logistic_grad_oracle(X, y, w, lam):
+    """Float64 gradient of the L2-regularised logistic objective
+    ``(1/n) sum_i log(1 + exp(-y_i x_i^T w)) + (lam/2) ||w||^2`` at w."""
+    X = np.asarray(X, np.float64)
+    y = np.asarray(y, np.float64)
+    w = np.asarray(w, np.float64)
+    s = 0.5 * (1.0 + np.tanh(-0.5 * y * (X.T @ w)))   # sigmoid(-y x^T w)
+    return X @ (-y * s) / X.shape[1] + lam * w
+
+
 def newton_direction_oracle(prob, w):
     """Dense NumPy Newton direction ``H^{-1} g`` of a GLMProblem at w
     (the target every PCG variant must solve to its tolerance)."""

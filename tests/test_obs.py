@@ -336,6 +336,36 @@ def test_inmemory_counter_matches_ledger_and_iter_s(ref_mode, glm_data):
         assert h["iter_s"] > 0.0             # per-iteration wall-clock
 
 
+@pytest.mark.parametrize("layout", ["dense", "sparse"])
+def test_solver_placement_spans_once_per_construction(ref_mode, glm_data,
+                                                      layout):
+    """Each ``DiscoSolver`` construction opens one ``disco.place`` span
+    and adds its most-loaded device's placed bytes to
+    ``disco.place_bytes_max``; both kinds are registered."""
+    from repro import obs
+    from repro.core import DiscoConfig, DiscoSolver
+    from repro.obs.tracer import COUNTER_KINDS, SPAN_KINDS
+
+    assert SPAN_KINDS["disco.place"][1] == "span"
+    assert "disco.place_bytes_max" in COUNTER_KINDS
+    X, y, _ = glm_data if layout == "dense" else _sparse_problem()
+    cfg = DiscoConfig(partition="samples", loss="logistic", lam=1e-2,
+                      tau=16, ell_block_d=8, ell_block_n=8)
+    tracer = obs.enable(reset=True)
+    solvers = [DiscoSolver(X, y, cfg) for _ in range(2)]
+    events, counters, _ = tracer.snapshot()
+    place = [e for e in events if e.kind == "disco.place"]
+    assert len(place) == 2 and all(e.ph == "X" for e in place)
+    assert {e.args["shards"] for e in place} == {1}
+    placed = (["X", "y", "weights", "X_tau", "y_tau"] if layout == "dense"
+              else ["ell_data", "ell_cols", "ell_dataT", "ell_colsT", "y",
+                    "weights", "X_tau", "y_tau"])
+    one = sum(getattr(solvers[0], k).nbytes for k in placed)
+    # one device holds everything: the span's bytes and the counter agree
+    assert {e.args["bytes"] for e in place} == {one}
+    assert counters["disco.place_bytes_max"] == 2 * one
+
+
 def test_newton_step_nests_in_each_outer_and_times_iter_s(ref_mode,
                                                          glm_data):
     from repro import obs

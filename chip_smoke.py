@@ -241,22 +241,25 @@ def phase_dense(seed: int, sz: Sizes, partition: str = "samples",
 
 
 def phase_sparse(seed: int, sz: Sizes) -> dict:
-    """CSR DiSCO-F (128x128 tiles, LPT) == the dense solve of the same
-    matrix densified."""
+    """CSR DiSCO-F (LPT; the HVP layout the input picks, slots or
+    128x128 tiles) == the dense solve of the same matrix densified."""
     X, y, _ = make_sparse_glm_data(sz.sparse_d, sz.sparse_n,
                                    density=sz.sparse_density, seed=seed)
     base = dict(partition="features", lam=SPARSE_LAM,
                 max_outer=sz.sparse_outer, grad_tol=0.0, seed=seed)
     solver = DiscoSolver(X, y, DiscoConfig(ell_block_d=sz.tile,
                                            ell_block_n=sz.tile, **base))
-    tiles = solver.ell_data.nbytes + solver.ell_dataT.nbytes
+    layout = solver.layout.layout
+    layout_bytes = (solver.slots.nbytes if layout == "slots" else
+                    solver.ell_data.nbytes + solver.ell_dataT.nbytes)
     res, _, timing = timed_fit(solver)
     del solver
     gc.collect()
     dense = solve(X.todense(), y, DiscoConfig(**base))
     check(timing["steady_compiles"] == 0, "sparse: compiled in steady window")
     rec = dict(phase="sparse", partition="features", shape=[*X.shape],
-               nnz=X.nnz, tile=[sz.tile, sz.tile], padded_tile_bytes=tiles,
+               nnz=X.nnz, tile=[sz.tile, sz.tile], layout=layout,
+               hvp_layout_bytes=layout_bytes,
                imbalance=res.partition_info["imbalance"],
                outer_iters=len(res.history),
                grad_norm_last=res.history[-1]["grad_norm"],

@@ -28,7 +28,8 @@ from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import comm
-from repro.core.hvp import StreamedHvpOperator, validate_solver_cell
+from repro.core.hvp import (StreamedHvpOperator, make_local_operator,
+                             validate_solver_cell)
 from repro.core.losses import get_loss
 from repro.core.pcg import pcg_features, pcg_samples
 from repro.obs import tracer as obs
@@ -36,6 +37,7 @@ from repro.data.partition import Partition, make_partition
 from repro.kernels import ops as kops
 from repro.launch.mesh import make_mesh
 from repro.data.sparse import (CSRMatrix, EllPair, build_shard_ell_pairs,
+                               build_shard_slot_pairs, choose_hvp_layout,
                                hvp_tile_dtype, shard_csrs_from_partition)
 from repro.robust.checkpoint import (CheckpointState, load_checkpoint,
                                      save_checkpoint)
@@ -308,10 +310,11 @@ class DiscoSolver:
         self.loss = get_loss(cfg.loss)
         if cfg.trace:
             obs.enable()
-        validate_solver_cell(family="binary", partition=cfg.partition,
-                             fused=cfg.hvp_fused, dtype=cfg.hvp_dtype,
-                             sparse=self._sparse,
-                             use_kernel=cfg.use_kernel)
+        if not self._sparse:
+            # a sparse solve validates once it has chosen its layout
+            validate_solver_cell(family="binary", partition=cfg.partition,
+                                 fused=cfg.hvp_fused, dtype=cfg.hvp_dtype,
+                                 use_kernel=cfg.use_kernel)
         self.d, self.n = X.shape
         self.tau = min(cfg.tau, self.n)
 
@@ -367,18 +370,21 @@ class DiscoSolver:
         self.X_hvp = self.X if self.X.dtype == hdt else self.X.astype(hdt)
 
     def _init_sparse(self, X: CSRMatrix, y):
-        """Partition (load-balanced), tile, and shard a sparse matrix.
+        """Partition (load-balanced), lay out, and shard a sparse matrix.
 
-        The chosen axis is permuted by the nnz-aware partitioner, each
-        shard's local matrix is laid out as a forward + transposed
-        blocked-ELL pair (data/sparse.py), and the tau preconditioner
-        samples are materialized as a small dense slab (the ELL layout
-        cannot be column-sliced on device).
+        The chosen axis is permuted by the nnz-aware partitioner, and
+        each shard's local matrix is laid out, as the index structure
+        decides (:func:`repro.data.sparse.choose_hvp_layout`), either as
+        (id, value) slots with a dense head slab (``self.slots``) or as a
+        forward + transposed blocked-ELL pair (``self.ell_*``). The tau
+        preconditioner samples are materialized as a small dense slab
+        (neither layout can be column-sliced on device).
         """
         cfg, axis, m = self.cfg, self.axis, self.m
         br, bc = cfg.ell_block_d, cfg.ell_block_n
         d, n = self.d, self.n
         dtype = X.dtype
+        self._dtype = jax.dtypes.canonicalize_dtype(dtype)
 
         # preconditioner samples: the first tau *original* columns
         X_tau = X.take_cols_dense(np.arange(self.tau))          # (d, tau)
@@ -392,10 +398,8 @@ class DiscoSolver:
                                   block=cfg.partition_block,
                                   pad_multiple=br)
             shard_csrs = shard_csrs_from_partition(X, part, "features")
-            data, cols, dataT, colsT = build_shard_ell_pairs(
-                shard_csrs, br, bc)
             self.d_padded = len(part.perm)
-            self.n_padded = dataT.shape[1] * bc
+            self.n_padded = max(-(-n // bc), 1) * bc
             y_p = np.pad(y, (0, self.n_padded - n))
             smask = np.zeros(self.n_padded, dtype)
             smask[:n] = 1.0
@@ -413,10 +417,8 @@ class DiscoSolver:
                                   block=cfg.partition_block,
                                   pad_multiple=bc)
             shard_csrs = shard_csrs_from_partition(X, part, "samples")
-            data, cols, dataT, colsT = build_shard_ell_pairs(
-                shard_csrs, br, bc)
             self.n_padded = len(part.perm)
-            self.d_padded = data.shape[1] * br          # nrb * br
+            self.d_padded = -(-d // br) * br
             ext = lambda v: np.pad(v, (0, self.n_padded - n))
             y_p = ext(y)[part.perm]
             wts = ext(np.ones(n, dtype))[part.perm]
@@ -430,31 +432,46 @@ class DiscoSolver:
         else:
             raise ValueError(f"unknown partition {cfg.partition!r}")
         self._part = part
-        es, cs = P(axis, None, None, None, None), P(axis, None)
-        self._place(ell_data=(data, es), ell_cols=(cols, cs),
-                    ell_dataT=(dataT, es), ell_colsT=(colsT, cs),
+        self.layout = choose_hvp_layout(shard_csrs, br, bc)
+        self._slots = self.layout.layout == "slots"
+        validate_solver_cell(family="binary", partition=cfg.partition,
+                             fused=cfg.hvp_fused, dtype=cfg.hvp_dtype,
+                             sparse=True, slots=self._slots)
+        # mixed-precision HVP copies (docs/kernels.md): the PCG loop
+        # streams these; margins/gradient keep the f32 values, and ids,
+        # owners and tile columns are shared. Same objects at the default
+        # hvp_dtype, so f32 costs nothing; otherwise each device casts its
+        # own shard.
+        if self._slots:
+            local = ((self.d_padded // m, self.n_padded)
+                     if cfg.partition == "features"
+                     else (self.d_padded, self.n_padded // m))
+            pair = build_shard_slot_pairs(shard_csrs, local)
+            self._place(slots=(pair, P(axis)), y_tau=(y_tau, P()), **placed)
+            self.slots_h = self.slots.with_values(hdt)
+            obs.count("disco.hvp_slot_bytes", pair.nbytes)
+            return
+        data, cols, dataT, colsT = build_shard_ell_pairs(shard_csrs, br, bc)
+        self._place(ell_data=(data, P(axis)), ell_cols=(cols, P(axis)),
+                    ell_dataT=(dataT, P(axis)), ell_colsT=(colsT, P(axis)),
                     y_tau=(y_tau, P()), **placed)
-
-        # mixed-precision HVP tile copies (docs/kernels.md): the PCG loop
-        # streams these; margins/gradient keep the f32 layouts and the
-        # cols arrays are shared (int32 either way). Same objects at the
-        # default hvp_dtype, so f32 costs nothing; otherwise each device
-        # casts its own shard.
         if self.ell_data.dtype == hdt:
             self.ell_data_h = self.ell_data
             self.ell_dataT_h = self.ell_dataT
         else:
             self.ell_data_h = self.ell_data.astype(hdt)
             self.ell_dataT_h = self.ell_dataT.astype(hdt)
+        obs.count("disco.hvp_slot_bytes", 0)
 
     def _place(self, **arrays):
-        """Put host arrays, ``name=(array, spec)``, on the mesh as
-        ``self.<name>``: each device receives only the shard that
-        ``spec`` gives it, sliced on the host, so no device ever holds
-        a whole sharded array on its way to the others. Waits for the
-        copies, inside the ``disco.place`` span."""
+        """Put host arrays or pytrees of them, ``name=(tree, spec)``, on
+        the mesh as ``self.<name>``: each device receives only the shard
+        that ``spec`` gives each array, sliced on the host, so no device
+        ever holds a whole sharded array on its way to the others. Waits
+        for the copies, inside the ``disco.place`` span."""
         total = sum(a.size * jax.dtypes.canonicalize_dtype(a.dtype).itemsize
-                    for a, _ in arrays.values())
+                    for t, _ in arrays.values()
+                    for a in jax.tree_util.tree_leaves(t))
         with obs.span("disco.place", bytes=total, shards=self.m):
             placed = {name: jax.device_put(a, NamedSharding(self.mesh, spec))
                       for name, (a, spec) in arrays.items()}
@@ -463,7 +480,7 @@ class DiscoSolver:
             setattr(self, name, a)
         if obs.enabled():
             per_device = collections.Counter()
-            for a in placed.values():
+            for a in jax.tree_util.tree_leaves(list(placed.values())):
                 for s in a.addressable_shards:
                     per_device[s.device] += s.data.nbytes
             obs.count("disco.place_bytes_max", max(per_device.values()))
@@ -568,25 +585,31 @@ class DiscoSolver:
     # ------------------------------------------------------------------
     def _build_step_sparse(self):
         """Sparse twin of ``_build_step``: identical algorithm, with every
-        X product routed through the blocked-ELL kernel pair. The ELL
-        arrays enter ``shard_map`` sharded on their leading (shard) axis
-        and are re-wrapped as an :class:`EllPair` per shard."""
+        X product routed through the local operator of the layout the
+        solver chose (slots or blocked-ELL tiles, ``core/hvp.py``). The
+        layout's arrays enter ``shard_map`` as one pytree sharded on
+        their leading (shard) axis, and each shard drops that axis."""
         cfg, loss, axis = self.cfg, self.loss, self.axis
         n, tau = self.n, self.tau
         frac = cfg.hessian_subsample
+        if self._slots:
+            X, X_h = self.slots, self.slots_h
+        else:
+            # HVP twin: (possibly bf16) tile copies, shared cols
+            X = EllPair(self.ell_data, self.ell_cols, self.ell_dataT,
+                        self.ell_colsT)
+            X_h = EllPair(self.ell_data_h, self.ell_cols, self.ell_dataT_h,
+                          self.ell_colsT)
+        local = lambda t: jax.tree_util.tree_map(lambda a: a[0], t)
 
         if cfg.partition == "features":
-            def step_local(ed, ec, edT, ecT, edh, edTh, X_tau_loc, y,
-                           y_tau, smask, w_loc, key):
-                ell = EllPair(ed[0], ec[0], edT[0], ecT[0])
-                # HVP twin: (possibly bf16) tile copies, shared cols
-                ell_h = EllPair(edh[0], ec[0], edTh[0], ecT[0])
-                margins = lax.psum(
-                    kops.ell_matvec(ell.dataT, ell.colsT, w_loc), axis)
+            def step_local(Xs, Xhs, X_tau_loc, y, y_tau, smask, w_loc, key):
+                op = make_local_operator(local(Xs), None,
+                                         partition="features")
+                margins = lax.psum(op.pass_a(w_loc), axis)
                 d1 = loss.d1(margins, y) * smask
                 c = loss.d2(margins, y) * smask
-                g_loc = kops.ell_matvec(ell.data, ell.cols, d1) / n \
-                    + cfg.lam * w_loc
+                g_loc = op.pass_b(d1) / n + cfg.lam * w_loc
                 gnorm = jnp.sqrt(lax.psum(jnp.vdot(g_loc, g_loc), axis))
                 fval = jnp.sum(loss.value(margins, y) * smask) / n \
                     + 0.5 * cfg.lam * lax.psum(jnp.vdot(w_loc, w_loc), axis)
@@ -600,7 +623,7 @@ class DiscoSolver:
 
                 eps = cfg.pcg_rel_tol * gnorm
                 res = pcg_features(
-                    ell_h, c_eff, n, cfg.lam, g_loc, eps, cfg.max_pcg,
+                    local(Xhs), c_eff, n, cfg.lam, g_loc, eps, cfg.max_pcg,
                     coeffs_tau=coeffs_tau, mu=cfg.mu, axis_name=axis,
                     precond=cfg.precond, block_s=cfg.pcg_block_s,
                     X_tau_loc=X_tau_loc, axis_size=self.m,
@@ -612,30 +635,23 @@ class DiscoSolver:
 
             fn = jax.jit(shard_map(
                 step_local, mesh=self.mesh,
-                in_specs=(P(axis, None, None, None, None), P(axis, None),
-                          P(axis, None, None, None, None), P(axis, None),
-                          P(axis, None, None, None, None),
-                          P(axis, None, None, None, None),
-                          P(axis, None), P(), P(), P(), P(axis), P()),
+                in_specs=(P(axis), P(axis), P(axis, None), P(), P(), P(),
+                          P(axis), P()),
                 out_specs=(P(axis), P()),
                 check_vma=False))  # pallas_call outputs carry no vma info
 
             def step(w, key):
-                return fn(self.ell_data, self.ell_cols, self.ell_dataT,
-                          self.ell_colsT, self.ell_data_h,
-                          self.ell_dataT_h, self.X_tau, self.y,
-                          self.y_tau, self.smask, w, key)
+                return fn(X, X_h, self.X_tau, self.y, self.y_tau,
+                          self.smask, w, key)
 
         else:  # samples
-            def step_local(ed, ec, edT, ecT, edh, edTh, y_loc, wts_loc,
-                           X_tau, y_tau, w, key):
-                ell = EllPair(ed[0], ec[0], edT[0], ecT[0])
-                ell_h = EllPair(edh[0], ec[0], edTh[0], ecT[0])
-                margins = kops.ell_matvec(ell.dataT, ell.colsT, w)
+            def step_local(Xs, Xhs, y_loc, wts_loc, X_tau, y_tau, w, key):
+                op = make_local_operator(local(Xs), None,
+                                         partition="samples")
+                margins = op.pass_a(w)
                 d1 = loss.d1(margins, y_loc) * wts_loc
                 c = loss.d2(margins, y_loc) * wts_loc
-                g = lax.psum(kops.ell_matvec(ell.data, ell.cols, d1),
-                             axis) / n + cfg.lam * w
+                g = lax.psum(op.pass_b(d1), axis) / n + cfg.lam * w
                 gnorm = jnp.sqrt(jnp.vdot(g, g))
                 fval = lax.psum(jnp.sum(loss.value(margins, y_loc)
                                         * wts_loc), axis) / n \
@@ -651,7 +667,7 @@ class DiscoSolver:
 
                 eps = cfg.pcg_rel_tol * gnorm
                 res = pcg_samples(
-                    ell_h, c_eff, n, cfg.lam, g, eps, cfg.max_pcg,
+                    local(Xhs), c_eff, n, cfg.lam, g, eps, cfg.max_pcg,
                     X_tau=X_tau, coeffs_tau=coeffs_tau, mu=cfg.mu,
                     axis_name=axis, precond=cfg.precond,
                     sag_epochs=cfg.sag_epochs,
@@ -664,19 +680,14 @@ class DiscoSolver:
 
             fn = jax.jit(shard_map(
                 step_local, mesh=self.mesh,
-                in_specs=(P(axis, None, None, None, None), P(axis, None),
-                          P(axis, None, None, None, None), P(axis, None),
-                          P(axis, None, None, None, None),
-                          P(axis, None, None, None, None),
-                          P(axis), P(axis), P(), P(), P(), P()),
+                in_specs=(P(axis), P(axis), P(axis), P(axis), P(), P(), P(),
+                          P()),
                 out_specs=(P(), P()),
                 check_vma=False))  # pallas_call outputs carry no vma info
 
             def step(w, key):
-                return fn(self.ell_data, self.ell_cols, self.ell_dataT,
-                          self.ell_colsT, self.ell_data_h,
-                          self.ell_dataT_h, self.y, self.weights,
-                          self.X_tau, self.y_tau, w, key)
+                return fn(X, X_h, self.y, self.weights, self.X_tau,
+                          self.y_tau, w, key)
 
         # the device data enter the jitted program as arguments: an array
         # closed over by a jitted function is embedded as a constant
@@ -1266,7 +1277,7 @@ class DiscoSolver:
         if self._streaming:
             dtype = self._plan.store.dtype
         else:
-            dtype = self.ell_data.dtype if self._sparse else self.X.dtype
+            dtype = self._dtype if self._sparse else self.X.dtype
 
         history: list[dict[str, Any]] = []
         ledger = comm.CommLedger()

@@ -17,6 +17,8 @@ operator                  backing
                           optionally one-pass fused
 :class:`EllOperator`      blocked-ELL sparse kernels
                           (``kernels/sparse_hvp.py``), optionally fused
+:class:`SlotOperator`     (id, value) slots plus a dense head slab
+                          (``kernels/ops.py``, plain jnp; two-pass)
 :class:`StreamedHvpOperator`  out-of-core chunk scans supplied by the
                           streaming solver (``data/stream.py``)
 :class:`SoftmaxHvpOperator`   K-class softmax Hessian application composed
@@ -41,11 +43,11 @@ from typing import Callable, NamedTuple
 
 import jax.numpy as jnp
 
-from repro.data.sparse import EllPair
+from repro.data.sparse import EllPair, SlotPair
 from repro.obs import tracer as obs
 
 FAMILIES = ("binary", "softmax")
-LAYOUTS = ("dense", "dense_kernel", "ell", "streamed")
+LAYOUTS = ("dense", "dense_kernel", "ell", "slots", "streamed")
 PARTITIONS = ("samples", "features")
 DTYPES = ("float32", "bfloat16")
 
@@ -67,7 +69,7 @@ class OperatorCell(NamedTuple):
     """
 
     family: str      # 'binary' (margin GLM losses) | 'softmax' (K-class)
-    layout: str      # 'dense' | 'dense_kernel' | 'ell' | 'streamed'
+    layout: str      # 'dense' | 'dense_kernel' | 'ell' | 'slots' | 'streamed'
     partition: str   # 'samples' (DiSCO-S) | 'features' (DiSCO-F)
     fused: bool      # one-pass fused kernels requested
     dtype: str       # HVP tile storage dtype: 'float32' | 'bfloat16'
@@ -106,7 +108,10 @@ def _cell_verdict(family: str, layout: str, partition: str, fused: bool,
                        "cover the full HVP (this flag used to be silently "
                        "ignored here)"), ""
     note = ""
-    if fused and layout == "streamed":
+    if fused and layout == "slots":
+        note = ("runs the two passes: no one-pass form exists across the "
+                "sample-major and feature-major slot layouts")
+    elif fused and layout == "streamed":
         note = ("VMEM-gated: oversized chunk panels fall back to the "
                 "two-pass chunk stream")
     elif fused and partition == "features":
@@ -152,15 +157,17 @@ def resolve_cell(family: str, layout: str, partition: str, fused: bool,
 def validate_solver_cell(*, family: str, partition: str, fused: bool,
                          dtype: str, sparse: bool = False,
                          use_kernel: bool = False,
-                         streaming: bool = False) -> OperatorCell:
+                         streaming: bool = False,
+                         slots: bool = False) -> OperatorCell:
     """Solver-setup validation: map solver flags to the registry layout
     and resolve the cell (raising early, with the cell named, instead of
     letting an ignored flag surface as silent wrong dispatch deep in the
-    PCG loop)."""
+    PCG loop). ``slots`` marks a sparse solve that took the slot layout
+    (:func:`repro.data.sparse.choose_hvp_layout`)."""
     if streaming:
         layout = "streamed"
     elif sparse:
-        layout = "ell"
+        layout = "slots" if slots else "ell"
     elif use_kernel:
         layout = "dense_kernel"
     else:
@@ -362,6 +369,39 @@ class EllOperator(HvpOperator):
         return self.pass_b_multi(self.pass_a_multi(U))
 
 
+class SlotOperator(HvpOperator):
+    """(id, value) slot layout (:class:`repro.data.sparse.SlotPair`): the
+    densest rows as a dense head slab, every other nonzero a slot in the
+    sample-major (pass A) and feature-major (pass B) layouts. Shares no
+    logic with the tile kernels. ``fused=True`` runs the two passes:
+    there is no one-pass form across two layouts."""
+
+    layout = "slots"
+
+    def __init__(self, pair: SlotPair, coeffs, fused=False):
+        from repro.kernels import ops as kops
+        self._kops = kops
+        self.pair = pair
+        self.coeffs = coeffs
+        self.fused = bool(fused)
+
+    def pass_a(self, u):
+        """``X^T u`` from the head slab and the sample-major slots."""
+        return self._kops.slot_xt(self.pair, u)
+
+    def pass_b(self, z):
+        """``X (c .* z)`` from the feature-major slots and the head slab."""
+        return self._kops.slot_x(self.pair, z, self.coeffs)
+
+    def pass_a_multi(self, U):
+        """Batched ``X^T U``, one column at a time."""
+        return self._kops.slot_xt(self.pair, U)
+
+    def pass_b_multi(self, Z):
+        """Batched ``X (c[:, None] .* Z)``, one column at a time."""
+        return self._kops.slot_x(self.pair, Z, self.coeffs)
+
+
 class StreamedHvpOperator(HvpOperator):
     """Out-of-core layout: the streaming solver supplies chunk-scan
     callables (each is one prefetched pass over the
@@ -494,7 +534,8 @@ def make_local_operator(X_loc, coeffs, *, use_kernel: bool = False,
     point the PCG loops use.
 
     Layout is inferred from the data: an :class:`repro.data.sparse.EllPair`
-    selects :class:`EllOperator`; dense arrays select
+    selects :class:`EllOperator`, a :class:`repro.data.sparse.SlotPair`
+    :class:`SlotOperator`; dense arrays select
     :class:`DenseKernelOperator` when ``use_kernel`` else
     :class:`DenseOperator`. Raises :class:`UnsupportedHvpError` (cell
     named) for combinations no operator implements — e.g. ``fused`` on
@@ -503,6 +544,9 @@ def make_local_operator(X_loc, coeffs, *, use_kernel: bool = False,
     if isinstance(X_loc, EllPair):
         resolve_cell("binary", "ell", partition, fused)
         return EllOperator(X_loc, coeffs, fused=fused)
+    if isinstance(X_loc, SlotPair):
+        resolve_cell("binary", "slots", partition, fused)
+        return SlotOperator(X_loc, coeffs, fused=fused)
     if use_kernel:
         resolve_cell("binary", "dense_kernel", partition, fused)
         return DenseKernelOperator(X_loc, coeffs, fused=fused)

@@ -33,6 +33,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Iterator, NamedTuple
 
+import jax
 import numpy as np
 
 
@@ -436,6 +437,225 @@ def build_shard_ell_pairs(shard_csrs: list[CSRMatrix], block_rows: int,
         data = data.astype(dtype)
         dataT = dataT.astype(dtype)
     return data, cols, dataT, colsT
+
+
+# ---------------------------------------------------------------------------
+# (id, value) slots: the nonzeros themselves, grouped by output index
+# ---------------------------------------------------------------------------
+
+# Slots per chunk. A chunk belongs to one output index; a heavy output
+# spans many chunks instead of widening every row.
+SLOT_WIDTH = 8
+# Chunks per reduction block: a block's partial sums land in a window of
+# 2 x 128 outputs (kernels/ops.py), so every output owns at least one
+# chunk and a layout holds a whole number of blocks.
+SLOT_BLOCK = 128
+
+# Device seconds per byte of each layout, measured on a TPU v5e at the
+# shape of the benchmark's real-sim cell (d = 20,958, n = 36,155, 1.85M
+# nonzeros): the Pallas tile kernel streams padded 128x128 tiles at about
+# 200 GB/s; a dense slab row goes through ``jnp.dot`` at about 740 GB/s;
+# a slot (8 B of id and value) costs about 1.5 ns, gathered by a one-hot
+# matmul and reduced in 256-output windows (1.3 to 2.0 ns as the gathered
+# vector grows from 164 to 283 rows of 128). So a slot byte costs about
+# 37 tile bytes and 140 slab bytes.
+TILE_S_PER_BYTE = 1 / 200e9
+SLAB_S_PER_BYTE = 1 / 740e9
+SLOT_S_PER_BYTE = 1.5e-9 / 8
+
+
+class SlotLayout(NamedTuple):
+    """One product direction as fixed-width chunks of (id, value) slots:
+    ``y[owner[k]] += sum_l vals[k, l] * v[ids[k, l]]``.
+
+    Chunks are grouped by output index, so ``owner`` is nondecreasing,
+    and every output owns at least one chunk. Padding slots carry value
+    0 and the valid id 0; padding chunks belong to the last output.
+    """
+
+    ids: np.ndarray     # (chunks, SLOT_WIDTH) int32 input indices
+    vals: np.ndarray    # (chunks, SLOT_WIDTH) values
+    owner: np.ndarray   # (chunks,) int32 output index
+
+
+@dataclasses.dataclass(frozen=True)
+class SlotPair:
+    """Device-side slot operand of one shard, the twin of
+    :class:`EllPair` (a jax pytree; ``shape`` is static).
+
+    The ``head`` rows, the densest of ``X_loc``, are a dense
+    ``(H, cols)`` slab at row indices ``head_rows``; every other nonzero
+    is a slot, in ``fwd`` (feature-major: rows of ``X_loc``, drives
+    ``X @ v``) and in ``tr`` (sample-major: rows of ``X_loc^T``, drives
+    ``X^T u``). ``shape`` is the padded ``(rows, cols)`` of the local
+    operand, the vector lengths of the two directions. Stacked shards
+    carry a leading shard axis on every array.
+    """
+
+    fwd: SlotLayout
+    tr: SlotLayout
+    head: np.ndarray        # (H, cols) dense rows
+    head_rows: np.ndarray   # (H,) int32
+    shape: tuple[int, int]
+
+    @property
+    def dtype(self):
+        return self.fwd.vals.dtype
+
+    @property
+    def nbytes(self) -> int:
+        """Bytes of every array of the pair."""
+        return int(sum(a.nbytes for a in jax.tree_util.tree_leaves(self)))
+
+    def with_values(self, dtype) -> "SlotPair":
+        """The same pair with its values (slot values and the head slab)
+        in ``dtype``; ids, owners and head rows shared."""
+        if self.dtype == dtype:
+            return self
+        return dataclasses.replace(
+            self, fwd=self.fwd._replace(vals=self.fwd.vals.astype(dtype)),
+            tr=self.tr._replace(vals=self.tr.vals.astype(dtype)),
+            head=self.head.astype(dtype))
+
+
+jax.tree_util.register_dataclass(
+    SlotPair, data_fields=["fwd", "tr", "head", "head_rows"],
+    meta_fields=["shape"])
+
+
+def slot_chunks(nnz_per_output: np.ndarray) -> int:
+    """Chunks a slot layout takes for these per-output nonzero counts:
+    at least one per output, rounded up to whole blocks."""
+    per = np.maximum(-(-np.asarray(nnz_per_output) // SLOT_WIDTH), 1)
+    return -(-max(int(per.sum()), 1) // SLOT_BLOCK) * SLOT_BLOCK
+
+
+def slot_layout_from_csr(csr: CSRMatrix, n_out: int,
+                         n_chunks: int | None = None) -> SlotLayout:
+    """Slots of ``csr`` with its rows as the output indices (padded with
+    empty outputs to ``n_out``) and its column indices as the slot ids,
+    padded to ``n_chunks`` chunks."""
+    counts = np.zeros(n_out, np.int64)
+    counts[: csr.shape[0]] = csr.nnz_per_row()
+    per_row = np.maximum(-(-counts // SLOT_WIDTH), 1)
+    natural = slot_chunks(counts)
+    C = natural if n_chunks is None else n_chunks
+    if C < natural:
+        raise ValueError(f"n_chunks {C} < the {natural} chunks needed")
+    first = np.zeros(n_out + 1, np.int64)
+    np.cumsum(per_row, out=first[1:])
+    owner = np.full(C, n_out - 1, np.int32)
+    owner[: first[-1]] = np.repeat(np.arange(n_out), per_row)
+    rows = np.repeat(np.arange(csr.shape[0]), csr.nnz_per_row())
+    rank = np.arange(csr.nnz) - csr.indptr[rows]
+    chunk = first[rows] + rank // SLOT_WIDTH
+    ids = np.zeros((C, SLOT_WIDTH), np.int32)
+    vals = np.zeros((C, SLOT_WIDTH), csr.data.dtype)
+    ids[chunk, rank % SLOT_WIDTH] = csr.indices
+    vals[chunk, rank % SLOT_WIDTH] = csr.data
+    return SlotLayout(ids=ids, vals=vals, owner=owner)
+
+
+def slot_head_rows(csr: CSRMatrix) -> np.ndarray:
+    """Rows of ``csr`` that cost less as a dense slab row than as slots:
+    those whose nonzeros' slot bytes, at ``SLOT_S_PER_BYTE``, take longer
+    than a dense row of ``cols`` values at ``SLAB_S_PER_BYTE``. Densest
+    first."""
+    counts = csr.nnz_per_row()
+    row_s = csr.shape[1] * csr.data.dtype.itemsize * SLAB_S_PER_BYTE
+    heavy = np.nonzero(counts * 8 * SLOT_S_PER_BYTE > row_s)[0]
+    return heavy[np.argsort(-counts[heavy], kind="stable")]
+
+
+def _split_head(csr: CSRMatrix, head: np.ndarray
+                ) -> tuple[np.ndarray, CSRMatrix]:
+    """The dense ``(len(head), cols)`` slab of rows ``head`` and the CSR
+    of every other row (head rows emptied)."""
+    slab = np.zeros((len(head), csr.shape[1]), csr.data.dtype)
+    counts = csr.nnz_per_row()
+    rows = np.repeat(np.arange(csr.shape[0]), counts)
+    in_head = np.zeros(csr.shape[0], bool)
+    in_head[head] = True
+    pos = np.zeros(csr.shape[0], np.int64)
+    pos[head] = np.arange(len(head))
+    hit = in_head[rows]
+    slab[pos[rows[hit]], csr.indices[hit]] = csr.data[hit]
+    keep = ~hit
+    tail = CSRMatrix.from_coo(rows[keep], csr.indices[keep],
+                              csr.data[keep], csr.shape,
+                              dtype=csr.data.dtype)
+    return slab, tail
+
+
+def build_shard_slot_pairs(shard_csrs: list[CSRMatrix],
+                           shape: tuple[int, int]) -> SlotPair:
+    """Per-shard slot pairs stacked with a leading shard axis ``m``:
+    each shard's head slab padded to the largest shard's head (zero rows
+    at row 0), and its layouts to the largest shard's chunk counts (as
+    :func:`build_shard_ell_pairs` stacks tile widths). ``shape`` is the
+    padded local ``(rows, cols)``."""
+    rows, cols = shape
+    heads = [slot_head_rows(c) for c in shard_csrs]
+    splits = [_split_head(c, h) for c, h in zip(shard_csrs, heads)]
+    H = max(len(h) for h in heads)
+    tails = [t for _, t in splits]
+    tails_t = [t.transpose() for t in tails]
+    C = max(slot_chunks(np.pad(t.nnz_per_row(), (0, rows - t.shape[0])))
+            for t in tails)
+    CT = max(slot_chunks(np.pad(t.nnz_per_row(), (0, cols - t.shape[0])))
+             for t in tails_t)
+    fwd = [slot_layout_from_csr(t, rows, C) for t in tails]
+    tr = [slot_layout_from_csr(t, cols, CT) for t in tails_t]
+    stack = lambda parts: SlotLayout(*(np.stack(f) for f in zip(*parts)))
+    head = np.stack([np.pad(s, ((0, H - len(s)), (0, cols - s.shape[1])))
+                     for s, _ in splits])
+    head_rows = np.stack([np.pad(h, (0, H - len(h))).astype(np.int32)
+                          for h in heads])
+    return SlotPair(stack(fwd), stack(tr), head, head_rows, (rows, cols))
+
+
+class LayoutChoice(NamedTuple):
+    """Which layout a sparse in-memory solve takes, with the bytes and
+    the estimated device seconds of one pass over each (every shard)."""
+
+    layout: str         # 'slots' | 'ell'
+    tile_bytes: int     # padded blocked-ELL tiles, both layouts
+    slot_bytes: int     # slots, chunk owners and head slab, both layouts
+    tile_s: float
+    slot_s: float
+
+
+def choose_hvp_layout(shard_csrs: list[CSRMatrix], block_rows: int,
+                      block_cols: int) -> LayoutChoice:
+    """Slots or tiles for these shards, from the index structure alone
+    (no layout is built). Each is costed at its measured seconds per
+    byte (``*_S_PER_BYTE``): tiles win only where they are dense enough,
+    as on block-structured data at high tile fill."""
+    m = len(shard_csrs)
+    rows, cols = shard_csrs[0].shape
+    itemsize = shard_csrs[0].data.dtype.itemsize
+    widths = [ell_tile_widths(c, block_rows, block_cols)
+              for c in shard_csrs]
+    tiles = (-(-rows // block_rows) * max(w for w, _ in widths)
+             + max(-(-cols // block_cols), 1) * max(w for _, w in widths))
+    tile_bytes = m * tiles * block_rows * block_cols * itemsize
+    slot_bytes = slab_bytes = 0
+    for c in shard_csrs:
+        head = slot_head_rows(c)
+        keep = np.ones(rows, bool)
+        keep[head] = False
+        per_row = np.where(keep, c.nnz_per_row(), 0)
+        per_col = np.bincount(
+            c.indices[np.repeat(keep, c.nnz_per_row())], minlength=cols)
+        chunks = slot_chunks(per_row) + slot_chunks(per_col)
+        slot_bytes += chunks * (SLOT_WIDTH * (4 + itemsize) + 4)
+        slab_bytes += len(head) * (cols * itemsize + 4)
+    tile_s = tile_bytes * TILE_S_PER_BYTE
+    slot_s = (slot_bytes * SLOT_S_PER_BYTE
+              + 2 * slab_bytes * SLAB_S_PER_BYTE)
+    layout = "slots" if slot_s < tile_s else "ell"
+    return LayoutChoice(layout, int(tile_bytes), int(slot_bytes + slab_bytes),
+                        tile_s, slot_s)
 
 
 # ---------------------------------------------------------------------------

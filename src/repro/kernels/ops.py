@@ -22,6 +22,7 @@ from repro.kernels import glm_hvp as _hvp
 from repro.kernels import flash_attention as _fa
 from repro.kernels import ref as _ref
 from repro.kernels import sparse_hvp as _sparse
+from repro.data.sparse import SLOT_BLOCK
 from repro.obs import tracer as obs
 from repro.utils.padding import pad_to_multiple as _pad_axis
 
@@ -489,6 +490,99 @@ def ell_hvp_mm(dataT, colsT, U, c=None, *, fwd=None, mode=None,
     fused = _fused_or_fwd(dataT, fwd, U.shape[0], U.shape[1], mode)
     return _ell_hvp_mm_impl(dataT, colsT, U, c, fwd, mode=mode,
                             fused=fused, out_dtype=out_dtype)
+
+
+# ---------------------------------------------------------------------------
+# (id, value) slot products (see data/sparse.py for the layout)
+# ---------------------------------------------------------------------------
+
+# Vectors of at most this many 128-lane rows are gathered by a one-hot
+# matmul, exact at HIGHEST precision: on a TPU v5e that takes about a
+# quarter of the time of XLA's scalar gather (7 ns a slot) at the 164- and
+# 283-row vectors of the real-sim benchmark cell, and its cost grows with
+# the rows; longer vectors take the scalar gather.
+_ONE_HOT_ROWS = 512
+
+
+def _slot_gather(v, ids):
+    """``v[ids]`` for a 1-D f32 ``v``."""
+    rows = -(-v.shape[0] // LANE)
+    if rows > _ONE_HOT_ROWS:
+        return v[ids]
+    table = jnp.pad(v, (0, rows * LANE - v.shape[0])).reshape(rows, LANE)
+    hit = ((ids // LANE)[..., None] == jnp.arange(rows)).astype(jnp.float32)
+    picked = jnp.einsum("clr,rk->clk", hit, table,
+                        precision=jax.lax.Precision.HIGHEST)
+    lane = (ids % LANE)[..., None] == jnp.arange(LANE)
+    return jnp.sum(jnp.where(lane, picked, 0.0), axis=-1)
+
+
+def _window_sum(part, owner, n_out):
+    """Sorted segment sum of the chunk partials ``part`` into ``n_out``
+    outputs. Each block of ``SLOT_BLOCK`` chunks spans at most 128
+    consecutive owners (every output owns a chunk), so it reduces into a
+    window of 2 x 128 outputs at a 128-aligned base, and the windows are
+    added as whole 128-lane rows: no scalar scatter."""
+    nb = part.shape[0] // SLOT_BLOCK
+    blocks = owner.reshape(nb, SLOT_BLOCK)
+    base = blocks[:, 0] // LANE
+    rel = blocks - base[:, None] * LANE
+    hit = rel[..., None] == jnp.arange(2 * LANE)
+    win = jnp.sum(jnp.where(hit, part.reshape(nb, SLOT_BLOCK, 1), 0.0),
+                  axis=1)
+    n_rows = -(-n_out // LANE) + 1
+    at = jnp.stack([base, base + 1], axis=1).reshape(-1)
+    y = jnp.zeros((n_rows, LANE), jnp.float32).at[at].add(
+        win.reshape(2 * nb, LANE), indices_are_sorted=True)
+    return y.reshape(-1)[:n_out]
+
+
+def _slot_mv(layout, v, n_out):
+    ids, vals, owner = layout
+    part = jnp.sum(vals.astype(jnp.float32) * _slot_gather(v, ids), axis=1)
+    return _window_sum(part, owner, n_out)
+
+
+def slot_matvec(layout, v, n_out):
+    """``y = A @ v`` for one direction of a slot layout
+    (:class:`repro.data.sparse.SlotLayout`): gather ``v`` at the slot ids,
+    multiply by the slot values, reduce each chunk along its slots, then
+    sum the chunk partials per owner. ``v`` is ``(len,)`` or ``(len, s)``
+    (s probe vectors, one column at a time); f32 in and out. Plain jnp:
+    no tile, no scatter over the nonzeros, the same on every backend."""
+    v = v.astype(jnp.float32)
+    if v.ndim == 2:
+        return jax.vmap(lambda col: _slot_mv(layout, col, n_out),
+                        in_axes=1, out_axes=1)(v)
+    return _slot_mv(layout, v, n_out)
+
+
+@jax.jit
+def slot_xt(pair, u):
+    """Pass A over a :class:`repro.data.sparse.SlotPair`: ``X^T u``, the
+    head slab's rows by a dense product plus the sample-major slots.
+    ``u`` is ``(rows,)`` or ``(rows, s)``; returns ``(cols[, s])`` f32."""
+    u = u.astype(jnp.float32)
+    head = jnp.tensordot(u[pair.head_rows], pair.head.astype(jnp.float32),
+                         axes=(0, 0),
+                         precision=jax.lax.Precision.HIGHEST)
+    head = head if u.ndim == 1 else head.T
+    return slot_matvec(pair.tr, u, pair.shape[1]) + head
+
+
+@jax.jit
+def slot_x(pair, z, c=None):
+    """Pass B over a :class:`repro.data.sparse.SlotPair`: ``X (c .* z)``,
+    the feature-major slots plus the head slab's rows by a dense
+    product. ``z`` is ``(cols,)`` or ``(cols, s)``; returns
+    ``(rows[, s])`` f32."""
+    z = z.astype(jnp.float32)
+    if c is not None:
+        z = z * (c if z.ndim == 1 else c[:, None])
+    head = jnp.dot(pair.head.astype(jnp.float32), z,
+                   precision=jax.lax.Precision.HIGHEST)
+    y = slot_matvec(pair.fwd, z, pair.shape[0])
+    return y.at[pair.head_rows].add(head)
 
 
 # ---------------------------------------------------------------------------

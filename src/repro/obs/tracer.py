@@ -160,6 +160,10 @@ COUNTER_KINDS: dict[str, str] = {
         "bytes of the solver's placed data held by its most-loaded "
         "device, summed over the shards of every array `disco.place` "
         "put (a replicated array counts on each device)"),
+    "disco.hvp_slot_bytes": (
+        "bytes of the (id, value) slot layouts and head slab a sparse "
+        "in-memory solver placed for its HVP, all shards; 0 when it "
+        "chose blocked-ELL tiles"),
     "io.retries": "transient I/O failures retried by the retry policy",
     "serve.scored": "requests scored by the micro-batch scheduler",
     "serve.pack_bytes": (

@@ -193,6 +193,57 @@ def ell_pair_case(rng, d, n, density, br, bc, width_pad=0, dtype=None):
     return pair, Xp
 
 
+def slot_csr_case(rng, d=64, n=4000, full_row=True, heavy=True):
+    """A CSR whose slot layout exercises every shape of the format: a
+    feature present in every sample (``full_row``), heavy features that
+    take the dense head slab (``heavy``), features longer than one chunk
+    of slots and shorter ones, three hub samples in every light feature
+    (sample-major chunks longer than one), empty features and empty
+    samples."""
+    from repro.data.sparse import CSRMatrix
+
+    rows, cols = [], []
+    for i in range(d):
+        if i == 0 and full_row:
+            k = n
+        elif i < 6 and heavy:
+            k = int(rng.integers(60, 200))
+        elif i % 7 == 3:
+            k = 0                                   # empty feature
+        else:
+            k = int(rng.integers(1, 14))            # 1 or 2 chunks
+        if k == n:
+            picked = np.arange(n)
+        else:
+            pool = n // 2                           # upper half empty
+            picked = rng.choice(pool, size=k, replace=False)
+            if i >= 6 and k:
+                picked = np.union1d(picked, [0, 1, 2])[:max(k, 3)]
+        rows.append(np.full(len(picked), i))
+        cols.append(picked)
+    rows, cols = np.concatenate(rows), np.concatenate(cols)
+    vals = rng.standard_normal(len(rows)).astype(np.float32)
+    return CSRMatrix.from_coo(rows, cols, vals, (d, n))
+
+
+def slot_pair_case(csr, shape, dtype=None):
+    """One shard's :class:`repro.data.sparse.SlotPair` of ``csr`` at the
+    padded local ``shape`` (values optionally in ``dtype``) on the
+    device, plus the padded dense X those values stand for, in f32."""
+    import jax
+    from repro.data.sparse import build_shard_slot_pairs
+
+    pair = build_shard_slot_pairs([csr], shape)
+    pair = jax.tree_util.tree_map(lambda a: jnp.asarray(a[0]), pair)
+    if dtype is not None:
+        pair = pair.with_values(dtype)
+    Xp = np.zeros(shape, np.float32)
+    Xp[: csr.shape[0], : csr.shape[1]] = csr.todense()
+    if dtype is not None:
+        Xp = np.asarray(jnp.asarray(Xp, dtype).astype(jnp.float32))
+    return pair, Xp
+
+
 # ---------------------------------------------------------------------------
 # frozen pre-refactor dispatch (the bit-identity target)
 # ---------------------------------------------------------------------------

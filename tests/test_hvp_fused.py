@@ -317,6 +317,7 @@ def test_solver_bf16_tiles_actually_engaged(ref_mode):
                       tau=16, ell_block_d=8, ell_block_n=8,
                       hvp_dtype="bfloat16")
     s = DiscoSolver(X, y, cfg)
+    assert s.layout.layout == "ell"               # 20% dense: tiles win
     assert str(s.ell_data_h.dtype) == "bfloat16"
     assert str(s.ell_dataT_h.dtype) == "bfloat16"
     assert str(s.ell_data.dtype) == "float32"     # first-order plane f32
@@ -324,6 +325,29 @@ def test_solver_bf16_tiles_actually_engaged(ref_mode):
     s32 = DiscoSolver(X, y, DiscoConfig(partition="samples",
                                         ell_block_d=8, ell_block_n=8))
     assert s32.ell_data_h is s32.ell_data
+
+
+@pytest.mark.parametrize("partition", ["features", "samples"])
+def test_solver_bf16_slots_actually_engaged(partition):
+    """The slot twin: on data where slots win, the HVP copy's slot values
+    and head slab are bf16, the first-order plane stays f32, ids and
+    owners are shared, and at f32 the copy is the same object."""
+    from repro.core import DiscoConfig, DiscoSolver
+    from repro.data.sparse import make_sparse_glm_data
+
+    X, y, _ = make_sparse_glm_data(1500, 2500, density=0.004, alpha=1.0,
+                                   seed=3)
+    s = DiscoSolver(X, y, DiscoConfig(partition=partition, tau=16,
+                                      hvp_dtype="bfloat16"))
+    assert s.layout.layout == "slots"
+    h, f = s.slots_h, s.slots
+    for a in (h.fwd.vals, h.tr.vals, h.head):
+        assert str(a.dtype) == "bfloat16"
+    for a in (f.fwd.vals, f.tr.vals, f.head):
+        assert str(a.dtype) == "float32"          # first-order plane f32
+    assert h.fwd.ids is f.fwd.ids and h.tr.owner is f.tr.owner
+    s32 = DiscoSolver(X, y, DiscoConfig(partition=partition, tau=16))
+    assert s32.slots_h is s32.slots
 
 
 def test_hvp_dtype_validation():

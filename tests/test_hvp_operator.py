@@ -32,7 +32,8 @@ import pytest
 
 from oracles import (ell_pair_case, fd_derivative, legacy_local_hvp,
                      local_hvp_multi_oracle, local_hvp_oracle,
-                     softmax_newton_fit, softmax_probs_oracle)
+                     slot_csr_case, slot_pair_case, softmax_newton_fit,
+                     softmax_probs_oracle)
 from repro.core.hvp import (SoftmaxHvpOperator, UnsupportedHvpError,
                             cell_id, make_local_operator, operator_cells,
                             render_support_matrix, resolve_cell,
@@ -101,6 +102,41 @@ def _check_binary_inmem(cell, rng, stream_env):
                           np.asarray(leg_m(U)))
 
 
+def _slot_case(rng, dtype):
+    """A slot pair with a head slab and multi-chunk slots (24 features,
+    2,000 samples, padded to 27 x 2,005) and its f32 dense twin."""
+    X = slot_csr_case(rng, d=24, n=2000)
+    return slot_pair_case(X, (27, 2005), _JDT[dtype]) + (X,)
+
+
+def _check_binary_slots(cell, rng, stream_env):
+    """Slots against the f64 oracle, and against the tile two-pass
+    product of the same matrix (slots share no logic with the tiles, so
+    there is no bit-identity target: f32 tolerance)."""
+    from repro.data.sparse import EllPair, ell_pair_from_csr
+
+    pair, Xf, X = _slot_case(rng, cell.dtype)
+    c = jnp.asarray(rng.random(Xf.shape[1]), jnp.float32)
+    u = jnp.asarray(rng.standard_normal(Xf.shape[0]), jnp.float32)
+    U = jnp.asarray(rng.standard_normal((Xf.shape[0], 3)), jnp.float32)
+    op = make_local_operator(pair, c, fused=cell.fused,
+                             partition=cell.partition)
+    assert op.fused == cell.fused and op.layout == "slots"
+    _check_against_oracle(op, Xf, c, u, U, cell.dtype)
+    fwd, tr = ell_pair_from_csr(X, 8, 8)
+    rp, cp = fwd.n_row_blocks * 8, tr.n_row_blocks * 8
+    ell = EllPair(jnp.asarray(fwd.data, _JDT[cell.dtype]),
+                  jnp.asarray(fwd.cols),
+                  jnp.asarray(tr.data, _JDT[cell.dtype]),
+                  jnp.asarray(tr.cols))
+    tiles = make_local_operator(ell, c[:cp],
+                                partition=cell.partition)
+    want = np.asarray(tiles.apply(jnp.pad(u[:24], (0, rp - 24))))[:24]
+    tol = _TOL[cell.dtype]
+    np.testing.assert_allclose(np.asarray(op.apply(u))[:24], want,
+                               rtol=tol, atol=tol * np.abs(want).max())
+
+
 def _softmax_local_oracle(Xf, P, wts, U):
     """f64 local softmax product X (w .* (P.*V - P.*rowsum(P.*V)))."""
     Xd = np.asarray(Xf, np.float64)
@@ -116,7 +152,14 @@ def _check_softmax_inmem(cell, rng, stream_env):
     use_kernel = cell.layout == "dense_kernel"
     K = 4
     W = rng.standard_normal((24, K)).astype(np.float32) * 0.3
-    if cell.layout == "ell":
+    if cell.layout == "slots":
+        pair, Xf, _ = _slot_case(rng, cell.dtype)
+        wts = np.zeros(Xf.shape[1], np.float32)
+        wts[:2000] = 1.0                    # mask the padding samples
+        W = np.pad(W, ((0, Xf.shape[0] - 24), (0, 0)))
+        base = make_local_operator(pair, None, fused=False,
+                                   partition=cell.partition)
+    elif cell.layout == "ell":
         pair, Xp = ell_pair_case(rng, 24, 40, 0.3, 8, 8, width_pad=1,
                                  dtype=_JDT[cell.dtype])
         Xf = np.asarray(jnp.asarray(Xp, _JDT[cell.dtype])
@@ -179,10 +222,12 @@ CHECKERS = {
     ("binary", "dense"): _check_binary_inmem,
     ("binary", "dense_kernel"): _check_binary_inmem,
     ("binary", "ell"): _check_binary_inmem,
+    ("binary", "slots"): _check_binary_slots,
     ("binary", "streamed"): _check_binary_streamed,
     ("softmax", "dense"): _check_softmax_inmem,
     ("softmax", "dense_kernel"): _check_softmax_inmem,
     ("softmax", "ell"): _check_softmax_inmem,
+    ("softmax", "slots"): _check_softmax_inmem,
 }
 
 
@@ -237,7 +282,7 @@ def test_every_supported_cell_has_checker():
 
 
 def test_registry_is_exhaustive_and_deterministic():
-    assert len(CELLS) == 2 * 4 * 2 * 2 * 2
+    assert len(CELLS) == 2 * 5 * 2 * 2 * 2
     assert CELLS == operator_cells()
     ids = [cell_id(c.family, c.layout, c.partition, c.fused, c.dtype)
            for c in CELLS]
@@ -245,7 +290,7 @@ def test_registry_is_exhaustive_and_deterministic():
     # the generated docs matrix has one row per (family, layout,
     # partition) triple
     matrix = render_support_matrix()
-    assert matrix.count("\n") == 2 * 4 * 2 + 1
+    assert matrix.count("\n") == 2 * 5 * 2 + 1
 
 
 # ---------------------------------------------------------------------------

@@ -336,7 +336,7 @@ def test_inmemory_counter_matches_ledger_and_iter_s(ref_mode, glm_data):
         assert h["iter_s"] > 0.0             # per-iteration wall-clock
 
 
-@pytest.mark.parametrize("layout", ["dense", "sparse"])
+@pytest.mark.parametrize("layout", ["dense", "sparse", "slots"])
 def test_solver_placement_spans_once_per_construction(ref_mode, glm_data,
                                                       layout):
     """Each ``DiscoSolver`` construction opens one ``disco.place`` span
@@ -348,18 +348,32 @@ def test_solver_placement_spans_once_per_construction(ref_mode, glm_data,
 
     assert SPAN_KINDS["disco.place"][1] == "span"
     assert "disco.place_bytes_max" in COUNTER_KINDS
-    X, y, _ = glm_data if layout == "dense" else _sparse_problem()
+    tile = 8
+    if layout == "dense":
+        X, y, _ = glm_data
+    elif layout == "sparse":
+        X, y, _ = _sparse_problem()
+    else:                       # 0.4% dense under 128 x 128 tiles
+        from repro.data.sparse import make_sparse_glm_data
+        X, y, _ = make_sparse_glm_data(1500, 2500, density=0.004,
+                                       alpha=1.0, seed=3)
+        tile = 128
     cfg = DiscoConfig(partition="samples", loss="logistic", lam=1e-2,
-                      tau=16, ell_block_d=8, ell_block_n=8)
+                      tau=16, ell_block_d=tile, ell_block_n=tile)
     tracer = obs.enable(reset=True)
     solvers = [DiscoSolver(X, y, cfg) for _ in range(2)]
     events, counters, _ = tracer.snapshot()
     place = [e for e in events if e.kind == "disco.place"]
     assert len(place) == 2 and all(e.ph == "X" for e in place)
     assert {e.args["shards"] for e in place} == {1}
-    placed = (["X", "y", "weights", "X_tau", "y_tau"] if layout == "dense"
-              else ["ell_data", "ell_cols", "ell_dataT", "ell_colsT", "y",
-                    "weights", "X_tau", "y_tau"])
+    if layout != "dense":
+        assert solvers[0].layout.layout == ("ell" if layout == "sparse"
+                                            else "slots")
+    placed = {"dense": ["X", "y", "weights", "X_tau", "y_tau"],
+              "sparse": ["ell_data", "ell_cols", "ell_dataT", "ell_colsT",
+                         "y", "weights", "X_tau", "y_tau"],
+              "slots": ["slots", "y", "weights", "X_tau",
+                        "y_tau"]}[layout]
     one = sum(getattr(solvers[0], k).nbytes for k in placed)
     # one device holds everything: the span's bytes and the counter agree
     assert {e.args["bytes"] for e in place} == {one}

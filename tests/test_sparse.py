@@ -231,3 +231,204 @@ def test_sparse_solver_warm_start_roundtrip():
     r2 = disco_fit(X, y, cfg, w0=r1.w)    # continue from the solution
     # restarting from the solution must not blow up the trajectory
     assert r2.grad_norms[-1] <= 5 * r1.grad_norms[-1] + 1e-6
+
+
+# ---------------------------------------------------------------------------
+# (id, value) slots: layout, products, layout choice, solver
+# ---------------------------------------------------------------------------
+
+SLOT_CASES = {"full_row": dict(full_row=True, heavy=True),
+              "heavy_only": dict(full_row=False, heavy=True),
+              "no_head": dict(full_row=False, heavy=False)}
+
+
+@pytest.mark.parametrize("case", sorted(SLOT_CASES))
+def test_slot_layout_holds_every_nonzero_once(rng, case):
+    """Owners sorted, every output owns a chunk, whole blocks of chunks,
+    and the slots plus the head slab rebuild the matrix exactly."""
+    import jax
+
+    from oracles import slot_csr_case
+    from repro.data.sparse import (SLOT_BLOCK, SLOT_WIDTH,
+                                   build_shard_slot_pairs)
+
+    X = slot_csr_case(rng, **SLOT_CASES[case])
+    shape = (X.shape[0] + 3, X.shape[1] + 5)
+    pair = jax.tree_util.tree_map(lambda a: a[0],
+                                  build_shard_slot_pairs([X], shape))
+    for lay, n_out, transpose in ((pair.fwd, shape[0], False),
+                                  (pair.tr, shape[1], True)):
+        assert lay.ids.shape[1] == SLOT_WIDTH
+        assert lay.ids.shape[0] % SLOT_BLOCK == 0
+        assert np.all(np.diff(lay.owner) >= 0)
+        assert set(np.unique(lay.owner)) == set(range(n_out))
+        got = np.zeros((n_out, shape[1 - transpose]), np.float64)
+        np.add.at(got, (np.repeat(lay.owner, SLOT_WIDTH), lay.ids.ravel()),
+                  lay.vals.ravel())
+        want = np.zeros(shape)
+        want[: X.shape[0], : X.shape[1]] = X.todense()
+        want[pair.head_rows] = 0.0
+        np.testing.assert_array_equal(got, want.T if transpose else want)
+    heads = X.nnz_per_row()[pair.head_rows]
+    assert len(pair.head_rows) == 0 or heads.min() > 14
+    dense = np.zeros(shape)
+    dense[: X.shape[0], : X.shape[1]] = X.todense()
+    np.testing.assert_array_equal(pair.head, dense[pair.head_rows])
+    if case == "no_head":
+        assert pair.head.shape == (0, shape[1])
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", sorted(SLOT_CASES))
+def test_slot_products_match_oracle_and_tiles(rng, case, dtype):
+    """Both passes, one vector and three, against the f64 oracle of the
+    values the slots hold and against the tile two-pass products."""
+    from oracles import slot_csr_case, slot_pair_case
+    from repro.core.hvp import EllOperator, SlotOperator
+    from repro.data.sparse import EllPair
+
+    X = slot_csr_case(rng, **SLOT_CASES[case])
+    shape = (X.shape[0] + 3, X.shape[1] + 5)
+    jdt = jnp.bfloat16 if dtype == "bfloat16" else None
+    pair, Xp = slot_pair_case(X, shape, jdt)
+    c = jnp.asarray(rng.random(shape[1]), jnp.float32)
+    u = jnp.asarray(rng.standard_normal(shape[0]), jnp.float32)
+    U = jnp.asarray(rng.standard_normal((shape[0], 3)), jnp.float32)
+    op = SlotOperator(pair, c)
+    Xd = Xp.astype(np.float64)
+    z, Z = op.pass_a(u), op.pass_a_multi(U)
+    y, Y = op.pass_b(z), op.pass_b_multi(Z)
+    for got, want in ((z, Xd.T @ np.asarray(u, np.float64)),
+                      (Z, Xd.T @ np.asarray(U, np.float64))):
+        assert got.dtype == jnp.float32
+        np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5,
+                                   atol=1e-5 * np.abs(want).max())
+    cz = np.asarray(c, np.float64)[:, None] * np.asarray(Z, np.float64)
+    np.testing.assert_allclose(
+        np.asarray(Y), Xd @ cz, rtol=1e-5, atol=1e-5 * np.abs(Xd @ cz).max())
+    want = Xd @ (np.asarray(c, np.float64) * np.asarray(z, np.float64))
+    np.testing.assert_allclose(np.asarray(y), want, rtol=1e-5,
+                               atol=1e-5 * np.abs(want).max())
+    # the tile two-pass products of the same matrix (8 x 8 tiles; tiles
+    # in bf16 round the probe vector too, so bf16 compares loosely)
+    fwd, tr = ell_pair_from_csr(X, 8, 8)
+    ell = EllPair(*(jnp.asarray(a, jdt) if a.dtype == np.float32 and jdt
+                    else jnp.asarray(a)
+                    for a in (fwd.data, fwd.cols, tr.data, tr.cols)))
+    rp, cp = fwd.n_row_blocks * 8, tr.n_row_blocks * 8
+    pad = lambda v, k: jnp.pad(v[: min(len(v), k)],
+                               (0, max(k - len(v), 0)))
+    tiles = EllOperator(ell, pad(c, cp))
+    zt = tiles.pass_a(pad(u, rp))
+    yt = tiles.pass_b(pad(z, cp))
+    tol = 1e-5 if dtype == "float32" else 2e-2
+    d, n = X.shape
+    np.testing.assert_allclose(np.asarray(z)[:n], np.asarray(zt)[:n],
+                               rtol=tol, atol=tol * np.abs(zt).max())
+    np.testing.assert_allclose(np.asarray(y)[:d], np.asarray(yt)[:d],
+                               rtol=tol, atol=tol * np.abs(yt).max())
+
+
+@pytest.mark.parametrize("m", [1, 2])
+@pytest.mark.parametrize("partition", ["features", "samples"])
+def test_slot_shards_match_their_dense_blocks(rng, partition, m):
+    """Stacked shards (one or two, either partition) each hold exactly
+    their own block of the permuted matrix."""
+    import jax
+
+    from oracles import slot_csr_case
+    from repro.core.hvp import SlotOperator
+    from repro.data.partition import make_partition
+    from repro.data.sparse import (build_shard_slot_pairs,
+                                   shard_csrs_from_partition)
+
+    X = slot_csr_case(rng, d=64, n=4000)
+    part = make_partition(X, partition, m, "lpt", pad_multiple=8)
+    shards = shard_csrs_from_partition(X, part, partition)
+    shape = shards[0].shape
+    pairs = build_shard_slot_pairs(shards, shape)
+    for s in range(m):
+        op = SlotOperator(jax.tree_util.tree_map(
+            lambda a: jnp.asarray(a[s]), pairs), None)
+        Xd = shards[s].todense().astype(np.float64)
+        u = rng.standard_normal(shape[0]).astype(np.float32)
+        z = rng.standard_normal(shape[1]).astype(np.float32)
+        np.testing.assert_allclose(np.asarray(op.pass_a(jnp.asarray(u))),
+                                   Xd.T @ u, rtol=1e-5, atol=1e-4)
+        np.testing.assert_allclose(np.asarray(op.pass_b(jnp.asarray(z))),
+                                   Xd @ z, rtol=1e-5, atol=1e-4)
+
+
+def _block_dense(rng, blocks=3, b=128):
+    """Dense 128 x 128 blocks on the diagonal: tiles at full fill."""
+    Xd = np.zeros((blocks * b, blocks * b), np.float32)
+    for k in range(blocks):
+        Xd[k * b:(k + 1) * b, k * b:(k + 1) * b] = rng.standard_normal(
+            (b, b))
+    return CSRMatrix.from_dense(Xd)
+
+
+@pytest.mark.parametrize("data", ["realsim_like", "block_dense"])
+def test_layout_choice_follows_tile_fill(rng, data):
+    """Slots where tiles are mostly padding (Zipf text at 0.4% density
+    under 128 x 128 tiles), tiles where they are full."""
+    from repro.data.sparse import choose_hvp_layout
+
+    if data == "realsim_like":
+        X, _, _ = make_sparse_glm_data(1500, 2500, density=0.004,
+                                       alpha=1.0, seed=3)
+        want = "slots"
+    else:
+        X, want = _block_dense(rng), "ell"
+    choice = choose_hvp_layout([X], 128, 128)
+    assert choice.layout == want, choice
+    if want == "slots":
+        assert choice.tile_bytes > 5 * choice.slot_bytes
+
+
+@pytest.mark.parametrize("partition", ["features", "samples"])
+def test_slot_solver_reaches_grad_target(partition):
+    """A sparse solve through slots reaches grad_rel <= 1e-5 in float64,
+    and its counter records the slot bytes it placed."""
+    from oracles import logistic_grad_oracle
+    from repro import obs
+    from repro.core import DiscoSolver
+
+    X, y, _ = make_sparse_glm_data(1500, 2500, density=0.004, alpha=1.0,
+                                   seed=3)
+    lam = 1e-3
+    Xd = X.todense().astype(np.float64)
+    g0 = np.linalg.norm(logistic_grad_oracle(Xd, y, np.zeros(X.shape[0]),
+                                             lam))
+    tracer = obs.enable(reset=True)
+    try:
+        solver = DiscoSolver(X, y, DiscoConfig(
+            partition=partition, loss="logistic", lam=lam, tau=16,
+            max_outer=20, grad_tol=1e-7 * g0))
+        _, counters, _ = tracer.snapshot()
+    finally:
+        obs.disable()
+    assert solver.layout.layout == "slots"
+    assert not hasattr(solver, "ell_data")
+    assert counters["disco.hvp_slot_bytes"] == solver.slots.nbytes > 0
+    res = solver.fit()
+    g = logistic_grad_oracle(Xd, y, res.w.astype(np.float64), lam)
+    assert res.converged
+    assert np.linalg.norm(g) / g0 <= 1e-5
+
+
+def test_tile_solver_counts_no_slot_bytes(rng):
+    from repro import obs
+    from repro.core import DiscoSolver
+
+    X = _block_dense(rng, blocks=2)
+    y = np.where(rng.random(X.shape[1]) < 0.5, -1.0, 1.0).astype(np.float32)
+    tracer = obs.enable(reset=True)
+    try:
+        solver = DiscoSolver(X, y, DiscoConfig(partition="features",
+                                               tau=16))
+        _, counters, _ = tracer.snapshot()
+    finally:
+        obs.disable()
+    assert solver.layout.layout == "ell"
+    assert counters["disco.hvp_slot_bytes"] == 0

@@ -181,3 +181,28 @@ def test_streamed_step_compiles_on_four_chips(v5e, body):
         n_stacked=len(keys), n_sliced=n_sliced, chunk=chunk, reduce=reduce,
         mode="native").compile().as_text()
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_slot_passes_compile_for_v5e(one_chip, dtype):
+    """Both slot passes at the real-sim cell's shape (20,992 x 36,224
+    padded, a 1,431-row head slab, 57,984 and 66,176 chunks of 8 slots):
+    the one-hot gather and the window sums, with no Pallas kernel and
+    well inside one chip's memory."""
+    from repro.data.sparse import SLOT_WIDTH, SlotLayout, SlotPair
+
+    rows, cols, H = 20_992, 36_224, 1_431
+    sds = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+    lay = lambda c: SlotLayout(sds((c, SLOT_WIDTH), jnp.int32),
+                               sds((c, SLOT_WIDTH), dtype),
+                               sds((c,), jnp.int32))
+    pair = SlotPair(lay(57_984), lay(66_176), sds((H, cols), dtype),
+                    sds((H,), jnp.int32), (rows, cols))
+    u, z = sds((rows,), jnp.float32), sds((cols,), jnp.float32)
+    for fn, args, n_out in ((ops.slot_xt, (pair, u), cols),
+                            (ops.slot_x, (pair, z, z), rows)):
+        compiled = fn.lower(*args).compile()
+        assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+        out = jax.eval_shape(fn, *args)
+        assert out.shape == (n_out,) and out.dtype == jnp.float32

@@ -250,8 +250,8 @@ def phase_sparse(seed: int, sz: Sizes) -> dict:
     solver = DiscoSolver(X, y, DiscoConfig(ell_block_d=sz.tile,
                                            ell_block_n=sz.tile, **base))
     layout = solver.layout.layout
-    layout_bytes = (solver.slots.nbytes if layout == "slots" else
-                    solver.ell_data.nbytes + solver.ell_dataT.nbytes)
+    layout_bytes = (solver.X.nbytes if layout == "slots" else
+                    solver.X.data.nbytes + solver.X.dataT.nbytes)
     res, _, timing = timed_fit(solver)
     del solver
     gc.collect()

@@ -36,9 +36,10 @@ from repro.obs import tracer as obs
 from repro.data.partition import Partition, make_partition
 from repro.kernels import ops as kops
 from repro.launch.mesh import make_mesh
-from repro.data.sparse import (CSRMatrix, EllPair, build_shard_ell_pairs,
-                               build_shard_slot_pairs, choose_hvp_layout,
-                               hvp_tile_dtype, shard_csrs_from_partition)
+from repro.data.sparse import (CSRMatrix, EllPair, SlotPair,
+                               build_shard_ell_pairs, build_shard_slot_pairs,
+                               choose_hvp_layout, hvp_tile_dtype,
+                               shard_csrs_from_partition, with_hvp_dtype)
 from repro.robust.checkpoint import (CheckpointState, load_checkpoint,
                                      save_checkpoint)
 from repro.robust.faults import FaultInjector, FaultPlan
@@ -282,9 +283,15 @@ class DiscoSolver:
     docs/architecture.md#shape-conventions) either **dense** (any array)
     or **sparse** (:class:`repro.data.sparse.CSRMatrix`). Sparse inputs
     additionally run the nnz-aware load-balanced partitioner
-    (``cfg.partition_strategy``, docs/partitioning.md) and the blocked-ELL
-    Pallas HVP kernels; the resulting shard-balance metrics are reported
+    (``cfg.partition_strategy``, docs/partitioning.md) and take the HVP
+    layout their index structure favours, (id, value) slots or
+    blocked-ELL tiles; the resulting shard-balance metrics are reported
     in ``DiscoResult.partition_info``.
+
+    Whatever the layout, the placed data is one value, ``self.X`` (a
+    dense array, or the stacked ``SlotPair`` or ``EllPair`` of the
+    shards), with its HVP-dtype twin ``self.X_hvp``; one jitted step per
+    partition takes every X product from that value's local operator.
 
     Args:
         X: (d, n) dense array or CSRMatrix.
@@ -329,37 +336,33 @@ class DiscoSolver:
             self._init_sparse(X, y)
         else:
             self._init_dense(X, y)
+        self._w_sharding = NamedSharding(
+            self.mesh, P(axis) if cfg.partition == "features" else P())
+        self._w_shape = (self.d_padded,)
         self._step = self._build_step()
 
     def _init_dense(self, X, y):
         cfg, axis = self.cfg, self.axis
         # preconditioner samples: the first tau columns ("master's" samples)
-        self.tau_idx = np.arange(self.tau)
         X_tau = X[:, : self.tau].copy()
         y_tau = y[: self.tau].copy()
 
-        hdt = hvp_tile_dtype(cfg.hvp_dtype)
-
         if cfg.partition == "features":
-            Xp, self._dpad = pad_to_multiple(X, 0, self.m)
+            Xp, _ = pad_to_multiple(X, 0, self.m)
             self.d_padded = Xp.shape[0]
             X_tau_p, _ = pad_to_multiple(X_tau, 0, self.m)
             self._place(X=(Xp, P(axis, None)), X_tau=(X_tau_p, P(axis, None)),
                         y=(y, P()), y_tau=(y_tau, P()))
-            self.weights = None
-            self._w_sharding = NamedSharding(self.mesh, P(axis))
-            self._w_shape = (self.d_padded,)
         elif cfg.partition == "samples":
             Xp, npad = pad_to_multiple(X, 1, self.m)
             yp, _ = pad_to_multiple(y, 0, self.m)
             wts = np.ones(self.n, X.dtype)
             wts = np.pad(wts, (0, npad))
+            self.d_padded = self.d
             self.n_padded = Xp.shape[1]
             self._place(X=(Xp, P(None, axis)), y=(yp, P(axis)),
                         weights=(wts, P(axis)), X_tau=(X_tau, P()),
                         y_tau=(y_tau, P()))
-            self._w_sharding = NamedSharding(self.mesh, P())
-            self._w_shape = (self.d,)
         else:
             raise ValueError(f"unknown partition {cfg.partition!r}")
 
@@ -367,7 +370,7 @@ class DiscoSolver:
         # loop streams this; margins/gradient/preconditioner stay on the
         # f32 original. Same object when hvp_dtype is the data dtype, so
         # the default costs nothing.
-        self.X_hvp = self.X if self.X.dtype == hdt else self.X.astype(hdt)
+        self.X_hvp = with_hvp_dtype(self.X, hvp_tile_dtype(cfg.hvp_dtype))
 
     def _init_sparse(self, X: CSRMatrix, y):
         """Partition (load-balanced), lay out, and shard a sparse matrix.
@@ -375,22 +378,20 @@ class DiscoSolver:
         The chosen axis is permuted by the nnz-aware partitioner, and
         each shard's local matrix is laid out, as the index structure
         decides (:func:`repro.data.sparse.choose_hvp_layout`), either as
-        (id, value) slots with a dense head slab (``self.slots``) or as a
-        forward + transposed blocked-ELL pair (``self.ell_*``). The tau
-        preconditioner samples are materialized as a small dense slab
-        (neither layout can be column-sliced on device).
+        (id, value) slots with a dense head slab (a ``SlotPair``) or as a
+        forward + transposed blocked-ELL pair (an ``EllPair``). The
+        shards' pairs, stacked on a leading shard axis, are ``self.X``.
+        The tau preconditioner samples are materialized as a small dense
+        slab (neither layout can be column-sliced on device).
         """
         cfg, axis, m = self.cfg, self.axis, self.m
         br, bc = cfg.ell_block_d, cfg.ell_block_n
         d, n = self.d, self.n
         dtype = X.dtype
-        self._dtype = jax.dtypes.canonicalize_dtype(dtype)
 
         # preconditioner samples: the first tau *original* columns
         X_tau = X.take_cols_dense(np.arange(self.tau))          # (d, tau)
         y_tau = y[: self.tau].copy()
-
-        hdt = hvp_tile_dtype(cfg.hvp_dtype)
 
         if cfg.partition == "features":
             part = make_partition(X, "features", m,
@@ -409,8 +410,6 @@ class DiscoSolver:
 
             placed = dict(X_tau=(X_tau_p, P(axis, None)), y=(y_p, P()),
                           smask=(smask, P()))
-            self._w_sharding = NamedSharding(self.mesh, P(axis))
-            self._w_shape = (self.d_padded,)
         elif cfg.partition == "samples":
             part = make_partition(X, "samples", m,
                                   cfg.partition_strategy,
@@ -427,41 +426,29 @@ class DiscoSolver:
 
             placed = dict(y=(y_p, P(axis)), weights=(wts, P(axis)),
                           X_tau=(X_tau_p, P()))
-            self._w_sharding = NamedSharding(self.mesh, P())
-            self._w_shape = (self.d_padded,)
         else:
             raise ValueError(f"unknown partition {cfg.partition!r}")
         self._part = part
         self.layout = choose_hvp_layout(shard_csrs, br, bc)
-        self._slots = self.layout.layout == "slots"
+        slots = self.layout.layout == "slots"
         validate_solver_cell(family="binary", partition=cfg.partition,
                              fused=cfg.hvp_fused, dtype=cfg.hvp_dtype,
-                             sparse=True, slots=self._slots)
-        # mixed-precision HVP copies (docs/kernels.md): the PCG loop
-        # streams these; margins/gradient keep the f32 values, and ids,
-        # owners and tile columns are shared. Same objects at the default
-        # hvp_dtype, so f32 costs nothing; otherwise each device casts its
-        # own shard.
-        if self._slots:
+                             sparse=True, slots=slots)
+        if slots:
             local = ((self.d_padded // m, self.n_padded)
                      if cfg.partition == "features"
                      else (self.d_padded, self.n_padded // m))
-            pair = build_shard_slot_pairs(shard_csrs, local)
-            self._place(slots=(pair, P(axis)), y_tau=(y_tau, P()), **placed)
-            self.slots_h = self.slots.with_values(hdt)
-            obs.count("disco.hvp_slot_bytes", pair.nbytes)
-            return
-        data, cols, dataT, colsT = build_shard_ell_pairs(shard_csrs, br, bc)
-        self._place(ell_data=(data, P(axis)), ell_cols=(cols, P(axis)),
-                    ell_dataT=(dataT, P(axis)), ell_colsT=(colsT, P(axis)),
-                    y_tau=(y_tau, P()), **placed)
-        if self.ell_data.dtype == hdt:
-            self.ell_data_h = self.ell_data
-            self.ell_dataT_h = self.ell_dataT
+            pairs = build_shard_slot_pairs(shard_csrs, local)
         else:
-            self.ell_data_h = self.ell_data.astype(hdt)
-            self.ell_dataT_h = self.ell_dataT.astype(hdt)
-        obs.count("disco.hvp_slot_bytes", 0)
+            pairs = EllPair(*build_shard_ell_pairs(shard_csrs, br, bc))
+        self._place(X=(pairs, P(axis)), y_tau=(y_tau, P()), **placed)
+        # mixed-precision HVP copy (docs/kernels.md): the PCG loop
+        # streams it; margins/gradient keep the f32 values, and ids,
+        # owners and tile columns are shared. The same object at the
+        # default hvp_dtype, so f32 costs nothing; otherwise each device
+        # casts its own shard.
+        self.X_hvp = with_hvp_dtype(self.X, hvp_tile_dtype(cfg.hvp_dtype))
+        obs.count("disco.hvp_slot_bytes", pairs.nbytes if slots else 0)
 
     def _place(self, **arrays):
         """Put host arrays or pytrees of them, ``name=(tree, spec)``, on
@@ -487,24 +474,56 @@ class DiscoSolver:
 
     # ------------------------------------------------------------------
     def _build_step(self):
-        if self._sparse:
-            return self._build_step_sparse()
+        """The jitted damped Newton step of an in-memory solve, one per
+        partition (paper Algorithms 3 and 2), for every layout of
+        ``self.X``. Every X product goes through the layout's local
+        operator (``core/hvp.py``). A dense array is sliced on its
+        partition dimension; a stacked pytree (slots or blocked-ELL
+        tiles) is sharded on its leading shard axis, which each shard
+        drops."""
         cfg, loss, axis = self.cfg, self.loss, self.axis
         n, tau = self.n, self.tau
         frac = cfg.hessian_subsample
+        features = cfg.partition == "features"
+        if isinstance(self.X, (EllPair, SlotPair)):
+            x_spec = P(axis)
+            local = lambda X: jax.tree_util.tree_map(lambda a: a[0], X)
+        else:
+            x_spec = P(axis, None) if features else P(None, axis)
+            local = lambda X: X
+        pcg_kw = dict(mu=cfg.mu, axis_name=axis, precond=cfg.precond,
+                      use_kernel=cfg.use_kernel, block_s=cfg.pcg_block_s,
+                      axis_size=self.m, hvp_fused=cfg.hvp_fused)
 
-        if cfg.partition == "features":
-            def step_local(X_loc, Xh_loc, X_tau_loc, y, y_tau, w_loc, key):
-                margins = lax.psum(X_loc.T @ w_loc, axis)           # (n,)
+        def damped(w, res, gnorm, fval):
+            w_new = w - res.v / (1.0 + res.delta)
+            stats = dict(grad_norm=gnorm, f=fval, pcg_iters=res.iters,
+                         delta=res.delta, pcg_r_norm=res.r_norm)
+            return w_new, stats
+
+        if features:
+            # samples padded by the sparse layouts are masked out; dense
+            # X has none, and its program takes no mask
+            smask = self.smask if self._sparse else None
+
+            def step_local(Xs, Xhs, X_tau_loc, y, y_tau, smask, w_loc, key):
+                op = make_local_operator(local(Xs), None,
+                                         partition="features")
+                margins = lax.psum(op.pass_a(w_loc), axis)           # (n,)
                 d1 = loss.d1(margins, y)
                 c = loss.d2(margins, y)
-                g_loc = X_loc @ d1 / n + cfg.lam * w_loc
+                if smask is None:
+                    f_loss = jnp.mean(loss.value(margins, y))
+                else:
+                    d1, c = d1 * smask, c * smask
+                    f_loss = jnp.sum(loss.value(margins, y) * smask) / n
+                g_loc = op.pass_b(d1) / n + cfg.lam * w_loc
                 gnorm = jnp.sqrt(lax.psum(jnp.vdot(g_loc, g_loc), axis))
-                fval = jnp.mean(loss.value(margins, y)) + 0.5 * cfg.lam * lax.psum(
+                fval = f_loss + 0.5 * cfg.lam * lax.psum(
                     jnp.vdot(w_loc, w_loc), axis)
 
                 if frac < 1.0:  # Hessian subsampling, paper §5.4
-                    mask = jax.random.bernoulli(key, frac, (n,))
+                    mask = jax.random.bernoulli(key, frac, margins.shape)
                     c_eff = c * mask / frac
                 else:
                     c_eff = c
@@ -514,148 +533,32 @@ class DiscoSolver:
                 # f32 tau slab feeds the preconditioner
                 eps = cfg.pcg_rel_tol * gnorm
                 res = pcg_features(
-                    Xh_loc, c_eff, n, cfg.lam, g_loc, eps, cfg.max_pcg,
-                    tau_idx=jnp.arange(tau), coeffs_tau=coeffs_tau,
-                    mu=cfg.mu, axis_name=axis, precond=cfg.precond,
-                    use_kernel=cfg.use_kernel, block_s=cfg.pcg_block_s,
-                    X_tau_loc=X_tau_loc, axis_size=self.m,
-                    hvp_fused=cfg.hvp_fused)
-                w_new = w_loc - res.v / (1.0 + res.delta)
-                stats = dict(grad_norm=gnorm, f=fval, pcg_iters=res.iters,
-                             delta=res.delta, pcg_r_norm=res.r_norm)
-                return w_new, stats
-
-            fn = jax.jit(shard_map(
-                step_local, mesh=self.mesh,
-                in_specs=(P(axis, None), P(axis, None), P(axis, None),
-                          P(), P(), P(axis), P()),
-                out_specs=(P(axis), P()),
-                check_vma=False))  # pallas_call outputs carry no vma info
-
-            def step(w, key):
-                return fn(self.X, self.X_hvp, self.X_tau, self.y,
-                          self.y_tau, w, key)
-
-        else:  # samples
-            def step_local(X_loc, Xh_loc, y_loc, wts_loc, X_tau, y_tau, w,
-                           key):
-                margins = X_loc.T @ w                                # (n_loc,)
-                d1 = loss.d1(margins, y_loc) * wts_loc
-                c = loss.d2(margins, y_loc) * wts_loc
-                g = lax.psum(X_loc @ d1, axis) / n + cfg.lam * w
-                gnorm = jnp.sqrt(jnp.vdot(g, g))
-                fval = lax.psum(jnp.sum(loss.value(margins, y_loc) * wts_loc),
-                                axis) / n + 0.5 * cfg.lam * jnp.vdot(w, w)
-
-                if frac < 1.0:
-                    mask = _shard_subsample_mask(key, frac, margins.shape, axis)
-                    c_eff = c * mask / frac
-                else:
-                    c_eff = c
-                coeffs_tau = loss.d2(X_tau.T @ w, y_tau)
-
-                eps = cfg.pcg_rel_tol * gnorm
-                res = pcg_samples(
-                    Xh_loc, c_eff, n, cfg.lam, g, eps, cfg.max_pcg,
-                    X_tau=X_tau, coeffs_tau=coeffs_tau, mu=cfg.mu,
-                    axis_name=axis, precond=cfg.precond,
-                    sag_epochs=cfg.sag_epochs, use_kernel=cfg.use_kernel,
-                    block_s=cfg.pcg_block_s, axis_size=self.m,
-                    hvp_fused=cfg.hvp_fused)
-                w_new = w - res.v / (1.0 + res.delta)
-                stats = dict(grad_norm=gnorm, f=fval, pcg_iters=res.iters,
-                             delta=res.delta, pcg_r_norm=res.r_norm)
-                return w_new, stats
-
-            fn = jax.jit(shard_map(
-                step_local, mesh=self.mesh,
-                in_specs=(P(None, axis), P(None, axis), P(axis), P(axis),
-                          P(), P(), P(), P()),
-                out_specs=(P(), P()),
-                check_vma=False))  # pallas_call outputs carry no vma info
-
-            def step(w, key):
-                return fn(self.X, self.X_hvp, self.y, self.weights,
-                          self.X_tau, self.y_tau, w, key)
-
-        # the device data enter the jitted program as arguments: an array
-        # closed over by a jitted function is embedded as a constant
-        return step
-
-    # ------------------------------------------------------------------
-    def _build_step_sparse(self):
-        """Sparse twin of ``_build_step``: identical algorithm, with every
-        X product routed through the local operator of the layout the
-        solver chose (slots or blocked-ELL tiles, ``core/hvp.py``). The
-        layout's arrays enter ``shard_map`` as one pytree sharded on
-        their leading (shard) axis, and each shard drops that axis."""
-        cfg, loss, axis = self.cfg, self.loss, self.axis
-        n, tau = self.n, self.tau
-        frac = cfg.hessian_subsample
-        if self._slots:
-            X, X_h = self.slots, self.slots_h
-        else:
-            # HVP twin: (possibly bf16) tile copies, shared cols
-            X = EllPair(self.ell_data, self.ell_cols, self.ell_dataT,
-                        self.ell_colsT)
-            X_h = EllPair(self.ell_data_h, self.ell_cols, self.ell_dataT_h,
-                          self.ell_colsT)
-        local = lambda t: jax.tree_util.tree_map(lambda a: a[0], t)
-
-        if cfg.partition == "features":
-            def step_local(Xs, Xhs, X_tau_loc, y, y_tau, smask, w_loc, key):
-                op = make_local_operator(local(Xs), None,
-                                         partition="features")
-                margins = lax.psum(op.pass_a(w_loc), axis)
-                d1 = loss.d1(margins, y) * smask
-                c = loss.d2(margins, y) * smask
-                g_loc = op.pass_b(d1) / n + cfg.lam * w_loc
-                gnorm = jnp.sqrt(lax.psum(jnp.vdot(g_loc, g_loc), axis))
-                fval = jnp.sum(loss.value(margins, y) * smask) / n \
-                    + 0.5 * cfg.lam * lax.psum(jnp.vdot(w_loc, w_loc), axis)
-
-                if frac < 1.0:  # Hessian subsampling, paper §5.4
-                    mask = jax.random.bernoulli(key, frac, margins.shape)
-                    c_eff = c * mask / frac
-                else:
-                    c_eff = c
-                coeffs_tau = loss.d2(margins[:tau], y_tau)
-
-                eps = cfg.pcg_rel_tol * gnorm
-                res = pcg_features(
                     local(Xhs), c_eff, n, cfg.lam, g_loc, eps, cfg.max_pcg,
-                    coeffs_tau=coeffs_tau, mu=cfg.mu, axis_name=axis,
-                    precond=cfg.precond, block_s=cfg.pcg_block_s,
-                    X_tau_loc=X_tau_loc, axis_size=self.m,
-                    hvp_fused=cfg.hvp_fused)
-                w_new = w_loc - res.v / (1.0 + res.delta)
-                stats = dict(grad_norm=gnorm, f=fval, pcg_iters=res.iters,
-                             delta=res.delta, pcg_r_norm=res.r_norm)
-                return w_new, stats
+                    coeffs_tau=coeffs_tau, X_tau_loc=X_tau_loc, **pcg_kw)
+                return damped(w_loc, res, gnorm, fval)
 
             fn = jax.jit(shard_map(
                 step_local, mesh=self.mesh,
-                in_specs=(P(axis), P(axis), P(axis, None), P(), P(), P(),
+                in_specs=(x_spec, x_spec, P(axis, None), P(), P(), P(),
                           P(axis), P()),
                 out_specs=(P(axis), P()),
                 check_vma=False))  # pallas_call outputs carry no vma info
 
             def step(w, key):
-                return fn(X, X_h, self.X_tau, self.y, self.y_tau,
-                          self.smask, w, key)
+                return fn(self.X, self.X_hvp, self.X_tau, self.y,
+                          self.y_tau, smask, w, key)
 
         else:  # samples
             def step_local(Xs, Xhs, y_loc, wts_loc, X_tau, y_tau, w, key):
                 op = make_local_operator(local(Xs), None,
                                          partition="samples")
-                margins = op.pass_a(w)
+                margins = op.pass_a(w)                               # (n_loc,)
                 d1 = loss.d1(margins, y_loc) * wts_loc
                 c = loss.d2(margins, y_loc) * wts_loc
                 g = lax.psum(op.pass_b(d1), axis) / n + cfg.lam * w
                 gnorm = jnp.sqrt(jnp.vdot(g, g))
-                fval = lax.psum(jnp.sum(loss.value(margins, y_loc)
-                                        * wts_loc), axis) / n \
-                    + 0.5 * cfg.lam * jnp.vdot(w, w)
+                fval = lax.psum(jnp.sum(loss.value(margins, y_loc) * wts_loc),
+                                axis) / n + 0.5 * cfg.lam * jnp.vdot(w, w)
 
                 if frac < 1.0:
                     mask = _shard_subsample_mask(key, frac, margins.shape,
@@ -668,26 +571,20 @@ class DiscoSolver:
                 eps = cfg.pcg_rel_tol * gnorm
                 res = pcg_samples(
                     local(Xhs), c_eff, n, cfg.lam, g, eps, cfg.max_pcg,
-                    X_tau=X_tau, coeffs_tau=coeffs_tau, mu=cfg.mu,
-                    axis_name=axis, precond=cfg.precond,
-                    sag_epochs=cfg.sag_epochs,
-                    block_s=cfg.pcg_block_s, axis_size=self.m,
-                    hvp_fused=cfg.hvp_fused)
-                w_new = w - res.v / (1.0 + res.delta)
-                stats = dict(grad_norm=gnorm, f=fval, pcg_iters=res.iters,
-                             delta=res.delta, pcg_r_norm=res.r_norm)
-                return w_new, stats
+                    X_tau=X_tau, coeffs_tau=coeffs_tau,
+                    sag_epochs=cfg.sag_epochs, **pcg_kw)
+                return damped(w, res, gnorm, fval)
 
             fn = jax.jit(shard_map(
                 step_local, mesh=self.mesh,
-                in_specs=(P(axis), P(axis), P(axis), P(axis), P(), P(), P(),
+                in_specs=(x_spec, x_spec, P(axis), P(axis), P(), P(), P(),
                           P()),
                 out_specs=(P(), P()),
                 check_vma=False))  # pallas_call outputs carry no vma info
 
             def step(w, key):
-                return fn(X, X_h, self.y, self.weights, self.X_tau,
-                          self.y_tau, w, key)
+                return fn(self.X, self.X_hvp, self.y, self.weights,
+                          self.X_tau, self.y_tau, w, key)
 
         # the device data enter the jitted program as arguments: an array
         # closed over by a jitted function is embedded as a constant
@@ -1190,8 +1087,8 @@ class DiscoSolver:
         """Cheap clone at a different regularization weight — the λ-path
         primitive (:mod:`repro.core.lambda_path`).
 
-        Shares every sharded device array (X, its HVP-dtype copy, ELL
-        tiles, labels, the tau slab) with ``self`` and rebuilds only the
+        Shares every sharded device array (X in its layout, its HVP-dtype
+        twin, labels, the tau slab) with ``self`` and rebuilds only the
         compiled step, so sweeping a λ grid pays the data layout once.
         In-memory solvers only; streaming solves rebuild via
         :meth:`from_store` per λ.
@@ -1274,10 +1171,7 @@ class DiscoSolver:
         re-planned schedule.
         """
         cfg = self.cfg
-        if self._streaming:
-            dtype = self._plan.store.dtype
-        else:
-            dtype = self._dtype if self._sparse else self.X.dtype
+        dtype = self._plan.store.dtype if self._streaming else self.X.dtype
 
         history: list[dict[str, Any]] = []
         ledger = comm.CommLedger()

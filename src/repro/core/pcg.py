@@ -397,18 +397,21 @@ def pcg_features(X_loc, coeffs, n_global, lam, g_loc, eps, max_iter,
                  X_tau_loc=None, axis_size=None, hvp_fused=False):
     """Runs inside shard_map over ``axis_name``.
 
-    X_loc      : (d_j, n) local feature rows (all samples) — a dense array
-                 or a blocked-ELL :class:`repro.data.sparse.EllPair`
-                 (then every vector below carries the ELL-padded lengths);
+    X_loc      : (d_j, n) local feature rows (all samples) — a dense
+                 array, or a :class:`repro.data.sparse.EllPair` or
+                 :class:`repro.data.sparse.SlotPair` (then every vector
+                 below carries the layout's padded lengths);
                  f32, or the bf16 mixed-precision HVP copy
                  (``DiscoConfig.hvp_dtype`` — state vectors stay f32)
     coeffs     : (n,) phi'' at w_k — *replicated* (derived from the globally
                  reduced margins, which every shard already holds)
     g_loc      : (d_j,) local gradient shard
-    tau_idx    : (tau,) indices of the preconditioner samples
-    X_tau_loc  : (d_j, tau) dense local rows of the preconditioner samples;
-                 required for sparse ``X_loc`` (which cannot be column-
-                 sliced in-kernel), optional for dense
+    tau_idx    : (tau,) indices of the preconditioner samples, sliced
+                 from a dense ``X_loc`` when ``X_tau_loc`` is not given
+    X_tau_loc  : (d_j, tau) dense local rows of the preconditioner samples
+                 — what ``DiscoSolver`` passes for every layout; required
+                 for sparse ``X_loc`` (which cannot be column-sliced
+                 in-kernel)
     block_s    : >1 selects the s-step engine (see pcg_samples)
     axis_size  : static size of ``axis_name``; with ``hvp_fused`` a size-1
                  axis lets the classic HVP fuse too (the z psum is the

@@ -35,7 +35,7 @@ from repro.core.hvp import (SoftmaxHvpOperator, make_local_operator,
                             validate_solver_cell)
 from repro.core.pcg import (PCGResult, _krylov_columns, _mgs, _pcg_loop,
                             _sstep_loop)
-from repro.data.sparse import hvp_tile_dtype
+from repro.data.sparse import hvp_tile_dtype, with_hvp_dtype
 from repro.launch.mesh import make_mesh
 from repro.utils.padding import pad_to_multiple
 
@@ -165,7 +165,6 @@ class SoftmaxSolver:
         self.mesh = mesh if mesh is not None else make_mesh(
             (jax.device_count(),), (axis,))
         self.m = self.mesh.shape[axis]
-        hdt = hvp_tile_dtype(cfg.hvp_dtype)
 
         Y1 = np.eye(self.K, dtype=X.dtype)[y]               # (n, K)
         X_tau = X[:, : self.tau].copy()
@@ -197,7 +196,7 @@ class SoftmaxSolver:
             raise ValueError(f"unknown partition {cfg.partition!r}")
         self.X_tau = jax.device_put(jnp.asarray(X_tau), rep)
         self.Y1_tau = jax.device_put(jnp.asarray(Y1_tau), rep)
-        self.X_hvp = self.X if self.X.dtype == hdt else self.X.astype(hdt)
+        self.X_hvp = with_hvp_dtype(self.X, hvp_tile_dtype(cfg.hvp_dtype))
         self._step = self._build_step()
 
     # ------------------------------------------------------------------

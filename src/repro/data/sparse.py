@@ -366,6 +366,19 @@ class EllPair(NamedTuple):
         ncb, _, bc, _ = self.dataT.shape
         return nrb * br, ncb * bc
 
+    @property
+    def nbytes(self) -> int:
+        """Bytes of every array of the pair."""
+        return int(sum(a.nbytes for a in self))
+
+    def with_values(self, dtype) -> "EllPair":
+        """The same pair with its tile values in ``dtype``; the column
+        arrays shared."""
+        if self.dtype == dtype:
+            return self
+        return self._replace(data=self.data.astype(dtype),
+                             dataT=self.dataT.astype(dtype))
+
 
 def ell_pair_from_csr(csr: CSRMatrix, block_rows: int, block_cols: int,
                       width: int | None = None, width_t: int | None = None
@@ -521,6 +534,16 @@ class SlotPair:
 jax.tree_util.register_dataclass(
     SlotPair, data_fields=["fwd", "tr", "head", "head_rows"],
     meta_fields=["shape"])
+
+
+def with_hvp_dtype(X, dtype):
+    """The HVP twin of a solver's data (docs/kernels.md): a dense array,
+    an :class:`EllPair` or a :class:`SlotPair` with its values in
+    ``dtype`` and its index arrays shared; ``X`` itself when its values
+    already are."""
+    if isinstance(X, (EllPair, SlotPair)):
+        return X.with_values(dtype)
+    return X if X.dtype == dtype else X.astype(dtype)
 
 
 def slot_chunks(nnz_per_output: np.ndarray) -> int:

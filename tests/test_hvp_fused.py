@@ -309,45 +309,50 @@ def test_solver_bf16_converges_to_f32_optimum(ref_mode):
         assert rel <= 1e-4, (partition, rel)
 
 
-def test_solver_bf16_tiles_actually_engaged(ref_mode):
-    from repro.core import DiscoConfig, DiscoSolver
+def _bf16_twin_problem(layout):
+    """Data on which each in-memory layout is the one the solver takes,
+    with the config keys it needs."""
+    from repro.data.sparse import make_sparse_glm_data
+    from repro.data.synthetic import make_glm_data
 
-    X, y, _ = _solver_problem(seed=5)
-    cfg = DiscoConfig(partition="samples", loss="logistic", lam=1e-2,
-                      tau=16, ell_block_d=8, ell_block_n=8,
-                      hvp_dtype="bfloat16")
-    s = DiscoSolver(X, y, cfg)
-    assert s.layout.layout == "ell"               # 20% dense: tiles win
-    assert str(s.ell_data_h.dtype) == "bfloat16"
-    assert str(s.ell_dataT_h.dtype) == "bfloat16"
-    assert str(s.ell_data.dtype) == "float32"     # first-order plane f32
-    # default config shares the same buffers (no copy)
-    s32 = DiscoSolver(X, y, DiscoConfig(partition="samples",
-                                        ell_block_d=8, ell_block_n=8))
-    assert s32.ell_data_h is s32.ell_data
+    if layout == "dense":
+        X, y, _ = make_glm_data(d=60, n=300, seed=0)
+        return np.asarray(X), np.asarray(y), {}
+    if layout == "ell":                           # 20% dense: tiles win
+        X, y, _ = _solver_problem(seed=5)
+        return X, y, dict(ell_block_d=8, ell_block_n=8)
+    X, y, _ = make_sparse_glm_data(1500, 2500, density=0.004, alpha=1.0,
+                                   seed=3)
+    return X, y, {}
 
 
 @pytest.mark.parametrize("partition", ["features", "samples"])
-def test_solver_bf16_slots_actually_engaged(partition):
-    """The slot twin: on data where slots win, the HVP copy's slot values
-    and head slab are bf16, the first-order plane stays f32, ids and
-    owners are shared, and at f32 the copy is the same object."""
+@pytest.mark.parametrize("layout", ["dense", "ell", "slots"])
+def test_solver_bf16_twin_actually_engaged(ref_mode, layout, partition):
+    """Under ``hvp_dtype='bfloat16'`` every floating leaf of the HVP twin
+    ``X_hvp`` is bf16 while ``X``, the first-order plane, stays f32, and
+    index arrays are shared; at f32 the twin is ``X`` itself."""
+    import jax
     from repro.core import DiscoConfig, DiscoSolver
-    from repro.data.sparse import make_sparse_glm_data
 
-    X, y, _ = make_sparse_glm_data(1500, 2500, density=0.004, alpha=1.0,
-                                   seed=3)
-    s = DiscoSolver(X, y, DiscoConfig(partition=partition, tau=16,
-                                      hvp_dtype="bfloat16"))
-    assert s.layout.layout == "slots"
-    h, f = s.slots_h, s.slots
-    for a in (h.fwd.vals, h.tr.vals, h.head):
-        assert str(a.dtype) == "bfloat16"
-    for a in (f.fwd.vals, f.tr.vals, f.head):
-        assert str(a.dtype) == "float32"          # first-order plane f32
-    assert h.fwd.ids is f.fwd.ids and h.tr.owner is f.tr.owner
-    s32 = DiscoSolver(X, y, DiscoConfig(partition=partition, tau=16))
-    assert s32.slots_h is s32.slots
+    X, y, kw = _bf16_twin_problem(layout)
+    kw = dict(partition=partition, loss="logistic", lam=1e-2, tau=16, **kw)
+    s = DiscoSolver(X, y, DiscoConfig(hvp_dtype="bfloat16", **kw))
+    if layout != "dense":
+        assert s.layout.layout == layout
+    leaves = zip(jax.tree_util.tree_leaves(s.X_hvp),
+                 jax.tree_util.tree_leaves(s.X))
+    floating = 0
+    for h, f in leaves:
+        if jnp.issubdtype(f.dtype, jnp.floating):
+            assert str(h.dtype) == "bfloat16"
+            assert str(f.dtype) == "float32"      # first-order plane f32
+            floating += 1
+        else:
+            assert h is f                         # ids, columns shared
+    assert floating > 0
+    s32 = DiscoSolver(X, y, DiscoConfig(**kw))
+    assert s32.X_hvp is s32.X
 
 
 def test_hvp_dtype_validation():

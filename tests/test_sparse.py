@@ -8,9 +8,9 @@ import jax.numpy as jnp
 
 from repro.core import DiscoConfig, disco_fit
 from repro.data.libsvm import load_libsvm, save_libsvm
-from repro.data.sparse import (CSRMatrix, ell_from_csr, ell_pair_from_csr,
-                               load_libsvm_sparse, make_sparse_glm_data,
-                               stack_shard_ells)
+from repro.data.sparse import (CSRMatrix, EllPair, SlotPair, ell_from_csr,
+                               ell_pair_from_csr, load_libsvm_sparse,
+                               make_sparse_glm_data, stack_shard_ells)
 from repro.kernels import ops as kops
 from repro.kernels import ref as kref
 
@@ -409,8 +409,8 @@ def test_slot_solver_reaches_grad_target(partition):
     finally:
         obs.disable()
     assert solver.layout.layout == "slots"
-    assert not hasattr(solver, "ell_data")
-    assert counters["disco.hvp_slot_bytes"] == solver.slots.nbytes > 0
+    assert isinstance(solver.X, SlotPair)
+    assert counters["disco.hvp_slot_bytes"] == solver.X.nbytes > 0
     res = solver.fit()
     g = logistic_grad_oracle(Xd, y, res.w.astype(np.float64), lam)
     assert res.converged
@@ -432,3 +432,44 @@ def test_tile_solver_counts_no_slot_bytes(rng):
         obs.disable()
     assert solver.layout.layout == "ell"
     assert counters["disco.hvp_slot_bytes"] == 0
+
+
+@pytest.mark.parametrize("layout", ["array", "ell", "slots"])
+def test_with_hvp_dtype(rng, layout):
+    """The HVP twin of each layout: values cast to the HVP dtype, index
+    arrays shared with the original, and the original itself back when
+    its values already have that dtype."""
+    import jax
+
+    from oracles import slot_csr_case
+    from repro.data.sparse import (build_shard_ell_pairs,
+                                   build_shard_slot_pairs, hvp_tile_dtype,
+                                   with_hvp_dtype)
+
+    X = slot_csr_case(rng)
+    host = {"array": lambda: X.todense(),
+            "ell": lambda: EllPair(*build_shard_ell_pairs([X], 8, 8)),
+            "slots": lambda: build_shard_slot_pairs([X], X.shape)}[layout]()
+    dev = jax.tree_util.tree_map(jnp.asarray, host)
+    bf16 = hvp_tile_dtype("bfloat16")
+    h = with_hvp_dtype(dev, bf16)
+    assert type(h) is type(dev)
+    if layout == "array":
+        values, shared = [(h, dev)], []
+    elif layout == "ell":
+        values = [(h.data, dev.data), (h.dataT, dev.dataT)]
+        shared = [(h.cols, dev.cols), (h.colsT, dev.colsT)]
+    else:
+        values = [(h.fwd.vals, dev.fwd.vals), (h.tr.vals, dev.tr.vals),
+                  (h.head, dev.head)]
+        shared = [(h.fwd.ids, dev.fwd.ids), (h.tr.ids, dev.tr.ids),
+                  (h.fwd.owner, dev.fwd.owner), (h.tr.owner, dev.tr.owner),
+                  (h.head_rows, dev.head_rows)]
+    for a, f in values:
+        assert a.dtype == bf16 and f.dtype == np.float32
+        np.testing.assert_array_equal(np.asarray(a),
+                                      np.asarray(f).astype(bf16))
+    for a, f in shared:
+        assert a is f
+    assert with_hvp_dtype(dev, np.dtype(np.float32)) is dev
+    assert with_hvp_dtype(h, bf16) is h

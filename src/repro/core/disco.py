@@ -35,6 +35,7 @@ from repro.core.pcg import pcg_features, pcg_samples
 from repro.obs import tracer as obs
 from repro.data.partition import Partition, make_partition
 from repro.kernels import ops as kops
+from repro.kernels.glm_hvp import one_pass_block
 from repro.launch.mesh import make_mesh
 from repro.data.sparse import (CSRMatrix, EllPair, SlotPair,
                                build_shard_ell_pairs, build_shard_slot_pairs,
@@ -339,7 +340,30 @@ class DiscoSolver:
         self._w_sharding = NamedSharding(
             self.mesh, P(axis) if cfg.partition == "features" else P())
         self._w_shape = (self.d_padded,)
+        obs.count("disco.hvp_one_pass_bytes", self._hvp_one_pass_bytes())
         self._step = self._build_step()
+
+    def _hvp_one_pass_bytes(self) -> int:
+        """Bytes of X the PCG's local products read once per HVP, all
+        shards: the whole HVP copy when every shard's product takes the
+        one-pass dense kernel, else 0. That is a dense X on TPU devices
+        whose local panel fits (``one_pass_block``), in the classic PCG
+        (``pcg_block_s`` 1), whose HVP is the full local product: always
+        on DiSCO-S except over the two-pass kernels (``use_kernel``
+        without ``hvp_fused``), and on DiSCO-F only fused on one shard."""
+        cfg, X = self.cfg, self.X_hvp
+        if (self._sparse or cfg.pcg_block_s > 1
+                or self.mesh.devices.flat[0].platform != "tpu"):
+            return 0
+        if cfg.partition == "samples":
+            full = not cfg.use_kernel or cfg.hvp_fused
+            d_loc, n_loc = X.shape[0], X.shape[1] // self.m
+        else:
+            full = cfg.use_kernel and cfg.hvp_fused and self.m == 1
+            d_loc, n_loc = X.shape[0] // self.m, X.shape[1]
+        if full and one_pass_block(d_loc, n_loc, X.dtype.itemsize):
+            return X.nbytes
+        return 0
 
     def _init_dense(self, X, y):
         cfg, axis = self.cfg, self.axis
@@ -544,9 +568,8 @@ class DiscoSolver:
                 out_specs=(P(axis), P()),
                 check_vma=False))  # pallas_call outputs carry no vma info
 
-            def step(w, key):
-                return fn(self.X, self.X_hvp, self.X_tau, self.y,
-                          self.y_tau, smask, w, key)
+            step = functools.partial(fn, self.X, self.X_hvp, self.X_tau,
+                                     self.y, self.y_tau, smask)
 
         else:  # samples
             def step_local(Xs, Xhs, y_loc, wts_loc, X_tau, y_tau, w, key):
@@ -582,12 +605,12 @@ class DiscoSolver:
                 out_specs=(P(), P()),
                 check_vma=False))  # pallas_call outputs carry no vma info
 
-            def step(w, key):
-                return fn(self.X, self.X_hvp, self.y, self.weights,
-                          self.X_tau, self.y_tau, w, key)
+            step = functools.partial(fn, self.X, self.X_hvp, self.y,
+                                     self.weights, self.X_tau, self.y_tau)
 
-        # the device data enter the jitted program as arguments: an array
-        # closed over by a jitted function is embedded as a constant
+        # the device data enter the jitted program as arguments, bound
+        # ahead of (w, key): an array closed over by a jitted function is
+        # embedded as a constant
         return step
 
     # ------------------------------------------------------------------

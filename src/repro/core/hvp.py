@@ -11,8 +11,9 @@ once at solver setup:
 ========================  =====================================================
 operator                  backing
 ========================  =====================================================
-:class:`DenseOperator`    plain ``jnp`` matmuls on a dense ``(d_loc, n)`` /
-                          ``(d, n_loc)`` shard (two-pass only)
+:class:`DenseOperator`    plain ``jnp`` passes on a dense ``(d_loc, n)`` /
+                          ``(d, n_loc)`` shard; the full product reads X
+                          once by the one-pass kernel on a TPU
 :class:`DenseKernelOperator`  Pallas GLM kernels (``kernels/glm_hvp.py``),
                           optionally one-pass fused
 :class:`EllOperator`      blocked-ELL sparse kernels
@@ -100,8 +101,9 @@ def _cell_verdict(family: str, layout: str, partition: str, fused: bool,
         return False, ("the softmax class coupling runs between pass A "
                        "and pass B, so no one-pass fused kernel exists"), ""
     if layout == "dense" and fused:
-        return False, ("the plain-jnp dense path has no one-pass kernel; "
-                       "set use_kernel=True for fused dense HVPs"), ""
+        return False, ("the default dense path picks its one-pass kernel "
+                       "itself (on a TPU, when the panel fits); the flag "
+                       "is use_kernel=True's"), ""
     if layout == "streamed" and partition == "features" and fused:
         return False, ("streamed DiSCO-F accumulates pass A chunk by "
                        "chunk, so no collective-free one-pass kernel can "
@@ -244,11 +246,16 @@ class HvpOperator:
 
 
 class DenseOperator(HvpOperator):
-    """Plain-``jnp`` dense layout (two-pass only; no Pallas)."""
+    """Dense layout, the default. The split passes are plain ``jnp``; the
+    full product :meth:`apply` reads X once through the one-pass kernel
+    where it is lowered for a TPU and the kernel's panel fits, and is the
+    two ``jnp`` passes elsewhere (``kernels.ops.dense_local_hvp``)."""
 
     layout = "dense"
 
     def __init__(self, X, coeffs):
+        from repro.kernels import ops as kops
+        self._kops = kops
         self.X = X
         self.coeffs = coeffs
         self.fused = False
@@ -272,6 +279,13 @@ class DenseOperator(HvpOperator):
         if self.coeffs is None:
             return self.X @ Z
         return self.X @ (self.coeffs[:, None] * Z)
+
+    def apply(self, u):
+        """Full local product ``X (c .* X^T u)``: one read of X on a TPU
+        when the panel fits, else ``pass_b(pass_a(u))``."""
+        if self.coeffs is None:
+            return self.pass_b(self.pass_a(u))
+        return self._kops.dense_local_hvp(self.X, self.coeffs, u)
 
 
 class DenseKernelOperator(HvpOperator):
@@ -539,7 +553,7 @@ def make_local_operator(X_loc, coeffs, *, use_kernel: bool = False,
     :class:`DenseKernelOperator` when ``use_kernel`` else
     :class:`DenseOperator`. Raises :class:`UnsupportedHvpError` (cell
     named) for combinations no operator implements — e.g. ``fused`` on
-    the plain-jnp dense path, which older revisions silently ignored.
+    the default dense path, which older revisions silently ignored.
     """
     if isinstance(X_loc, EllPair):
         resolve_cell("binary", "ell", partition, fused)

@@ -59,10 +59,12 @@ def _mode() -> str:
     return resolved
 
 
-# VMEM budget for the fused one-pass kernels (docs/kernels.md): the dense
-# panel (d, block_n) — or the sparse tile row plus the resident u/y
-# vectors — must fit alongside double buffering; past the budget the
-# wrappers fall back to the two-pass kernels, which is always legal.
+# VMEM budget for the multi-vector dense and the ELL one-pass kernels
+# (docs/kernels.md): the dense panel (d, block_n) — or the sparse tile
+# row plus the resident u/y vectors — must fit alongside double
+# buffering; past the budget the wrappers fall back to the two-pass
+# kernels, which is always legal. The single-vector dense one-pass
+# kernel plans its own panel (``glm_hvp.one_pass_block``).
 _FUSED_VMEM_BYTES = int(os.environ.get("REPRO_FUSED_VMEM_BYTES", 4 << 20))
 
 
@@ -108,7 +110,7 @@ def _fused_ell_fits(dataT, u_len: int, s: int = 1) -> bool:
 def _glm_hvp_impl(X, c, u, lam, *, block_d, block_n, mode, fused):
     d, n = X.shape
     if fused:
-        y = x_c_xt_u(X, c, u, block_d=block_d, block_n=block_n, mode=mode)
+        y = x_c_xt_u(X, c, u, block_d=block_d, mode=mode)
         return y / n + lam * u
     if mode == "ref":
         return _ref.ref_glm_hvp(X, c, u, lam)
@@ -129,7 +131,8 @@ def glm_hvp(X, c, u, lam, *, block_d=512, block_n=512, mode=None,
     """H u = X diag(c) X^T u / n + lam u  via the Pallas HVP kernels.
 
     ``fused=True`` routes through the one-pass panel-resident kernel
-    (:func:`x_c_xt_u`) — X streams from HBM once instead of twice."""
+    (:func:`x_c_xt_u`, at its own panel width) — X streams from HBM once
+    instead of twice."""
     mode = mode or _mode()
     return _glm_hvp_impl(X, c, u, jnp.asarray(lam, jnp.float32),
                          block_d=block_d, block_n=block_n, mode=mode,
@@ -247,35 +250,58 @@ def glm_hvp_multi(X, c, U, lam, *, block_d=512, block_n=512, mode=None,
 # fused one-pass GLM HVP (panel-resident; docs/kernels.md)
 # ---------------------------------------------------------------------------
 
-def x_c_xt_u(X, c, u, *, block_d=512, block_n=512, mode=None,
+def x_c_xt_u(X, c, u, *, block_d=512, block_n=None, mode=None,
              out_dtype=jnp.float32):
     """y = X (c .* (X^T u)) in ONE streaming pass over X.
 
     The local fused HVP core: both directions run from the same
-    VMEM-resident (d, block_n) column panel, so X streams from HBM once
-    per application instead of twice. Legal wherever no collective
-    separates the passes (DiSCO-S local products, single-shard DiSCO-F,
-    the s-step zero-communication basis operators). Falls back to the
-    two-pass kernels when the panel exceeds the fused VMEM budget
-    (``REPRO_FUSED_VMEM_BYTES``). Accumulates f32, returns ``out_dtype``.
+    VMEM-resident full-height column panel, so X streams from HBM once
+    per application instead of twice, and X is read where it lies (no
+    padded copy). Legal wherever no collective separates the passes
+    (DiSCO-S local products, single-shard DiSCO-F, the s-step
+    zero-communication basis operators). ``block_n`` defaults to the
+    kernel's own panel width (:func:`repro.kernels.glm_hvp.one_pass_block`);
+    when no panel fits VMEM the two-pass kernels run instead, at
+    ``block_d`` x 512 tiles. Accumulates f32, returns ``out_dtype``.
     """
     mode = mode or _mode()
     if mode == "ref":
         return _ref.ref_x_c_xt_u(X, c, u).astype(out_dtype)
-    interp = mode == "interpret"
     d, n = X.shape
-    if _fused_panel_fits(-(-d // LANE) * LANE, block_n,
-                         X.dtype.itemsize):
-        Xp, _ = _pad_axis(X, 0, LANE)
-        Xp, _ = _pad_axis(Xp, 1, block_n)
-        cp, _ = _pad_axis(c, 0, block_n)
-        up, _ = _pad_axis(u, 0, LANE)
-        y = _hvp.x_c_xt_u(Xp, cp, up, block_n=block_n, interpret=interp,
-                          out_dtype=out_dtype)
-        return y[:d]
-    z = xt_u(X, u, block_d=block_d, block_n=block_n, mode=mode)
-    return x_cz_local(X, c, z, block_d=block_d, block_n=block_n,
-                      mode=mode).astype(out_dtype)
+    itemsize = X.dtype.itemsize
+    bn = block_n or _hvp.one_pass_block(d, n, itemsize)
+    if bn and _hvp.one_pass_vmem_bytes(d, bn, itemsize) \
+            <= _hvp.ONE_PASS_VMEM_BYTES:
+        return _hvp.x_c_xt_u(X, c, u, block_n=bn,
+                             interpret=(mode == "interpret"),
+                             out_dtype=out_dtype)
+    z = xt_u(X, u, block_d=block_d, mode=mode)
+    return x_cz_local(X, c, z, block_d=block_d, mode=mode).astype(out_dtype)
+
+
+def dense_local_hvp(X, c, u):
+    """The dense local product ``X (c .* (X^T u))`` as a solver runs it.
+
+    Lowered for a TPU, and when the one-pass kernel's panel fits
+    (:func:`repro.kernels.glm_hvp.one_pass_block`, decided from d and the
+    itemsize), it is the one-pass kernel: one read of X per product, f32
+    on the VPU. Lowered for any other platform, or when no panel fits,
+    it is XLA's two passes, ``X @ (c * (X.T @ u))``. The platform is the
+    one the program is lowered for (``lax.platform_dependent``), so a
+    program compiled for a described chip takes the chip's branch.
+    """
+    def two_pass(X, c, u):
+        return X @ (c * (X.T @ u))
+
+    bn = _hvp.one_pass_block(*X.shape, X.dtype.itemsize)
+    if not bn:
+        return two_pass(X, c, u)
+
+    def one_pass(X, c, u):
+        return _hvp.x_c_xt_u(X, c, u, block_n=bn)
+
+    return jax.lax.platform_dependent(X, c, u, tpu=one_pass,
+                                      default=two_pass)
 
 
 def x_c_xt_multi(X, c, U, *, block_d=512, block_n=512, mode=None,

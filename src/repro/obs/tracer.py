@@ -164,6 +164,11 @@ COUNTER_KINDS: dict[str, str] = {
         "bytes of the (id, value) slot layouts and head slab a sparse "
         "in-memory solver placed for its HVP, all shards; 0 when it "
         "chose blocked-ELL tiles"),
+    "disco.hvp_one_pass_bytes": (
+        "bytes of X that an in-memory solver's local products read once "
+        "per HVP through the one-pass dense kernel, all shards; 0 when "
+        "its HVP takes two passes (off a TPU, sparse layouts, a panel "
+        "that does not fit VMEM)"),
     "io.retries": "transient I/O failures retried by the retry policy",
     "serve.scored": "requests scored by the micro-batch scheduler",
     "serve.pack_bytes": (

@@ -6,7 +6,9 @@ Three layers of coverage:
   blocks, padded ELL widths, s-step multi-vector shapes and both tile
   dtypes (interpret mode: the kernel bodies execute on CPU exactly as
   they would on TPU), plus the out_dtype regression (bf16 tiles must
-  NOT round the f32 accumulator) and the VMEM-budget fallback;
+  NOT round the f32 accumulator) and the VMEM-budget fallback; the
+  dense one-pass kernel against a float64 oracle at the dense cells'
+  widths, and its dispatch rule;
 * solver level — ``hvp_fused=True`` reproduces the two-pass
   ``DiscoSolver`` bit-identically in ref mode, and ``hvp_dtype=
   'bfloat16'`` converges to the f32 optimum;
@@ -14,6 +16,7 @@ Three layers of coverage:
   classic and s-step, both partitionings (same idiom as
   tests/test_streaming.py).
 """
+import importlib
 import os
 import subprocess
 import sys
@@ -46,12 +49,19 @@ def test_dense_fused_matches_twopass_and_oracle(rng, d, n, dtype):
     Xf = np.asarray(X, np.float32)
     want = Xf @ (np.asarray(c) * (Xf.T @ np.asarray(u)))
     assert got.dtype == jnp.float32          # f32 out regardless of tiles
-    tol = 1e-5 if dtype == jnp.float32 else 5e-2
+    # the one-pass kernel multiplies the stored values by the f32 vectors
+    # in f32, bf16 tiles included, so both dtypes meet the f32 tolerance;
+    # the two-pass kernels round u and c*z to the tile dtype for the MXU,
+    # so the two paths agree to 1e-6 only in f32
     scale = max(np.abs(want).max(), 1.0)
-    np.testing.assert_allclose(np.asarray(got), want, atol=tol * scale,
+    np.testing.assert_allclose(np.asarray(got), want, atol=1e-5 * scale,
+                               rtol=1e-5)
+    tol = 1e-5 if dtype == jnp.float32 else 5e-2
+    np.testing.assert_allclose(np.asarray(two), want, atol=tol * scale,
                                rtol=tol)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(two),
-                               atol=1e-6 * scale, rtol=1e-6)
+    if dtype == jnp.float32:
+        np.testing.assert_allclose(np.asarray(got), np.asarray(two),
+                                   atol=1e-6 * scale, rtol=1e-6)
 
 
 @pytest.mark.parametrize("s", [1, 2, 5])
@@ -82,7 +92,8 @@ def test_dense_fused_vmem_fallback(rng, monkeypatch):
     kernels and still match."""
     from repro.kernels import ops as kops
 
-    monkeypatch.setattr(kops, "_FUSED_VMEM_BYTES", 1024)  # force fallback
+    dense = importlib.import_module("repro.kernels.glm_hvp")
+    monkeypatch.setattr(dense, "ONE_PASS_VMEM_BYTES", 1024)  # force fallback
     d, n = 64, 100
     X = jnp.asarray(rng.standard_normal((d, n)), jnp.float32)
     c = jnp.asarray(rng.random(n), jnp.float32)
@@ -91,6 +102,82 @@ def test_dense_fused_vmem_fallback(rng, monkeypatch):
     Xf = np.asarray(X)
     want = Xf @ (np.asarray(c) * (Xf.T @ np.asarray(u)))
     np.testing.assert_allclose(np.asarray(got), want, atol=1e-4, rtol=1e-4)
+
+
+# the one-pass kernel at the dense cells' widths: (d, dtype, columns past
+# the last whole panel), so the last panel is ragged
+ONE_PASS_CASES = [
+    (2000, jnp.float32, 300),      # epsilon's d
+    (1156, jnp.float32, 77),       # ocr's d: not a multiple of 8 rows
+    (130, jnp.float32, 5),
+    (1156, jnp.bfloat16, 77),      # the bf16 twin: 16-row packed tiles
+    (130, jnp.bfloat16, 129),
+]
+
+
+@pytest.mark.parametrize("d,dtype,extra", ONE_PASS_CASES)
+def test_dense_one_pass_matches_f64_oracle(rng, d, dtype, extra):
+    """The one-pass kernel at its own panel width against a float64
+    oracle over the stored values: max |y - y64| / max |y64| <= 1e-6.
+
+    Its products and sums are f32, so the error is a few f32 roundings
+    (about 3e-7 here). A kernel that rounded X, u or c*z to bf16, as an
+    MXU pass at default precision does, misses by about 1e-3 and fails.
+    The coefficients hold zeros (the Hessian-subsampling mask), and the
+    interpreter fills the out-of-bounds part of the ragged last panel
+    with NaN, so only the in-kernel mask keeps those columns out.
+    """
+    from jax.experimental.pallas import tpu as pltpu
+
+    dense = importlib.import_module("repro.kernels.glm_hvp")
+    bn = dense.one_pass_block(d, 1 << 30, jnp.dtype(dtype).itemsize)
+    n = bn + extra
+    assert dense.one_pass_block(d, n, jnp.dtype(dtype).itemsize) == bn
+    X = jnp.asarray(rng.standard_normal((d, n)), dtype)
+    c = rng.random(n) * (rng.random(n) < 0.7)
+    u = rng.standard_normal(d)
+    nan_oob = pltpu.InterpretParams(out_of_bounds_reads="uninitialized",
+                                    uninitialized_memory="nan")
+    got = np.asarray(dense.x_c_xt_u(X, jnp.asarray(c, jnp.float32),
+                                    jnp.asarray(u, jnp.float32),
+                                    interpret=nan_oob))
+    X64 = np.asarray(X.astype(jnp.float32), np.float64)
+    want = X64 @ (np.asarray(jnp.asarray(c, jnp.float32), np.float64)
+                  * (X64.T @ np.asarray(jnp.asarray(u, jnp.float32),
+                                        np.float64)))
+    assert got.dtype == np.float32 and np.isfinite(got).all()
+    assert np.abs(got - want).max() <= 1e-6 * np.abs(want).max()
+
+
+def test_dense_one_pass_dispatch_rule(rng):
+    """The panel plan engages at the dense cells' widths, in f32 and in
+    bf16, and not where one lane group of X outgrows the panel budget;
+    off a TPU the default dense operator is XLA's two passes, the same
+    program bit for bit, with no kernel in it."""
+    import jax
+    from repro.core.hvp import DenseOperator
+
+    dense = importlib.import_module("repro.kernels.glm_hvp")
+    for d, n_loc in ((2000, 400_000), (1156, 875_000)):
+        for itemsize in (4, 2):
+            bn = dense.one_pass_block(d, n_loc, itemsize)
+            assert bn >= 2048 and bn % 1024 == 0, (d, itemsize, bn)
+            assert d * bn * itemsize <= dense.ONE_PASS_PANEL_BYTES
+            assert dense.one_pass_vmem_bytes(d, bn, itemsize) \
+                <= dense.ONE_PASS_VMEM_BYTES
+    assert dense.one_pass_block(70_000, 400_000, 4) == 0
+    assert 0 < dense.one_pass_block(8000, 400_000, 4) < 2048
+    assert dense.one_pass_block(2000, 100, 4) <= 2048    # no wider than n
+
+    X = jnp.asarray(rng.standard_normal((200, 700)), jnp.float32)
+    c = jnp.asarray(rng.random(700), jnp.float32)
+    u = jnp.asarray(rng.standard_normal(200), jnp.float32)
+    op = DenseOperator(X, c)
+    text = jax.jit(op.apply).lower(u).as_text()
+    assert "dense_hvp_one_pass" not in text and "custom_call" not in text
+    two = jax.jit(lambda u: X @ (c * (X.T @ u)))(u)
+    assert np.array_equal(np.asarray(jax.jit(op.apply)(u)),
+                          np.asarray(two))
 
 
 # ---------------------------------------------------------------------------

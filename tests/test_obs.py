@@ -379,6 +379,50 @@ def test_solver_placement_spans_once_per_construction(ref_mode, glm_data,
     assert counters["disco.place_bytes_max"] == 2 * one
 
 
+@pytest.mark.parametrize("layout", ["dense", "slots"])
+def test_hvp_one_pass_bytes_counter(ref_mode, glm_data, layout):
+    """``disco.hvp_one_pass_bytes`` is registered and added once per
+    in-memory solver: 0 on the CPU, where every HVP takes two passes,
+    and 0 for slots. With the mesh's devices taken for TPUs, the rule
+    counts a dense solver's whole HVP copy wherever its PCG HVP is the
+    full local product, and 0 where it is not."""
+    import types
+
+    from repro import obs
+    from repro.core import DiscoConfig, DiscoSolver
+    from repro.obs.tracer import COUNTER_KINDS
+
+    assert "disco.hvp_one_pass_bytes" in COUNTER_KINDS
+    X, y, _ = glm_data
+    if layout == "slots":       # 0.4% dense under 128 x 128 tiles
+        from repro.data.sparse import make_sparse_glm_data
+        X, y, _ = make_sparse_glm_data(1500, 2500, density=0.004,
+                                       alpha=1.0, seed=3)
+    cfg = DiscoConfig(partition="samples", loss="logistic", lam=1e-2,
+                      tau=16)
+    tracer = obs.enable(reset=True)
+    solver = DiscoSolver(X, y, cfg)
+    DiscoSolver(X, y, cfg)
+    _, counters, _ = tracer.snapshot()
+    assert counters["disco.hvp_one_pass_bytes"] == 0
+
+    tpu = types.SimpleNamespace(platform="tpu")
+    solver.mesh = types.SimpleNamespace(devices=np.array([tpu]))
+    if layout == "slots":
+        assert solver._hvp_one_pass_bytes() == 0
+        return
+    assert solver._hvp_one_pass_bytes() == solver.X_hvp.nbytes > 0
+    for kw, engaged in ((dict(use_kernel=True), False),
+                        (dict(use_kernel=True, hvp_fused=True), True),
+                        (dict(pcg_block_s=2), False),
+                        (dict(partition="features"), False),
+                        (dict(partition="features", use_kernel=True,
+                              hvp_fused=True), True)):
+        solver.cfg = DiscoConfig(**{**dict(partition="samples"), **kw})
+        assert solver._hvp_one_pass_bytes() == (
+            solver.X_hvp.nbytes if engaged else 0), kw
+
+
 def test_newton_step_nests_in_each_outer_and_times_iter_s(ref_mode,
                                                          glm_data):
     from repro import obs

@@ -5,7 +5,9 @@ and ``get_topology_desc`` describes a v5e 2x2 host without one attached.
 Each test lowers one kernel at a real width for one chip of it and asserts
 that Mosaic accepted the kernel (``tpu_custom_call`` in the compiled HLO).
 This catches what interpret mode cannot: block shapes that break the
-(8, 128) tiling rule, unaligned slices, and VMEM overruns.
+(8, 128) tiling rule, unaligned slices, and VMEM overruns. The default
+dense solver step is compiled whole at the dense cells' shapes, where
+its HVP takes the branch lowered for the chip.
 
 The topology is described inside a module-scoped fixture (never at import)
 so that pytest-xdist workers all collect the same tests and only the worker
@@ -13,6 +15,7 @@ running this file loads the TPU library.
 """
 import importlib
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -21,7 +24,9 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
-from repro.core import disco
+from repro.core import DiscoConfig, disco
+from repro.core.hvp import DenseOperator
+from repro.core.losses import get_loss
 from repro.glm_serve.scoring import slot_margins
 from repro.kernels import ops
 from repro.launch.mesh import make_mesh
@@ -206,3 +211,62 @@ def test_slot_passes_compile_for_v5e(one_chip, dtype):
         assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
         out = jax.eval_shape(fn, *args)
         assert out.shape == (n_out,) and out.dtype == jnp.float32
+
+
+def _dense_samples_step(devices, d, n):
+    """The default dense DiSCO-S Newton step (logistic, classic PCG) of a
+    solver on ``devices`` over (d, n) f32 data sharded by samples,
+    compiled. The solver is assembled around shapes: nothing is placed,
+    and ``_build_step`` binds the shapes as the program's data."""
+    m = len(devices)
+    mesh = make_mesh((m,), ("data",), devices=devices)
+    s = disco.DiscoSolver.__new__(disco.DiscoSolver)
+    s.cfg = DiscoConfig(loss="logistic", lam=1e-3, partition="samples")
+    s.loss, s.axis, s.mesh, s.m = get_loss("logistic"), "data", mesh, m
+    s.d, s.n, s.tau, s._sparse = d, n, min(s.cfg.tau, n), False
+
+    def sds(shape, spec, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    s.X = s.X_hvp = sds((d, n), P(None, "data"))
+    s.y = s.weights = sds((n,), P("data"))
+    s.X_tau, s.y_tau = sds((d, s.tau), P()), sds((s.tau,), P())
+    step = s._build_step()
+    return step.func.lower(*step.args, sds((d,), P()),
+                           sds((2,), P(), jnp.uint32)).compile()
+
+
+@pytest.mark.parametrize("cell", ["epsilon", "ocr"])
+def test_dense_step_reads_x_once_per_hvp(v5e, cell):
+    """The default dense DiSCO-S step at the cell's shape: ``epsilon``
+    (2,000 x 400,000 on one chip) and ``ocr`` (1,156 x 3,500,000 on the
+    2x2 mesh). The PCG loop's one HVP is the one-pass kernel, the only
+    custom call of the step; nothing X-sized is padded, copied or
+    transposed, and the step's temporaries stay under 1% of X a chip."""
+    devices, d, n = {"epsilon": (v5e[:1], 2000, 400_000),
+                     "ocr": (v5e, 1156, 3_500_000)}[cell]
+    compiled = _dense_samples_step(devices, d, n)
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert re.search(rf"%dense_hvp_one_pass\S* = f32\[{d},128\]", text)
+    n_loc = n // len(devices)
+    assert not re.search(
+        rf"= f32\[{d},{n_loc}\]\S* (copy|pad|transpose|fusion)\(", text)
+    x_bytes = d * n_loc * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.01 * x_bytes
+
+
+@pytest.mark.parametrize("d,kernel", [(2000, True), (1156, True),
+                                      (70_000, False)])
+def test_dense_operator_dispatch_for_v5e(one_chip, d, kernel):
+    """Lowered for the chip, the default dense operator's full product is
+    the one-pass kernel where its panel fits, and XLA's two passes where
+    one lane group of X outgrows the panel budget."""
+    n = 400_000 if kernel else 4096
+    args = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+            for s in ((d, n), (n,), (d,))]
+    text = jax.jit(lambda X, c, u: DenseOperator(X, c).apply(u)).lower(
+        *args).compile().as_text()
+    assert ("dense_hvp_one_pass" in text) == kernel
+    assert ("tpu_custom_call" in text) == kernel
